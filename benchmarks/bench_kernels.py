@@ -30,7 +30,7 @@ from repro.core.spectral import _cp_exprs, _dense_expr
 from repro.kernels import ops
 from repro.kernels.spectral_contract import (
     cp_vmem_bytes, pick_block_m, vmem_bytes, vmem_bytes_bwd)
-from repro.launch.roofline import HBM_BW
+from repro.launch.roofline import PEAKS
 from repro.tune.measure import bytes_moved
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results", "kernels.json")
@@ -55,6 +55,14 @@ FUSED_CASES = {
 def _randc(rng, shape, scale=0.5):
     return jnp.asarray(
         scale * (rng.randn(*shape) + 1j * rng.randn(*shape)), jnp.complex64)
+
+
+def _hbm_share(gbps: float):
+    """Achieved bytes/s over the device's published HBM peak — only for
+    compiled kernels on a device the peak table knows, else None."""
+    peaks = (None if ops._use_interpret()
+             else PEAKS.get(jax.devices()[0].device_kind))
+    return round(gbps * 1e9 / peaks["hbm_bw"], 6) if peaks else None
 
 
 def _temp_bytes(fn, *args) -> int:
@@ -158,7 +166,7 @@ def bench_case(name: str, policy_name: str, seed: int = 0,
         if label != "einsum":
             gbps = moved / (entry["fwd_bwd_us"] * 1e-6) / 1e9
             entry["gbps"] = round(gbps, 3)
-            entry["roofline_fraction"] = round(gbps / (HBM_BW / 1e9), 6)
+            entry["roofline_fraction"] = _hbm_share(gbps)
         row[label] = entry
     row["pallas_over_einsum_wall"] = round(
         row["pallas"]["fwd_bwd_us"] / max(row["einsum"]["fwd_bwd_us"], 1e-9), 3)
@@ -228,7 +236,7 @@ def bench_fused_case(name: str, policy_name: str, seed: int = 0) -> dict:
             traffic = moved["fused"] if label == "fused" else moved["staged"]
             gbps = traffic / (entry["fwd_bwd_us"] * 1e-6) / 1e9
             entry["gbps"] = round(gbps, 3)
-            entry["roofline_fraction"] = round(gbps / (HBM_BW / 1e9), 6)
+            entry["roofline_fraction"] = _hbm_share(gbps)
         row[label] = entry
     row["fused_over_staged_wall"] = round(
         row["fused"]["fwd_bwd_us"] / max(row["staged"]["fwd_bwd_us"], 1e-9), 3)
@@ -296,4 +304,9 @@ def main():
 
 
 if __name__ == "__main__":
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    compile_cache.place(Path(__file__).resolve().parents[1])
     main()

@@ -38,4 +38,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    compile_cache.place(Path(__file__).resolve().parents[1])
     main()

@@ -6,6 +6,8 @@ action, and verifies Theorem 3.1/3.2 empirically.
 
     PYTHONPATH=src python examples/quickstart.py
 """
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +15,10 @@ import numpy as np
 from repro.core import (
     FULL, get_policy, greedy_path, path_intermediate_bytes, theory,
 )
+from repro.launch import compile_cache
 from repro.models import FNOConfig, fno_apply, init_fno
+
+compile_cache.place(Path(__file__).resolve().parents[1])
 
 # 1. a small FNO
 cfg = FNOConfig(in_channels=1, out_channels=1, hidden_channels=32,

@@ -38,7 +38,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.precision.rules import site_matches
 

@@ -23,6 +23,7 @@ CLI (tiny certification pass, used by CI)::
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 from typing import List, Optional, Tuple
 
@@ -133,8 +134,13 @@ def certify_policy(policy, cfg=None, params=None, x=None, *,
         cfg, params = tiny_fno()
     if x is None:
         x = sample_inputs(source, resolution, batch, seed)
-    y_ref = np.asarray(fno_apply(params, jax.numpy.asarray(x), cfg, FULL),
-                       np.float32)
+    # the reference is plain f32: the einsum path at the highest matmul
+    # precision (a TPU's default f32 matmul takes bf16 passes)
+    ref_cfg = dataclasses.replace(cfg, use_pallas=False)
+    with jax.default_matmul_precision("highest"):
+        y_ref = np.asarray(
+            fno_apply(params, jax.numpy.asarray(x), ref_cfg, FULL),
+            np.float32)
     y_pol, totals = instrumented_apply(policy, cfg, params, x)
 
     sites = {s: _site_row(s, w, policy) for s, w in sorted(totals.items())}

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .spectral_contract import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -85,9 +87,12 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """q/k/v: (BH, S, D) — batch·heads pre-flattened. Returns (BH, S, D)."""
+    """q/k/v: (BH, S, D) — batch·heads pre-flattened. Returns (BH, S, D).
+
+    ``interpret``: None resolves from the backend (compiled on a TPU).
+    """
     BH, S, D = q.shape
     Sk = k.shape[1]
     assert k.shape == (BH, Sk, D) and v.shape == (BH, Sk, D), (q.shape, k.shape, v.shape)
@@ -125,6 +130,6 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out[:, :S, :]

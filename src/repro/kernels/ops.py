@@ -24,8 +24,10 @@ import jax.numpy as jnp
 from repro.core.precision import ComplexPair
 from repro.precision import FULL, PrecisionPolicy
 from .spectral_contract import (
+    LANES,
     VMEM_BUDGET,
     cp_vmem_bytes,
+    fused_exact,
     fused_supported,
     fused_vmem_bytes,
     fused_vmem_bytes_bwd,
@@ -33,6 +35,7 @@ from .spectral_contract import (
     pick_block_b,
     pick_block_l,
     pick_block_m,
+    resolve_interpret,
     spectral_contract_cp_pallas,
     spectral_contract_lshared_pallas,
     spectral_contract_pallas,
@@ -45,7 +48,7 @@ from .rmsnorm import rmsnorm as _rmsnorm
 
 
 def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return resolve_interpret(None)
 
 
 def resolve_use_pallas(flag: Optional[bool] = None) -> bool:
@@ -108,7 +111,9 @@ def fused_spectral_viable(fft_in, ctr, B: int, I: int, O: int,
     """Can this dense layer run the fused megakernel bit-for-spec?
 
     Requires structural support (modes fit the truncated-DFT factor
-    layout), a VMEM fit for the training working set at the floor tile
+    layout and, where the kernels compile to Mosaic, a layout Mosaic
+    lowers: ``fused_layout_compiles``), a VMEM fit for the training
+    working set at the floor tile
     ``block_b=1``, an inactive autoprec collector (the staged path owns
     telemetry — its taps see the HBM spectrum the fused path never
     materialises), and spectral sites that agree on one quantisation
@@ -120,7 +125,8 @@ def fused_spectral_viable(fft_in, ctr, B: int, I: int, O: int,
 
     if telemetry_active():
         return False
-    if not fused_supported(tuple(spatial), tuple(modes)):
+    if not fused_supported(tuple(spatial), tuple(modes),
+                           compiled=not _use_interpret()):
         return False
     if fused_vmem_bytes_bwd(1, I, O, tuple(spatial), tuple(modes),
                             itemsize=4) > VMEM_BUDGET:
@@ -285,6 +291,73 @@ def _to_pair(x, half) -> ComplexPair:
     return ComplexPair.from_complex(x, half)
 
 
+def _batch_tile(B: int, need) -> int:
+    """Largest batch tile (B halved until it fits) whose VMEM working
+    set ``need(tile)`` at the smallest legal mode tile fits the budget.
+    The mode-tiled kernels hold the whole batch in every grid step, so a
+    batch too large for VMEM runs as several kernel calls."""
+    bb = B
+    while bb > 1 and need(bb) > VMEM_BUDGET:
+        bb = (bb + 1) // 2
+    return bb
+
+
+def _run_batched(kernel, bb: int, batched: tuple, replicated: tuple):
+    """``kernel(*batched, *replicated) -> tuple`` of batch-leading
+    outputs, over batch slices of at most ``bb`` rows (one call when the
+    batch fits).
+
+    Under a multi-device ambient mesh the call runs inside a
+    ``shard_map``: Mosaic kernels cannot be partitioned by the compiler,
+    so each device runs the kernel on its own batch shard (the leading
+    dim over every mesh axis, else over the data axes, else replicated)
+    against replicated weights, whose gradient the transpose sums across
+    devices.
+    """
+    n = len(batched)
+
+    def local(*args):
+        xs, ws = args[:n], args[n:]
+        B = xs[0].shape[0]
+        if bb >= B:
+            return tuple(kernel(*xs, *ws))
+        parts = [kernel(*(x[i:i + bb] for x in xs), *ws)
+                 for i in range(0, B, bb)]
+        return tuple(jnp.concatenate(p, axis=0) for p in zip(*parts))
+
+    from repro.dist import ambient_mesh, pick_spec
+
+    mesh = ambient_mesh()
+    if mesh is None or mesh.devices.size <= 1:
+        return local(*batched, *replicated)
+    from jax.sharding import PartitionSpec as P
+
+    lead = pick_spec(batched[0].shape[:1], mesh, [(("dp", "tp"),), ("dp",),
+                                                  ()])
+    bspec = P(*lead)
+    out_shapes = jax.eval_shape(local, *batched, *replicated)
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(bspec,) * n + (P(),) * len(replicated),
+        out_specs=tuple(bspec for _ in out_shapes),
+        check_vma=False,
+    )(*batched, *replicated)
+
+
+def _fused_cast_fits(x_shape, O: int) -> bool:
+    """Whether the fused storage cast's f32 operand tiles fit VMEM at
+    the smallest mode tile Mosaic accepts.  Where they do not (wide
+    layers at a large batch), the operands are rounded onto the half
+    grid in HBM instead — the same rounding, one more HBM pass."""
+    B, I, *modes = x_shape
+    M = 1
+    for m in modes:
+        M *= m
+    bm = min(M, LANES)
+    return max(vmem_bytes(B, I, O, bm, 4),
+               vmem_bytes_bwd(B, I, O, bm, 4)) <= VMEM_BUDGET
+
+
 def spectral_contract(
     x, w, *, policy=FULL, block_m: Optional[int] = None,
     block_m_bwd: Optional[int] = None, fuse_casts: Optional[bool] = None,
@@ -343,6 +416,7 @@ def spectral_contract(
         and not was_pair
         and not isinstance(w, ComplexPair)
         and resolve_fuse_casts(fuse_casts)
+        and _fused_cast_fits(x.shape, w.shape[1])
     )
     if fused:
         # fused-quantise path: split to f32 pairs without rounding here;
@@ -367,23 +441,30 @@ def spectral_contract(
     # the fused path streams f32 operand tiles, so its VMEM working set
     # (and its calibration entries) price at itemsize 4
     itemsize = 4 if fused else jnp.dtype(half).itemsize
+    bm0 = min(M, LANES)
+    bb = _batch_tile(B, lambda b: max(vmem_bytes(b, I, O, bm0, itemsize),
+                                      vmem_bytes_bwd(b, I, O, bm0, itemsize)))
     if block_m is None:
         block_m, tuned_bwd, _src = _resolve_blocks(
-            "dense-fused" if fused else "dense", (B, I, O, M), half,
-            lambda: pick_block_m(B, I, O, M, itemsize=itemsize),
+            "dense-fused" if fused else "dense", (bb, I, O, M), half,
+            lambda: pick_block_m(bb, I, O, M, itemsize=itemsize),
         )
         block_m_bwd = block_m_bwd or tuned_bwd
+    interpret = _use_interpret()
+
+    def kernel(xr, xi, wr, wi):
+        return spectral_contract_pallas(
+            xr, xi, wr, wi, block_m=block_m, block_m_bwd=block_m_bwd,
+            interpret=interpret, out_dtype=half,
+            cast_to=half if fused else None,
+        )
 
     # named_scope: eqns traced under this site carry its address in
     # their name stack — repro.analyze attributes findings with it
     with jax.named_scope(policy.site):
-        out_re, out_im = spectral_contract_pallas(
-            xp.re.reshape(B, I, M), xp.im.reshape(B, I, M),
-            wp.re.reshape(I, O, M), wp.im.reshape(I, O, M),
-            block_m=block_m, block_m_bwd=block_m_bwd,
-            interpret=_use_interpret(), out_dtype=half,
-            cast_to=half if fused else None,
-        )
+        out_re, out_im = _run_batched(
+            kernel, bb, (xp.re.reshape(B, I, M), xp.im.reshape(B, I, M)),
+            (wp.re.reshape(I, O, M), wp.im.reshape(I, O, M)))
     pair = ComplexPair(
         out_re.reshape(B, O, *modes), out_im.reshape(B, O, *modes)
     )
@@ -430,7 +511,7 @@ def spectral_conv_fused(
     if len(spatial) != ndim:
         raise ValueError(
             f"spectral_conv_fused: x {x.shape} vs modes {tuple(modes)}")
-    if not fused_supported(spatial, modes):
+    if not fused_exact(spatial, modes):
         raise ValueError(
             f"spectral_conv_fused: spatial {spatial} cannot retain modes "
             f"{tuple(modes)} in the fused factor layout — the staged "
@@ -458,12 +539,18 @@ def spectral_conv_fused(
         )
         block_b_bwd = block_b_bwd or tuned_bwd
 
+    interpret = _use_interpret()
+
+    def kernel(xb, wr, wi):
+        return (spectral_fused_pallas(
+            xb, wr, wi, modes=tuple(modes), block_b=block_b,
+            block_b_bwd=block_b_bwd, interpret=interpret, cast_to=cast_to,
+            sim_fmt=sim_fmt,
+        ),)
+
     with jax.named_scope(ctr.site):
-        y = spectral_fused_pallas(
-            x.astype(jnp.float32), wgr, wgi, modes=tuple(modes),
-            block_b=block_b, block_b_bwd=block_b_bwd,
-            interpret=_use_interpret(), cast_to=cast_to, sim_fmt=sim_fmt,
-        )
+        (y,) = _run_batched(kernel, B, (x.astype(jnp.float32),),
+                            (wgr, wgi))
 
     from repro.autoprec.telemetry import fmt_of, tap
 
@@ -518,21 +605,28 @@ def spectral_contract_cp(
     wp = _to_pair(w, half)
     O = uop.re.shape[0]
     R = uip.re.shape[1]
+    itemsize = jnp.dtype(half).itemsize
+    bm0 = min(M, LANES)
+    bb = _batch_tile(B, lambda b: cp_vmem_bytes(b, I, O, R, bm0, itemsize))
     if block_m is None:
         block_m, tuned_bwd, _src = _resolve_blocks(
-            "cp", (B, I, O, R, M), half,
-            lambda: pick_block_m(B, I, O, M, rank=R,
-                                 itemsize=jnp.dtype(half).itemsize),
+            "cp", (bb, I, O, R, M), half,
+            lambda: pick_block_m(bb, I, O, M, rank=R, itemsize=itemsize),
         )
         block_m_bwd = block_m_bwd or tuned_bwd
 
-    with jax.named_scope(policy.site):
-        out_re, out_im = spectral_contract_cp_pallas(
-            xp.re.reshape(B, I, M), xp.im.reshape(B, I, M),
-            uip.re, uip.im, uop.re, uop.im, wp.re, wp.im,
-            block_m=block_m, block_m_bwd=block_m_bwd,
-            interpret=_use_interpret(), out_dtype=half,
+    interpret = _use_interpret()
+
+    def kernel(*args):
+        return spectral_contract_cp_pallas(
+            *args, block_m=block_m, block_m_bwd=block_m_bwd,
+            interpret=interpret, out_dtype=half,
         )
+
+    with jax.named_scope(policy.site):
+        out_re, out_im = _run_batched(
+            kernel, bb, (xp.re.reshape(B, I, M), xp.im.reshape(B, I, M)),
+            (uip.re, uip.im, uop.re, uop.im, wp.re, wp.im))
     pair = ComplexPair(
         out_re.reshape(B, O, *modes), out_im.reshape(B, O, *modes)
     )
@@ -575,12 +669,17 @@ def spectral_contract_lshared(
                                  itemsize=jnp.dtype(half).itemsize),
         )
         block_l_bwd = block_l_bwd or tuned_bwd
-    with jax.named_scope(policy.site):
-        out_re, out_im = spectral_contract_lshared_pallas(
-            xp.re, xp.im, wp.re, wp.im,
-            block_l=block_l, block_l_bwd=block_l_bwd,
-            interpret=_use_interpret(), out_dtype=half,
+    interpret = _use_interpret()
+
+    def kernel(*args):
+        return spectral_contract_lshared_pallas(
+            *args, block_l=block_l, block_l_bwd=block_l_bwd,
+            interpret=interpret, out_dtype=half,
         )
+
+    with jax.named_scope(policy.site):
+        out_re, out_im = _run_batched(kernel, B, (xp.re, xp.im),
+                                      (wp.re, wp.im))
     pair = ComplexPair(out_re, out_im)
     if was_pair and policy.spectral_is_half:
         return pair

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .spectral_contract import resolve_interpret
+
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)  # (TR, D) — reduce in f32 (AMP rule)
@@ -32,9 +34,12 @@ def rmsnorm(
     *,
     eps: float = 1e-6,
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """x: (N, D) rows to normalise; w: (D,) scale. Returns (N, D)."""
+    """x: (N, D) rows to normalise; w: (D,) scale. Returns (N, D).
+
+    ``interpret``: None resolves from the backend (compiled on a TPU).
+    """
     N, D = x.shape
     pad = (-N) % block_rows
     if pad:
@@ -49,6 +54,6 @@ def rmsnorm(
         ],
         out_specs=pl.BlockSpec((block_rows, D), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((Np, D), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)
     return out[:N]

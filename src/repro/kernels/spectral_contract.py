@@ -58,9 +58,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-#: VMEM per TPU core (v5e-class) — the budget ``pick_block_m`` packs under.
-VMEM_BUDGET = 16 * 2 ** 20
+#: Scoped VMEM the tile pickers pack their working-set estimates under.
+VMEM_BUDGET = 32 * 2 ** 20
+
+#: The scoped-VMEM limit every kernel here asks Mosaic for: twice the
+#: budget (v5e holds 128 MiB of VMEM; Mosaic's default limit is 16 MiB).
+#: The estimators below count the pipelined blocks and the f32 products;
+#: Mosaic also keeps scratch they do not model (operand transposes, f32
+#: matmul passes), up to as much again — at FNO_DARCY width under
+#: ``full`` the dense kernel's smallest legal tile needs 17.8 MiB.
+VMEM_LIMIT = 2 * VMEM_BUDGET
+MOSAIC_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT)
 
 #: Version of the kernel *schedules* in this module (tile layouts, matmul
 #: decomposition, accumulation discipline).  Bump whenever a change could
@@ -69,7 +79,19 @@ VMEM_BUDGET = 16 * 2 ** 20
 #: without anyone having to remember to delete the cache file.
 #: v3: the fused rFFT→contract→irFFT family (``spectral_fused``) joins
 #: the registry, batch-tiled with its own VMEM estimators.
-KERNEL_VERSION = 3
+#: v4: Mosaic-compilable layouts — lane-legal mode tiles, the CP backward
+#: without two-dim contractions, the l-shared weight degree-major on a
+#: (degree, batch-row) grid — and estimators that count double buffers.
+KERNEL_VERSION = 4
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret=None`` (every kernel entry point's default) resolves
+    from the backend: compiled Mosaic on a TPU, the Pallas interpreter
+    elsewhere.  An explicit bool wins."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 def _acc_dtype(dtype) -> jnp.dtype:
@@ -189,6 +211,7 @@ def _dense_call(kernel, a_specs, out_specs, out_shapes, grid, interpret, *args):
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
+        compiler_params=MOSAIC_PARAMS,
     )(*args)
 
 
@@ -266,9 +289,9 @@ def spectral_contract_pallas(
     wr: jnp.ndarray,
     wi: jnp.ndarray,
     *,
-    block_m: int = 64,
+    block_m: int = 128,
     block_m_bwd: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
     out_dtype=None,
     cast_to=None,
 ) -> tuple:
@@ -281,8 +304,9 @@ def spectral_contract_pallas(
       block_m_bwd: mode-tile size for the two backward kernels (defaults
         to ``block_m``; the autotuner calibrates the directions
         independently because their working sets differ).
-      interpret: run the kernel body in Python (CPU validation); on TPU
-        pass False to compile to Mosaic.
+      interpret: run the kernel body in Python (CPU validation); None
+        resolves from the backend, so on a TPU the kernel compiles to
+        Mosaic unless the caller asks otherwise.
       cast_to: fused-quantise mode — pass the half storage dtype and feed
         f32 operands; each tile is rounded onto the storage grid in VMEM
         (same Thm 3.2 representation error as pre-casting in HBM, one
@@ -301,7 +325,8 @@ def spectral_contract_pallas(
         )
     out_dtype = jnp.dtype(out_dtype or xr.dtype)
     cast_to = jnp.dtype(cast_to) if cast_to is not None else None
-    config = (block_m, block_m_bwd or block_m, interpret, out_dtype, cast_to)
+    config = (block_m, block_m_bwd or block_m, resolve_interpret(interpret),
+              out_dtype, cast_to)
     return _dense_op(config, xr, xi, wr, wi)
 
 
@@ -369,6 +394,13 @@ def _cp_bwd_kernel(xr_ref, xi_ref, uir_ref, uii_ref, uor_ref, uoi_ref,
         return jax.lax.dot_general(a, b, (dims, ((), ())),
                                    preferred_element_type=acc)
 
+    def dg_bm(a, b):
+        # Σ_{b,m} a[b,n,m]·b[b,m,r] -> (n, r): a matmul per batch row
+        # (one contracting dim, as Mosaic requires), summed over b
+        prods = jax.lax.dot_general(a, b, (((2,), (1,)), ((0,), (0,))),
+                                    preferred_element_type=acc)
+        return jnp.sum(prods, axis=0)
+
     tr, ti, ur, ui, _, _ = _cp_fwd_stages(
         xr, xi, uir, uii, uor, uoi, wr, wi, acc)
 
@@ -377,9 +409,8 @@ def _cp_bwd_kernel(xr_ref, xi_ref, uir_ref, uii_ref, uor_ref, uoi_ref,
     dur = dg(gr, uor, d_du) + dg(gi, uoi, d_du)
     dui = dg(gi, uor, d_du) - dg(gr, uoi, d_du)
     # dUo[o,r] = Σ_{b,m} g[b,o,m]·conj(u[b,m,r])   (accumulated over tiles)
-    d_bm = ((0, 2), (0, 1))
-    duor = dg(gr, ur, d_bm) + dg(gi, ui, d_bm)
-    duoi = dg(gi, ur, d_bm) - dg(gr, ui, d_bm)
+    duor = dg_bm(gr, ur) + dg_bm(gi, ui)
+    duoi = dg_bm(gi, ur) - dg_bm(gr, ui)
     # dt = du·conj(W)
     wrT = jnp.transpose(wr, (1, 0)).astype(acc)[None]
     wiT = jnp.transpose(wi, (1, 0)).astype(acc)[None]
@@ -397,8 +428,8 @@ def _cp_bwd_kernel(xr_ref, xi_ref, uir_ref, uii_ref, uor_ref, uoi_ref,
     dxr_ref[...] = jnp.transpose(dxr, (0, 2, 1)).astype(dxr_ref.dtype)
     dxi_ref[...] = jnp.transpose(dxi, (0, 2, 1)).astype(dxi_ref.dtype)
     # dUi[i,r] = Σ_{b,m} conj(x[b,i,m])·dt[b,m,r]   (accumulated over tiles)
-    duir = dg(xr, dtr, d_bm) + dg(xi, dti, d_bm)
-    duii = dg(xr, dti, d_bm) - dg(xi, dtr, d_bm)
+    duir = dg_bm(xr, dtr) + dg_bm(xi, dti)
+    duii = dg_bm(xr, dti) - dg_bm(xi, dtr)
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -433,6 +464,7 @@ def _cp_fwd_call(config, xr, xi, uir, uii, uor, uoi, wr, wi):
         out_specs=[_x_spec(B, O, block_m)] * 2,
         out_shape=[jax.ShapeDtypeStruct((B, O, Mp), out_dtype)] * 2,
         interpret=interpret,
+        compiler_params=MOSAIC_PARAMS,
     )(xr, xi, uir, uii, uor, uoi, wr, wi)
     return out_re[..., :M], out_im[..., :M]
 
@@ -473,6 +505,7 @@ def _cp_op_bwd(config, res, cts):
             + [jax.ShapeDtypeStruct((R, Mp), wr.dtype)] * 2
         ),
         interpret=interpret,
+        compiler_params=MOSAIC_PARAMS,
     )(xrp, xip, uir, uii, uor, uoi, wrp, wip, grp, gip)
     dxr, dxi, duir, duii, duor, duoi, dwr, dwi = outs
     return (
@@ -500,9 +533,9 @@ def spectral_contract_cp_pallas(
     wr: jnp.ndarray,
     wi: jnp.ndarray,
     *,
-    block_m: int = 64,
+    block_m: int = 128,
     block_m_bwd: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
     out_dtype=None,
 ) -> tuple:
     """CP-factorised split-real contraction (differentiable).
@@ -526,7 +559,8 @@ def spectral_contract_cp_pallas(
             f"x {xr.shape}, Ui {uir.shape}, Uo {uor.shape}, W {wr.shape}"
         )
     out_dtype = jnp.dtype(out_dtype or xr.dtype)
-    config = (block_m, block_m_bwd or block_m, interpret, out_dtype)
+    config = (block_m, block_m_bwd or block_m, resolve_interpret(interpret),
+              out_dtype)
     return _cp_op(config, xr, xi, uir, uii, uor, uoi, wr, wi)
 
 
@@ -540,61 +574,69 @@ def spectral_contract_cp_pallas(
 # materialising it as a dense (I, O, l, m) operand for the dense kernel
 # would stream mmax× the weight bytes (and materialise an mmax× gradient
 # before reduction).  These kernels instead tile over *degrees l* and ride
-# m along as a free axis; the weight tile stays (I, O, TL).
+# m along as a free axis, one batch row per grid step (grid (L/TL, B),
+# batch minor), so the VMEM working set does not grow with the batch.
+# The kernels hold the weight degree-major, (L, I, O), so its tile
+# (TL, I, O) keeps the full channel dims minor — the layout Mosaic tiles
+# — whatever the degree tile; the public entry point takes and returns
+# the (I, O, L) layout and transposes at the edge.
 
 
 def _lshared_fwd_kernel(xr_ref, xi_ref, wr_ref, wi_ref, or_ref, oi_ref):
-    """Refs: x (B, I, TL, M), w (I, O, TL) -> out (B, O, TL, M)."""
-    xr, xi = xr_ref[...], xi_ref[...]
+    """Refs: x (1, I, TL, M), w (TL, I, O) -> out (1, O, TL, M)."""
+    xr, xi = xr_ref[0], xi_ref[0]
     wr, wi = wr_ref[...], wi_ref[...]
     acc = _acc_dtype(xr.dtype)
 
     def bmm(a, b):
-        # contract I; batch over the degree tile -> (TL, B, M, O)
+        # contract I; batch over the degree tile -> (TL, M, O)
         return jax.lax.dot_general(
-            a, b, (((1,), (0,)), ((2,), (2,))), preferred_element_type=acc)
+            a, b, (((0,), (1,)), ((1,), (0,))), preferred_element_type=acc)
 
     our = bmm(xr, wr) - bmm(xi, wi)
     oui = bmm(xr, wi) + bmm(xi, wr)
-    or_ref[...] = jnp.transpose(our, (1, 3, 0, 2)).astype(or_ref.dtype)
-    oi_ref[...] = jnp.transpose(oui, (1, 3, 0, 2)).astype(oi_ref.dtype)
+    or_ref[0] = jnp.transpose(our, (2, 0, 1)).astype(or_ref.dtype)
+    oi_ref[0] = jnp.transpose(oui, (2, 0, 1)).astype(oi_ref.dtype)
 
 
 def _lshared_bwd_x_kernel(gr_ref, gi_ref, wr_ref, wi_ref, dxr_ref, dxi_ref):
-    """dx = g · conj(w): g (B, O, TL, M), w (I, O, TL) -> dx (B, I, TL, M)."""
-    gr, gi = gr_ref[...], gi_ref[...]
+    """dx = g · conj(w): g (1, O, TL, M), w (TL, I, O) -> dx (1, I, TL, M)."""
+    gr, gi = gr_ref[0], gi_ref[0]
     wr, wi = wr_ref[...], wi_ref[...]
     acc = _acc_dtype(gr.dtype)
 
     def bmm(a, b):
-        # contract O; batch TL -> (TL, B, M, I)
+        # contract O; batch TL -> (TL, M, I)
         return jax.lax.dot_general(
-            a, b, (((1,), (1,)), ((2,), (2,))), preferred_element_type=acc)
+            a, b, (((0,), (2,)), ((1,), (0,))), preferred_element_type=acc)
 
     dxr = bmm(gr, wr) + bmm(gi, wi)
     dxi = bmm(gi, wr) - bmm(gr, wi)
-    dxr_ref[...] = jnp.transpose(dxr, (1, 3, 0, 2)).astype(dxr_ref.dtype)
-    dxi_ref[...] = jnp.transpose(dxi, (1, 3, 0, 2)).astype(dxi_ref.dtype)
+    dxr_ref[0] = jnp.transpose(dxr, (2, 0, 1)).astype(dxr_ref.dtype)
+    dxi_ref[0] = jnp.transpose(dxi, (2, 0, 1)).astype(dxi_ref.dtype)
 
 
 def _lshared_bwd_w_kernel(xr_ref, xi_ref, gr_ref, gi_ref, dwr_ref, dwi_ref):
-    """dw = conj(x) · g summed over b AND m: -> dw (I, O, TL).  The m
+    """dw = conj(x) · g summed over b AND m: -> dw (TL, I, O).  The m
     reduction happens in-tile, so the (I, O, l, m) intermediate the dense
-    path would materialise never exists."""
-    xr, xi = xr_ref[...], xi_ref[...]
-    gr, gi = gr_ref[...], gi_ref[...]
+    path would materialise never exists; the b reduction accumulates
+    across the batch grid axis into the revisited f32 output block."""
+    xr, xi = xr_ref[0], xi_ref[0]
+    gr, gi = gr_ref[0], gi_ref[0]
     acc = _acc_dtype(xr.dtype)
 
     def bmm(a, b):
-        # contract (B, M); batch TL -> (TL, I, O)
+        # contract M; batch TL -> (TL, I, O)
         return jax.lax.dot_general(
-            a, b, (((0, 3), (0, 3)), ((2,), (2,))),
-            preferred_element_type=acc)
+            a, b, (((2,), (2,)), ((1,), (1,))), preferred_element_type=acc)
 
-    dwr = bmm(xr, gr) + bmm(xi, gi)
-    dwi = bmm(xr, gi) - bmm(xi, gr)
-    dwr_ref[...] = jnp.transpose(dwr, (1, 2, 0)).astype(dwr_ref.dtype)
-    dwi_ref[...] = jnp.transpose(dwi, (1, 2, 0)).astype(dwi_ref.dtype)
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        dwr_ref[...] = jnp.zeros(dwr_ref.shape, dwr_ref.dtype)
+        dwi_ref[...] = jnp.zeros(dwi_ref.shape, dwi_ref.dtype)
+
+    dwr_ref[...] += (bmm(xr, gr) + bmm(xi, gi)).astype(dwr_ref.dtype)
+    dwi_ref[...] += (bmm(xr, gi) - bmm(xi, gr)).astype(dwi_ref.dtype)
 
 
 def _pad_l(a: jnp.ndarray, block_l: int, axis: int) -> jnp.ndarray:
@@ -606,28 +648,29 @@ def _pad_l(a: jnp.ndarray, block_l: int, axis: int) -> jnp.ndarray:
     return jnp.pad(a, widths)
 
 
-def _lshared_specs(B, I, O, Mm, block_l):
-    x = pl.BlockSpec((B, I, block_l, Mm), lambda l: (0, 0, l, 0))
-    g = pl.BlockSpec((B, O, block_l, Mm), lambda l: (0, 0, l, 0))
-    w = pl.BlockSpec((I, O, block_l), lambda l: (0, 0, l))
+def _lshared_specs(I, O, Mm, block_l):
+    x = pl.BlockSpec((1, I, block_l, Mm), lambda l, b: (b, 0, l, 0))
+    g = pl.BlockSpec((1, O, block_l, Mm), lambda l, b: (b, 0, l, 0))
+    w = pl.BlockSpec((block_l, I, O), lambda l, b: (l, 0, 0))
     return x, g, w
 
 
 def _lshared_fwd_call(config, xr, xi, wr, wi):
     block_l, _block_l_bwd, interpret, out_dtype = config
     B, I, L, Mm = xr.shape
-    _, O, _ = wr.shape
+    O = wr.shape[2]
     xr, xi = _pad_l(xr, block_l, 2), _pad_l(xi, block_l, 2)
-    wr, wi = _pad_l(wr, block_l, 2), _pad_l(wi, block_l, 2)
+    wr, wi = _pad_l(wr, block_l, 0), _pad_l(wi, block_l, 0)
     Lp = xr.shape[2]
-    x_s, g_s, w_s = _lshared_specs(B, I, O, Mm, block_l)
+    x_s, g_s, w_s = _lshared_specs(I, O, Mm, block_l)
     out_re, out_im = pl.pallas_call(
         _lshared_fwd_kernel,
-        grid=(Lp // block_l,),
+        grid=(Lp // block_l, B),
         in_specs=[x_s, x_s, w_s, w_s],
         out_specs=[g_s, g_s],
         out_shape=[jax.ShapeDtypeStruct((B, O, Lp, Mm), out_dtype)] * 2,
         interpret=interpret,
+        compiler_params=MOSAIC_PARAMS,
     )(xr, xi, wr, wi)
     return out_re[:, :, :L], out_im[:, :, :L]
 
@@ -646,13 +689,14 @@ def _lshared_op_bwd(config, res, cts):
     gr, gi = cts
     _block_l, block_l, interpret, _ = config
     B, I, L, Mm = xr.shape
-    _, O, _ = wr.shape
+    O = wr.shape[2]
+    acc = _acc_dtype(xr.dtype)
     xrp, xip = _pad_l(xr, block_l, 2), _pad_l(xi, block_l, 2)
-    wrp, wip = _pad_l(wr, block_l, 2), _pad_l(wi, block_l, 2)
+    wrp, wip = _pad_l(wr, block_l, 0), _pad_l(wi, block_l, 0)
     grp, gip = _pad_l(gr, block_l, 2), _pad_l(gi, block_l, 2)
     Lp = xrp.shape[2]
-    grid = (Lp // block_l,)
-    x_s, g_s, w_s = _lshared_specs(B, I, O, Mm, block_l)
+    grid = (Lp // block_l, B)
+    x_s, g_s, w_s = _lshared_specs(I, O, Mm, block_l)
     dxr, dxi = pl.pallas_call(
         _lshared_bwd_x_kernel,
         grid=grid,
@@ -660,16 +704,21 @@ def _lshared_op_bwd(config, res, cts):
         out_specs=[x_s, x_s],
         out_shape=[jax.ShapeDtypeStruct((B, I, Lp, Mm), xr.dtype)] * 2,
         interpret=interpret,
+        compiler_params=MOSAIC_PARAMS,
     )(grp, gip, wrp, wip)
     dwr, dwi = pl.pallas_call(
         _lshared_bwd_w_kernel,
         grid=grid,
         in_specs=[x_s, x_s, g_s, g_s],
         out_specs=[w_s, w_s],
-        out_shape=[jax.ShapeDtypeStruct((I, O, Lp), wr.dtype)] * 2,
+        # dw accumulates over the batch grid axis at the accumulator
+        # dtype; cast back to the primal dtype below
+        out_shape=[jax.ShapeDtypeStruct((Lp, I, O), acc)] * 2,
         interpret=interpret,
+        compiler_params=MOSAIC_PARAMS,
     )(xrp, xip, grp, gip)
-    return (dxr[:, :, :L], dxi[:, :, :L], dwr[:, :, :L], dwi[:, :, :L])
+    return (dxr[:, :, :L], dxi[:, :, :L],
+            dwr[:L].astype(wr.dtype), dwi[:L].astype(wi.dtype))
 
 
 _lshared_op.defvjp(_lshared_op_fwd, _lshared_op_bwd)
@@ -687,13 +736,15 @@ def spectral_contract_lshared_pallas(
     *,
     block_l: int = 8,
     block_l_bwd: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
     out_dtype=None,
 ) -> tuple:
     """Split-real ``bilm,iol->bolm`` with the weight shared over m
     (differentiable; the SFNO spherical contraction).
 
-    xr/xi: (B, I, L, M) spectrum; wr/wi: (I, O, L) per-degree weights.
+    xr/xi: (B, I, L, M) spectrum; wr/wi: (I, O, L) per-degree weights,
+    transposed here to the kernels' degree-major (L, I, O) layout.
+    ``interpret``: None resolves from the backend (compiled on a TPU).
     Returns (out_re, out_im): (B, O, L, M) at ``out_dtype``.
     """
     B, I, L, Mm = xr.shape
@@ -704,8 +755,10 @@ def spectral_contract_lshared_pallas(
             f"{wr.shape} — expected (B, I, L, M) and (I, O, L)"
         )
     out_dtype = jnp.dtype(out_dtype or xr.dtype)
-    config = (block_l, block_l_bwd or block_l, interpret, out_dtype)
-    return _lshared_op(config, xr, xi, wr, wi)
+    config = (block_l, block_l_bwd or block_l, resolve_interpret(interpret),
+              out_dtype)
+    return _lshared_op(config, xr, xi, jnp.transpose(wr, (2, 0, 1)),
+                       jnp.transpose(wi, (2, 0, 1)))
 
 
 # ---------------------------------------------------------------------------
@@ -749,7 +802,7 @@ def _fused_rows(spatial, modes):
     return tuple(2 * m for m in modes[:-1]) + (modes[-1],)
 
 
-def fused_supported(spatial, modes) -> bool:
+def fused_exact(spatial, modes) -> bool:
     """Whether the truncated-DFT factorisation is exact for this shape:
     corner blocks must not overlap (2m_k <= S_k) and the last axis must
     retain no more than the rfft spectrum holds."""
@@ -758,6 +811,27 @@ def fused_supported(spatial, modes) -> bool:
     if any(2 * m > s for m, s in zip(modes[:-1], spatial[:-1])):
         return False
     return modes[-1] <= spatial[-1] // 2 + 1
+
+
+def fused_layout_compiles(modes) -> bool:
+    """Whether Mosaic lowers the megakernel's vector reshapes for these
+    modes.  On a multi-axis grid the kernel reshapes the flattened
+    retained modes to per-axis row blocks and back; Mosaic's layout pass
+    refuses that ("infer-vector-layout: unsupported shape cast") unless
+    the minor block — the last axis's retained modes — is whole 128-lane
+    rows.  A 1-D grid needs no such reshape."""
+    return len(modes) == 1 or modes[-1] % LANES == 0
+
+
+def fused_supported(spatial, modes, *, compiled: bool | None = None) -> bool:
+    """Whether the dispatch may run this shape on the megakernel: the
+    factorisation is exact and, where the kernel compiles to Mosaic
+    (``compiled``; None resolves from the backend), its layout lowers."""
+    if not fused_exact(spatial, modes):
+        return False
+    if compiled is None:
+        compiled = not resolve_interpret(None)
+    return not compiled or fused_layout_compiles(modes)
 
 
 def fused_factors(spatial, modes):
@@ -989,6 +1063,7 @@ def _fused_fwd_call(config, x, wgr, wgi):
         out_specs=y_s,
         out_shape=jax.ShapeDtypeStruct((Bp, O, *spatial), out_dtype),
         interpret=interpret,
+        compiler_params=MOSAIC_PARAMS,
     )(xp, wgr, wgi, *factors)
     return y[:B]
 
@@ -1029,6 +1104,7 @@ def _fused_op_bwd(config, res, g):
             jax.ShapeDtypeStruct((I, O, Mh), acc),
         ],
         interpret=interpret,
+        compiler_params=MOSAIC_PARAMS,
     )(xp, wgr, wgi, *factors, gp)
     return dx[:B], dwr.astype(wgr.dtype), dwi.astype(wgi.dtype)
 
@@ -1049,7 +1125,7 @@ def spectral_fused_pallas(
     modes: tuple,
     block_b: int = 1,
     block_b_bwd: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
     out_dtype=None,
     cast_to=None,
     sim_fmt: str | None = None,
@@ -1080,7 +1156,7 @@ def spectral_fused_pallas(
             f"spectral_fused_pallas: x {x.shape} vs modes {modes} — "
             f"expected (B, I, *spatial) with one spatial axis per mode")
     spatial = x.shape[2:]
-    if not fused_supported(spatial, modes):
+    if not fused_exact(spatial, modes):
         raise ValueError(
             f"spectral_fused_pallas: modes {modes} do not fit spatial "
             f"{spatial} (need 2m <= S per truncated axis and "
@@ -1096,8 +1172,8 @@ def spectral_fused_pallas(
     out_dtype = jnp.dtype(out_dtype or x.dtype)
     cast_to = jnp.dtype(cast_to) if cast_to is not None else None
     config = (tuple(int(m) for m in modes), int(block_b),
-              int(block_b_bwd or block_b), interpret, out_dtype, cast_to,
-              sim_fmt)
+              int(block_b_bwd or block_b), resolve_interpret(interpret),
+              out_dtype, cast_to, sim_fmt)
     return _fused_op(config, x, wgr, wgi)
 
 
@@ -1106,50 +1182,79 @@ def spectral_fused_pallas(
 # ---------------------------------------------------------------------------
 
 
+# Mosaic's tiling rules for the staged families: the minor dim of every
+# block is a multiple of 128 lanes or the whole (padded) axis, the
+# second-minor a multiple of 8 sublanes or the whole axis.  The
+# estimators below count what a compiled grid step holds: every pipelined
+# block twice (Pallas double-buffers the HBM<->VMEM copies), the
+# half-grid copies a fused storage cast makes of f32 operand tiles, and
+# the f32 matmul results and their transposes.  They were checked
+# against the TPU compiler's scoped-VMEM verdicts for v5e at the
+# configs/fno_paper widths (tests/test_tpu_compile.py compiles them).
+LANES = 128
+SUBLANES = 8
+
+
+def _cast_copies(elems: int, itemsize: int) -> int:
+    # f32 operand tiles rounded onto a half grid in the tile prologue
+    return elems * 2 * 2 if itemsize > 2 else 0
+
+
 def vmem_bytes(B: int, I: int, O: int, block_m: int, itemsize: int = 2) -> int:
-    """Forward VMEM working set per grid step — used to pick block_m so
-    the tile fits comfortably under the ~16 MiB v5e VMEM budget."""
-    halves = (B * I + I * O + B * O) * block_m * 2  # re+im
-    accum = B * O * block_m * 4  # f32 accumulators
-    return halves * itemsize + accum
+    """Forward VMEM per grid step of the dense kernel: double-buffered
+    re/im x, w and out tiles, the cast copies, and six f32 (TM, B, O)
+    products and transposes."""
+    blocks = 2 * (B * I + I * O + B * O) * block_m * 2 * itemsize
+    casts = _cast_copies((B * I + I * O) * block_m, itemsize)
+    return blocks + casts + 6 * B * O * block_m * 4
 
 
 def vmem_bytes_bwd(B: int, I: int, O: int, block_m: int,
                    itemsize: int = 2) -> int:
-    """Backward VMEM working set per grid step: the larger of the dx
-    kernel (g, w tiles + f32 dx accumulators) and the dw kernel (x, g
-    tiles + f32 dw accumulators)."""
-    bwd_x = (B * O + I * O + B * I) * block_m * 2 * itemsize \
-        + B * I * block_m * 4
-    bwd_w = (B * I + B * O + I * O) * block_m * 2 * itemsize \
-        + I * O * block_m * 4
+    """Backward VMEM per grid step: the larger of the dx kernel (g, w
+    in; dx out; four f32 (TM, B, I) products live) and the dw kernel
+    (x, g in; dw out; four f32 (TM, I, O) products live)."""
+    blocks = 2 * (B * O + I * O + B * I) * block_m * 2 * itemsize
+    bwd_x = blocks + _cast_copies((B * O + I * O) * block_m, itemsize) \
+        + 4 * B * I * block_m * 4
+    bwd_w = blocks + _cast_copies((B * I + B * O) * block_m, itemsize) \
+        + 4 * I * O * block_m * 4
     return max(bwd_x, bwd_w)
 
 
 def cp_vmem_bytes(B: int, I: int, O: int, R: int, block_m: int,
                   itemsize: int = 2) -> int:
-    """CP kernel VMEM working set per grid step (backward dominates: it
-    holds x, g, W tiles, both rank factors, the recomputed t/u and the
-    f32 gradient accumulators)."""
-    tiles = (B * I + B * O + R) * block_m * 2 * itemsize   # x, g, W
-    factors = (I * R + O * R) * 2 * itemsize               # Ui, Uo
-    rankspace = 4 * B * R * block_m * 2 * 4                # t, u, du, dt (f32)
-    grads = (I * R + O * R + R * block_m + B * I * block_m) * 2 * 4
+    """CP kernel VMEM per grid step (the backward dominates: it holds
+    the double-buffered x, g, W tiles and rank factors, six f32
+    rank-space (B, TM, R) arrays — t, u, du, dt and their conjugate
+    partners — and the f32 gradient blocks)."""
+    tiles = 2 * (B * I + B * O + R) * block_m * 2 * itemsize   # x, g, W
+    factors = 2 * (I * R + O * R) * 2 * itemsize               # Ui, Uo
+    rankspace = 6 * B * R * block_m * 2 * 4
+    grads = 2 * (I * R + O * R + R * block_m + B * I * block_m) * 2 * 4
     return tiles + factors + rankspace + grads
 
 
 def lshared_vmem_bytes(B: int, I: int, O: int, Mm: int, block_l: int,
                        itemsize: int = 2) -> int:
-    """l-shared (SFNO) kernel VMEM working set per grid step (the bwd-dx
-    step, which holds g, w tiles and the f32 dx accumulator, dominates)."""
-    tiles = ((B * I + B * O) * Mm + I * O) * block_l * 2 * itemsize
-    accum = max(B * I, B * O) * block_l * Mm * 4
-    return tiles + accum
+    """l-shared (SFNO) kernel VMEM per grid step.  The grid streams one
+    batch row per step, so ``B`` does not enter: double-buffered x, g
+    and w tiles plus the f32 dw blocks, and six f32 (TL, M, max(I, O))
+    products and transposes."""
+    del B  # one batch row per grid step
+    blocks = 2 * block_l * ((I + O) * Mm * 2 * itemsize
+                            + I * O * 2 * itemsize + I * O * 2 * 4)
+    return blocks + 6 * max(I, O) * Mm * block_l * 4
+
+
+def _pad(n: int, to: int) -> int:
+    return -(-int(n) // to) * to
 
 
 def _fused_tile_elems(block_b: int, I: int, O: int, spatial, modes):
-    """(x tile, w tile, y tile, factor, worst transform intermediate)
-    element counts for one fused grid step."""
+    """(x tile, w tile, y tile, factor, worst transform intermediate,
+    per-product contraction footprint) element counts for one fused
+    grid step."""
     rows = _fused_rows(spatial, modes)
     S = Mh = 1
     for s in spatial:
@@ -1168,17 +1273,24 @@ def _fused_tile_elems(block_b: int, I: int, O: int, spatial, modes):
             cur *= int(rows[k])
             tail //= int(spatial[k])
     inter *= 2 * block_b * max(I, O)
-    return block_b * I * S, 2 * I * O * Mh, block_b * O * S, fac, inter
+    # the mode-batched contraction products, (Mh, BB, O) forward and
+    # (Mh, I, O) for dw, each padded to whole (8, 128) vector tiles
+    prod = Mh * max(_pad(block_b, SUBLANES) * _pad(max(I, O), LANES),
+                    _pad(I, SUBLANES) * _pad(O, LANES))
+    return (block_b * I * S, 2 * I * O * Mh, block_b * O * S, fac, inter,
+            prod)
 
 
 def fused_vmem_bytes(block_b: int, I: int, O: int, spatial, modes,
                      itemsize: int = 4) -> int:
     """Forward VMEM working set of one fused grid step: the x / weight /
-    output tiles plus the DFT factors and the worst per-axis transform
-    intermediate (split-real, accumulator dtype)."""
-    x_t, w_t, y_t, fac, inter = _fused_tile_elems(block_b, I, O,
-                                                  spatial, modes)
-    return (x_t + w_t + y_t) * itemsize + fac * 4 + inter * 4
+    output tiles plus the DFT factors, the worst per-axis transform
+    intermediate (split-real, accumulator dtype) and the four f32
+    contraction products."""
+    x_t, w_t, y_t, fac, inter, prod = _fused_tile_elems(
+        block_b, I, O, spatial, modes)
+    return (x_t + w_t + y_t) * itemsize + fac * 4 + inter * 4 \
+        + 4 * prod * 4
 
 
 def fused_vmem_bytes_bwd(block_b: int, I: int, O: int, spatial, modes,
@@ -1186,11 +1298,11 @@ def fused_vmem_bytes_bwd(block_b: int, I: int, O: int, spatial, modes,
     """Backward working set: the forward tiles plus the cotangent tile,
     the dx tile and the two f32 dw accumulator blocks (the transposed
     pipeline recomputes the spectrum in-tile, so both transform
-    intermediates are live)."""
-    x_t, w_t, y_t, fac, inter = _fused_tile_elems(block_b, I, O,
-                                                  spatial, modes)
+    intermediates are live), and the four f32 contraction products."""
+    x_t, w_t, y_t, fac, inter, prod = _fused_tile_elems(
+        block_b, I, O, spatial, modes)
     tiles = (x_t + w_t + 2 * y_t + x_t) * itemsize
-    return tiles + w_t * 4 + fac * 4 + 2 * inter * 4
+    return tiles + w_t * 4 + fac * 4 + 2 * inter * 4 + 4 * prod * 4
 
 
 def pick_block_b(B: int, I: int, O: int, spatial, modes, *,
@@ -1214,25 +1326,32 @@ def pick_block_b(B: int, I: int, O: int, spatial, modes, *,
 
 
 def pick_block_l(B: int, I: int, O: int, L: int, Mm: int, *,
-                 itemsize: int = 2, budget: int = VMEM_BUDGET // 2) -> int:
+                 itemsize: int = 2, budget: int = VMEM_BUDGET) -> int:
     """Largest power-of-two degree tile fitting the l-shared kernel's
-    working set under ``budget`` bytes of VMEM."""
-    for bl in (256, 128, 64, 32, 16, 8, 4, 2):
-        if bl > max(L, 2):
+    working set under ``budget`` bytes of VMEM.  The degree axis is a
+    block's second-minor dim, so the tile is a multiple of 8 sublanes or
+    the whole axis."""
+    if L <= SUBLANES:
+        return L
+    for bl in (256, 128, 64, 32, 16):
+        if bl > L:
             continue
         if lshared_vmem_bytes(B, I, O, Mm, bl, itemsize) <= budget:
             return bl
-    return 1
+    return SUBLANES
 
 
 def pick_block_m(B: int, I: int, O: int, M: int, *, rank: int = 0,
-                 itemsize: int = 2, budget: int = VMEM_BUDGET // 2,
+                 itemsize: int = 2, budget: int = VMEM_BUDGET,
                  train: bool = True) -> int:
-    """Largest power-of-two mode tile whose fwd (and, for ``train``, bwd)
-    working set fits in ``budget`` bytes of VMEM.  ``rank > 0`` budgets
-    the CP kernel instead of the dense one."""
-    for bm in (512, 256, 128, 64, 32, 16, 8):
-        if bm > max(M, 8):
+    """Largest mode tile whose fwd (and, for ``train``, bwd) working set
+    fits in ``budget`` bytes of VMEM.  The mode axis is a block's minor
+    dim, so the tile is a multiple of 128 lanes or the whole axis.
+    ``rank > 0`` budgets the CP kernel instead of the dense one."""
+    if M <= LANES:
+        return M
+    for bm in (512, 256):
+        if bm > M:
             continue
         if rank:
             need = cp_vmem_bytes(B, I, O, rank, bm, itemsize)
@@ -1242,4 +1361,4 @@ def pick_block_m(B: int, I: int, O: int, M: int, *, rank: int = 0,
                 need = max(need, vmem_bytes_bwd(B, I, O, bm, itemsize))
         if need <= budget:
             return bm
-    return 8
+    return LANES

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For every (architecture × input shape) cell, on the single-pod 16×16 mesh
@@ -22,6 +19,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -214,6 +212,9 @@ def save_result(rec: dict, path=RESULTS):
 
 
 def main():
+    # the simulated pod: set before the first JAX backend use, here and
+    # not at import, so importing this module never changes a process
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=ARCH_IDS + [None])
     ap.add_argument("--shape", default=None, choices=list(SHAPES) + [None])

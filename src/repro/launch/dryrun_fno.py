@@ -1,6 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """Dry-run for the paper's own FNO-family configs at pod scale.
 
 These rows extend the 40-cell LM table with the cells most representative
@@ -14,6 +11,7 @@ hillclimb compares and optimises them.
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -182,6 +180,9 @@ def run_fno_cell(name: str, multi_pod: bool, policy_name: str,
 
 
 def main():
+    # the simulated pod: set before the first JAX backend use, here and
+    # not at import, so importing this module never changes a process
+    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", default=None, choices=list(FNO_CELLS) + [None])
     ap.add_argument("--mesh", default="single", choices=["single", "multi", "both"])

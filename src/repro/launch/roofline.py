@@ -21,15 +21,25 @@ therefore parse the post-partitioning HLO text ourselves:
 
 All values are per-device (the HLO is the post-SPMD per-device program).
 
-Hardware model (TPU v5e-class, from the assignment):
-    197 TFLOP/s bf16 per chip · 819 GB/s HBM · ~50 GB/s/link ICI.
+Hardware model: the TPU v5e entry of :data:`PEAKS` for compute and HBM,
+and ~50 GB/s per ICI link.
 """
 from __future__ import annotations
 
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 393 TOP/s int8, 16 GB of HBM at 819 GB/s.  A device that is not in
+#: the table has no peak: callers report no roofline share for it.
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9},
+}
+V5E = "TPU v5 lite"
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+PEAK_FLOPS = PEAKS[V5E]["bf16_flops"]   # bf16 FLOP/s per chip
+HBM_BW = PEAKS[V5E]["hbm_bw"]           # bytes/s per chip
+ICI_BW = 50e9                           # bytes/s per link
+
 
 from .hlo_parse import HLOCounts, parse_hlo  # noqa: F401  (re-export)
 import dataclasses as _dc

@@ -34,8 +34,6 @@ def psum_compressed(grads, axis_name: str, dtype=jnp.bfloat16):
 def make_dp_allreduce(mesh, axis_names=("pod", "data"), dtype=jnp.bfloat16):
     """Returns f(grads)->grads performing the compressed DP all-reduce via
     shard_map over the data axes, identity on the model axis."""
-    from jax.experimental.shard_map import shard_map
-
     names = tuple(n for n in axis_names if n in mesh.axis_names)
 
     def reduce_fn(grads):
@@ -52,12 +50,12 @@ def make_dp_allreduce(mesh, axis_names=("pod", "data"), dtype=jnp.bfloat16):
     spec = P(*names)
 
     def wrapper(grads):
-        return shard_map(
+        return jax.shard_map(
             reduce_fn,
             mesh=mesh,
             in_specs=jax.tree_util.tree_map(lambda _: P(), grads),
             out_specs=jax.tree_util.tree_map(lambda _: P(), grads),
-            check_rep=False,
+            check_vma=False,
         )(grads)
 
     return wrapper

@@ -278,6 +278,16 @@ class Trainer:
             self.stats["recompiles"] += 1
         return self._steps_cache[key]
 
+    def lower(self, batch: Dict, policy: Optional[PrecisionPolicy] = None):
+        """Lower (without compiling) the train step ``run`` executes for
+        ``policy`` (default: the schedule's policy at the current step);
+        its text shows which kernels the step holds."""
+        if policy is None:
+            policy = self.cfg.schedule.policy_at(self.step,
+                                                 self.cfg.total_steps)
+        return self._step_fn(policy).lower(
+            self.params, self.opt_state, self.scale_state, batch)
+
     # -- observability --------------------------------------------------------
     def _obs_step_end(self, dt: float) -> None:
         """Per-step metrics + loss-scale numerics events (obs mode only;

@@ -25,8 +25,8 @@ import jax
 from . import cache as cache_mod
 from . import oracle, space
 from .cache import CalibrationCache, CalibrationError
-from .measure import default_interpret, measure
-from repro.kernels.spectral_contract import KERNEL_VERSION
+from .measure import measure
+from repro.kernels.spectral_contract import KERNEL_VERSION, resolve_interpret
 
 DEFAULT_STATE = os.path.join("benchmarks", "results",
                              "calibration_state.json")
@@ -81,8 +81,7 @@ def _entry_from(cand: space.Candidate, perf: dict, verdict: dict,
 
 
 def cmd_tune(args) -> int:
-    interpret = (default_interpret() if args.interpret is None
-                 else args.interpret)
+    interpret = resolve_interpret(args.interpret)
     backend = jax.default_backend()
     keys = SMOKE_KEYS if args.smoke else DEFAULT_KEYS
     limit = args.limit if args.limit is not None else (4 if args.smoke
@@ -153,8 +152,7 @@ def cmd_validate(args) -> int:
     except CalibrationError as e:
         print(f"validate: {e}", file=sys.stderr)
         return 2
-    interpret = (default_interpret() if args.interpret is None
-                 else args.interpret)
+    interpret = resolve_interpret(args.interpret)
     failures, stale, checked = [], [], 0
     for key, ent in sorted(state.entries.items()):
         if not cache_mod._entry_ok(ent):
@@ -186,6 +184,10 @@ def cmd_validate(args) -> int:
     return 1 if failures else 0
 
 
+def _pct(fraction) -> str:
+    return "n/a" if fraction is None else f"{100 * fraction:.1f}%"
+
+
 def cmd_report(args) -> int:
     path = _state_path(args)
     try:
@@ -212,7 +214,7 @@ def cmd_report(args) -> int:
               f"{ent.get('block_bwd', '?'):>4} "
               f"{ent.get('wall_us', float('nan')):>9.1f} "
               f"{ent.get('gbps', float('nan')):>8.2f} "
-              f"{100 * ent.get('roofline_fraction', 0):>5.1f}% "
+              f"{_pct(ent.get('roofline_fraction')):>6} "
               f"{flag:>3}")
     if args.json:
         print(json.dumps(state.to_json(), indent=2, sort_keys=True))

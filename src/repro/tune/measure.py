@@ -12,9 +12,10 @@ validate → cache → resolve cycle (the cache marks such entries
 each other but are not hardware bandwidth).
 
 Alongside wall time every measurement reports achieved GB/s (a
-per-family bytes-moved model over the measured wall) and the fraction of
-the roofline HBM bandwidth that represents — the CORTEX-style
-per-kernel bandwidth report.
+per-family bytes-moved model over the measured wall) and, for a compiled
+run on a device whose ``device_kind`` has published peaks
+(``launch.roofline.PEAKS``), the fraction of its HBM bandwidth that
+represents; otherwise ``roofline_fraction`` is None.
 """
 from __future__ import annotations
 
@@ -28,17 +29,14 @@ import numpy as np
 
 from repro.kernels.spectral_contract import (
     _fused_rows,
+    resolve_interpret,
     spectral_contract_cp_pallas,
     spectral_contract_lshared_pallas,
     spectral_contract_pallas,
     spectral_fused_pallas,
 )
-from repro.launch.roofline import HBM_BW
+from repro.launch.roofline import PEAKS
 from .space import Candidate, family_itemsize, fused_axes
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def bytes_moved(family: str, shape, dtype: str) -> int:
@@ -122,7 +120,7 @@ def make_operands(family: str, shape, dtype: str, seed: int = 0):
 
 def build_step(cand: Candidate, *, interpret: Optional[bool] = None):
     """The jitted value_and_grad train step a candidate is timed on."""
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     family = cand.family
     if family == "spectral_fused":
         _B, _I, _O, _spatial, modes = fused_axes(cand.shape)
@@ -185,16 +183,18 @@ def _wall_us(fn, args, iters: int, warmup: int) -> float:
 def measure(cand: Candidate, *, interpret: Optional[bool] = None,
             iters: int = 3, warmup: int = 1, seed: int = 0) -> dict:
     """Time one candidate; returns the perf fields of a cache entry."""
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     step = build_step(cand, interpret=interpret)
     ops = make_operands(cand.family, cand.shape, cand.dtype, seed=seed)
     wall = _wall_us(step, ops, iters, warmup)
     moved = bytes_moved(cand.family, cand.shape, cand.dtype)
     gbps = moved / (wall * 1e-6) / 1e9 if wall > 0 else 0.0
+    peaks = None if interpret else PEAKS.get(jax.devices()[0].device_kind)
     return {
         "wall_us": wall,
         "bytes_moved": moved,
         "gbps": round(gbps, 3),
-        "roofline_fraction": round(gbps / (HBM_BW / 1e9), 6),
+        "roofline_fraction": (round(gbps * 1e9 / peaks["hbm_bw"], 6)
+                              if peaks else None),
         "interpret": bool(interpret),
     }

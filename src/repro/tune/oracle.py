@@ -27,8 +27,10 @@ import numpy as np
 
 from repro.core.precision import FORMAT_EPS
 from repro.core.theory import prec_upper_bound
-from repro.kernels.spectral_contract import _fused_rows, fused_factors
-from .measure import default_interpret, make_operands
+from repro.kernels.spectral_contract import (
+    _fused_rows, fused_factors, resolve_interpret,
+)
+from .measure import make_operands
 from .space import Candidate, fused_axes
 
 F32_EPS = float(np.finfo(np.float32).eps)
@@ -163,7 +165,7 @@ def check(cand: Candidate, *, interpret: Optional[bool] = None,
         spectral_contract_pallas as d_kern,
     )
 
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     ops = make_operands(cand.family, cand.shape, cand.dtype, seed=seed)
     out_dtype = jnp.dtype(cand.dtype)
     if cand.family == "spectral_fused":

@@ -1,6 +1,6 @@
 """Test-session setup.
 
-Two jobs:
+Three jobs:
 
 * The container may lack ``hypothesis``; the property tests only use a
   narrow slice of it (``given`` / ``settings`` / three strategies), so
@@ -13,6 +13,15 @@ Two jobs:
   suite locks down both spectral paths.  The env var is honoured by
   ``repro.kernels.ops.resolve_use_pallas``; here we only surface which
   path the session runs in the pytest header.
+* The CPU backend of the installed jaxlib (0.9) hands some bf16 dots
+  with f32 accumulation — those contracting a 2-D operand's leading dim
+  against a 3-D operand's middle dim, at sizes that are multiples of 4,
+  e.g. ``einsum("xrb,ro->oxb")`` on the CP einsum path — to its YNNPACK
+  fusion, which then fails at run time ("Unsupported element type for
+  DotThunk::Execute: BF16 x BF16 = F32").  With that fusion off, XLA
+  upcasts such dots to f32 as it does every other bf16 dot on the CPU:
+  the same products and f32 sums.  XLA reads the flag when the backend
+  starts, which is after this module is imported.
 """
 import functools
 import inspect
@@ -20,6 +29,9 @@ import os
 import random
 import sys
 import types
+
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_cpu_experimental_ynn_fusion_type=")))
 
 
 def pytest_configure(config):

@@ -285,9 +285,16 @@ class TestLossScaleComposition:
         tr = Trainer(loss_fn, params, TrainerConfig(
             total_steps=6,
             schedule=PrecisionSchedule.constant("mixed_fno_fp16")))
+        # the clean steps before the injection may themselves overflow
+        # fp16 at the initial scale (a gradient larger than 65504 once
+        # scaled) and back the scale off; count from the scale and skip
+        # count the injected step actually starts from
+        tr.run(lambda s: batch, steps=2)
         s0 = float(tr.scale_state.scale)
+        k0 = tr.stats["skipped_steps"]
         tr.run(lambda s: bad if s == 2 else batch)
-        assert tr.stats["skipped_steps"] == 1
+        assert not np.isfinite(tr.history[2]["loss"])
+        assert tr.stats["skipped_steps"] == k0 + 1
         assert float(tr.scale_state.scale) == s0 * 0.5
         # subsequent steps trained through (finite losses, no divergence)
         assert np.isfinite([h["loss"] for h in tr.history[3:]]).all()

@@ -175,3 +175,82 @@ class TestShardedServing:
             env=env, capture_output=True, text=True, timeout=600)
         assert proc.returncode == 0, proc.stderr[-2000:]
         assert "MATCH" in proc.stdout
+
+
+_SHARDED_KERNELS_SCRIPT = r"""
+import dataclasses
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.core import get_policy
+from repro.dist import batch_specs, fno_param_specs, to_named, use_mesh
+from repro.models import (FNOConfig, SFNOConfig, fno_apply, init_fno,
+                          init_sfno, sfno_apply)
+from repro.train.losses import relative_l2
+
+policy = get_policy("full")
+mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"))
+rng = np.random.RandomState(0)
+cases = [
+    (FNOConfig(in_channels=1, out_channels=1, hidden_channels=8,
+               lifting_channels=8, projection_channels=8, n_layers=2,
+               modes=(4, 4), use_pallas=True, fuse_spectral=False),
+     init_fno, fno_apply, (1, 12, 12)),
+    (FNOConfig(in_channels=1, out_channels=1, hidden_channels=8,
+               lifting_channels=8, projection_channels=8, n_layers=2,
+               modes=(4, 4), factorization="cp", use_pallas=True),
+     init_fno, fno_apply, (1, 12, 12)),
+    (SFNOConfig(in_channels=3, out_channels=3, hidden_channels=8,
+                n_layers=2, nlat=8, nlon=16, lmax=4, mmax=4,
+                lifting_channels=8, projection_channels=8, use_pallas=True),
+     init_sfno, sfno_apply, (3, 8, 16)),
+]
+for cfg, init, apply, shape in cases:
+    params = init(jax.random.PRNGKey(0), cfg)
+    batch = {"x": jnp.asarray(rng.randn(8, *shape), jnp.float32),
+             "y": jnp.asarray(rng.randn(8, *shape), jnp.float32)}
+
+    def loss_fn(p, b):
+        return relative_l2(apply(p, b["x"], cfg, policy), b["y"])
+
+    grad = jax.value_and_grad(loss_fn)
+    l1, g1 = jax.jit(grad)(params, batch)
+    with use_mesh(mesh):
+        p_named = to_named(mesh, fno_param_specs(params, mesh))
+        b_named = to_named(mesh, batch_specs(batch, mesh))
+        sharded = jax.jit(grad, in_shardings=(p_named, b_named))
+        text = sharded.lower(params, batch).as_text()
+        # the kernels run per device inside a shard_map
+        assert "manual_computation" in text, "no shard_map around kernels"
+        l4, g4 = sharded(jax.device_put(params, p_named),
+                         jax.device_put(batch, b_named))
+    assert abs(float(l4) - float(l1)) <= 1e-5 * abs(float(l1)), (l4, l1)
+    for a, b in zip(jax.tree_util.tree_leaves(g4),
+                    jax.tree_util.tree_leaves(g1), strict=True):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-6)
+print("MATCH")
+"""
+
+
+class TestShardedKernels:
+    def test_pallas_kernels_under_a_mesh_match_one_device(self):
+        """Mosaic kernels cannot be partitioned by the compiler, so under
+        an ambient mesh the kernel wrappers run per batch shard in a
+        shard_map; loss and gradients (dense, CP and l-shared kernels)
+        must match the unsharded step.  Subprocess: the forced device
+        count must be set before jax initialises."""
+        import os
+        import subprocess
+        import sys
+
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        env["JAX_PLATFORMS"] = "cpu"
+        env["PYTHONPATH"] = os.pathsep.join(
+            [os.path.join(os.path.dirname(__file__), "..", "src")]
+            + env.get("PYTHONPATH", "").split(os.pathsep))
+        proc = subprocess.run(
+            [sys.executable, "-c", _SHARDED_KERNELS_SCRIPT],
+            env=env, capture_output=True, text=True, timeout=600)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "MATCH" in proc.stdout

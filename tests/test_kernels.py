@@ -101,8 +101,10 @@ class TestSpectralContractKernel:
         np.testing.assert_allclose(np.asarray(out_im), np.imag(want), rtol=1e-3, atol=1e-3)
 
     def test_vmem_budget_helper(self):
-        # default tile must sit well under 16 MiB
-        assert vmem_bytes(32, 64, 64, 64) < 4 * 2 ** 20
+        # the default tile (one 128-lane row of modes) at a 32-row batch
+        # of 64 channels fits the 16 MiB scoped VMEM limit, double
+        # buffers and f32 products included
+        assert vmem_bytes(32, 64, 64, 128) < 16 * 2 ** 20
 
 
 class TestFlashAttentionKernel:

@@ -233,14 +233,24 @@ class TestPaddingAndFallback:
 
     def test_pick_block_m_respects_budget(self):
         from repro.kernels.spectral_contract import (
-            cp_vmem_bytes, vmem_bytes, vmem_bytes_bwd)
+            VMEM_BUDGET, cp_vmem_bytes, vmem_bytes, vmem_bytes_bwd)
 
+        # the estimators count double-buffered blocks and the f32
+        # intermediates, so the pickers pack under the whole scoped limit;
+        # mode tiles are whole 128-lane rows (Mosaic's minor-dim rule)
         bm = pick_block_m(32, 64, 64, 4096)
-        assert bm in (8, 16, 32, 64, 128, 256, 512)
+        assert bm in (128, 256, 512)
         need = max(vmem_bytes(32, 64, 64, bm), vmem_bytes_bwd(32, 64, 64, bm))
-        assert need <= 8 * 2 ** 20
-        bm_cp = pick_block_m(32, 64, 64, 4096, rank=64)
-        assert cp_vmem_bytes(32, 64, 64, 64, bm_cp) <= 8 * 2 ** 20
+        assert need <= VMEM_BUDGET
+        # the CP kernel's rank-space arrays grow with the batch: the ops
+        # wrapper splits a batch whose smallest tile would not fit
+        from repro.kernels.ops import _batch_tile
+
+        bb = _batch_tile(64, lambda b: cp_vmem_bytes(b, 64, 64, 64, 128))
+        assert bb < 64
+        bm_cp = pick_block_m(bb, 64, 64, 4096, rank=64)
+        assert bm_cp in (128, 256, 512)
+        assert cp_vmem_bytes(bb, 64, 64, 64, bm_cp) <= VMEM_BUDGET
 
 
 # ---------------------------------------------------------------------------
@@ -699,3 +709,106 @@ class TestFusedMegakernel:
         with collecting(TraceCollector()):
             assert not ops.fused_spectral_viable(
                 fft_in, ctr, 2, 3, 4, (9, 11), (3, 5))
+
+
+class TestCompiledLayout:
+    """What the TPU path needs from the dispatch, checked on the CPU."""
+
+    @pytest.mark.parametrize("policy_name", ["full", "mixed_fno_bf16"])
+    @pytest.mark.parametrize("kind", ["dense", "cp"])
+    def test_batch_split_matches_one_call(self, kind, policy_name,
+                                          monkeypatch):
+        """A batch too large for one kernel call's VMEM runs as several
+        calls; values and gradients match one call (to f32 rounding at
+        f32 storage; to the policy's storage rounding otherwise, since
+        each call's weight gradient is stored at the storage dtype)."""
+        policy = get_policy(policy_name)
+        site = policy.at("fno/layer0/spectral/contract")
+        rng = np.random.RandomState(31)
+        B, I, O, modes, R = 6, 3, 4, (3, 5), 4
+        M = 15
+        x = _randc(rng, (B, I, *modes))
+        if kind == "dense":
+            ws = (_randc(rng, (I, O, *modes)),)
+
+            def call(x, *ws):
+                return ops.spectral_contract(x, ws[0], policy=site)
+
+            # at a budget this small the fused cast's f32 tiles do not
+            # fit, so half storage streams half operand tiles
+            item = 2 if site.spectral_is_half else 4
+
+            def need(b):
+                return max(ops.vmem_bytes(b, I, O, M, item),
+                           ops.vmem_bytes_bwd(b, I, O, M, item))
+        else:
+            ws = (_randc(rng, (R,)), _randc(rng, (I, R)), _randc(rng, (O, R)),
+                  *[_randc(rng, (m, R)) for m in modes])
+            item = jnp.dtype(site.spectral_dtype or jnp.float32).itemsize
+
+            def call(x, *ws):
+                return ops.spectral_contract_cp(x, ws[0], ws[1], ws[2],
+                                                list(ws[3:]), policy=site)
+
+            def need(b):
+                return ops.cp_vmem_bytes(b, I, O, R, M, item)
+
+        def loss(x, *ws):
+            y = call(x, *ws)
+            if isinstance(y, ComplexPair):
+                return jnp.sum(y.abs2())
+            return jnp.sum(jnp.abs(y) ** 2)
+
+        grad = jax.value_and_grad(loss, argnums=tuple(range(1 + len(ws))))
+        l1, g1 = grad(x, *ws)
+        # a budget that fits two batch rows, not six: three calls of two
+        monkeypatch.setattr(ops, "VMEM_BUDGET", need(2))
+        assert ops._batch_tile(B, need) == 2
+        fwd = str(jax.make_jaxpr(call)(x, *ws))
+        assert fwd.count("name=spectral_contract") == 3
+        l2, g2 = grad(x, *ws)
+        tol = GRAD_TOLS[policy_name]
+        assert abs(float(l2) - float(l1)) <= tol * abs(float(l1))
+        for a, b in zip(g2, g1, strict=True):
+            assert _rel_err(np.asarray(a), np.asarray(b)) <= tol
+
+    def test_fused_layout_condition(self):
+        from repro.kernels.spectral_contract import (
+            fused_exact, fused_layout_compiles, fused_supported)
+
+        # the compiled dispatch drops exactly what Mosaic cannot reshape
+        assert fused_exact((64, 64), (16, 16))
+        assert fused_supported((64, 64), (16, 16), compiled=False)
+        assert not fused_supported((64, 64), (16, 16), compiled=True)
+        assert fused_supported((8, 256), (2, 128), compiled=True)
+        assert fused_supported((64,), (16,), compiled=True)
+        assert fused_layout_compiles((16,)) and fused_layout_compiles((3, 128))
+        assert not fused_layout_compiles((3, 64))
+        # never admits a shape whose factorisation is not exact
+        assert not fused_supported((8, 8), (5, 3), compiled=False)
+        # None resolves from the backend the test runs on
+        assert fused_supported((64, 64), (16, 16)) == (
+            jax.default_backend() != "tpu")
+
+    def test_interpret_resolves_from_backend(self):
+        from repro.kernels.spectral_contract import resolve_interpret
+
+        assert resolve_interpret(True) is True
+        assert resolve_interpret(False) is False
+        assert resolve_interpret(None) == (jax.default_backend() != "tpu")
+
+    @pytest.mark.parametrize("B,I,O,M,rank,itemsize", [
+        (16, 64, 64, 1024, 0, 2), (16, 64, 64, 1764, 64, 2),
+        (2, 8, 8, 40, 0, 4), (4, 16, 16, 100, 8, 2)])
+    def test_mode_tiles_are_lane_legal(self, B, I, O, M, rank, itemsize):
+        """Mosaic tiles the minor dim in 128 lanes: a mode tile is a
+        multiple of 128 or the whole mode axis."""
+        bm = pick_block_m(B, I, O, M, rank=rank, itemsize=itemsize)
+        assert bm % 128 == 0 or bm == M
+
+    @pytest.mark.parametrize("L", [3, 8, 12, 128])
+    def test_degree_tiles_are_sublane_legal(self, L):
+        from repro.kernels.spectral_contract import pick_block_l
+
+        bl = pick_block_l(4, 64, 64, L, 128)
+        assert bl % 8 == 0 or bl == L

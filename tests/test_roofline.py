@@ -97,3 +97,47 @@ class TestRooflineTerms:
 
     def test_model_flops_6nd(self):
         assert model_flops(1e9, 1e6) == 6e15
+
+
+class TestPeakTableAndCompileCache:
+    def test_peaks_keyed_by_device_kind(self):
+        from repro.launch.roofline import HBM_BW, PEAKS
+
+        v5e = PEAKS["TPU v5 lite"]
+        assert v5e["hbm_bw"] == HBM_BW == 819e9
+        assert v5e["bf16_flops"] == 197e12
+        assert "cpu" not in PEAKS
+
+    def test_compile_cache_env_dir_is_left_to_jax(self, monkeypatch,
+                                                  tmp_path):
+        from repro.launch import compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+        assert compile_cache.place(tmp_path) == str(tmp_path / "c")
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_compile_cache_defaults_to_fixed_repo_dir(self, monkeypatch,
+                                                      tmp_path):
+        from repro.launch import compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        try:
+            got = compile_cache.place(tmp_path)
+            assert got == str(tmp_path.resolve() / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            # the same checkout always gets the same directory
+            assert compile_cache.place(tmp_path) == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_cache_counter_counts_monitoring_events(self):
+        from repro.launch import compile_cache
+
+        with compile_cache.CacheCounter() as c:
+            jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+            jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+            jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        assert c.counts == {"hits": 2, "misses": 1}
