@@ -268,7 +268,11 @@ def spectral_conv_apply(
 
     # 2. forward FFT in f32 (TPU has no half FFT); boundary quantisation
     #    models the half (or simulated fp8) representation per Thm 3.2.
-    xf = jnp.fft.rfftn(x.astype(jnp.float32), axes=tuple(range(2, 2 + ndim)))
+    #    named_scope: the device trace's operations keep the site, so the
+    #    transform's time can be told from the rest of the step.
+    x = x.astype(jnp.float32)
+    with jax.named_scope(f"{site}/fft_in"):
+        xf = jnp.fft.rfftn(x, axes=tuple(range(2, 2 + ndim)))
     xf = fft_in.quantize(xf)
 
     spectrum_shape = xf.shape[2:]

@@ -18,11 +18,15 @@ One spine for the evidence the paper's claim needs at production scale:
 ``python -m repro.obs`` renders a run summary table from a JSONL log
 and converts it to a Chrome trace or Prometheus snapshot.
 
-Span taxonomy (see README "Observability"): ``train/step``,
-``train/data``, ``train/telemetry``, ``train/controller``,
-``serve/tick``, ``serve/prefill``, ``serve/decode``,
-``serve/operator/batch``, plus per-request async phases
-``request``/``ttft`` correlated by uid.  Metric names follow
+Span taxonomy (see README "Observability"): ``train/data``,
+``train/step`` (with ``train/dispatch``, ``train/sync``),
+``train/telemetry``, ``train/controller``, ``serve/tick``,
+``serve/prefill``, ``serve/decode``, ``serve/operator/take``,
+``serve/operator/stage``, ``serve/operator/batch`` (with
+``serve/operator/dispatch``, ``serve/operator/fetch``),
+``serve/operator/answer``, plus per-request async phases
+``request``/``ttft`` correlated by uid.  Spans also reach the JAX
+profiler's trace while a profiler session records.  Metric names follow
 ``repro_<subsystem>_<name>``.
 """
 from .export import (  # noqa: F401

@@ -7,10 +7,20 @@ attributes device wall to the host phase that launched it.  Nothing here
 touches traced values — tracing a jitted function records the (one-off)
 trace, executing it records the dispatch+device wall.
 
-Design constraints (the <5% overhead budget of ``bench_obs``):
+A span has two sinks, each on only while its switch is:
 
-* **off is free**: ``span(...)`` / ``event(...)`` check one module-level
-  flag and return a shared no-op object — no allocation, no lock;
+* the ring below, while ``enable()`` is in force;
+* the JAX profiler's trace, while a profiler session is active
+  (``jax.profiler.start_trace`` or ``trace``): the span then opens a
+  ``jax.profiler.TraceAnnotation`` of the same name (attributes stay in
+  the ring), so it lies on the profiler's clock, nested in the caller's
+  annotations and beside the device operations it launched.
+
+Design constraints (what tracing costs on the chip is in PERF.md):
+
+* **off is free**: with neither sink on, ``span(...)`` checks the
+  module flag and the profiler's and returns a shared no-op object; no
+  allocation, no lock.  ``event(...)`` and friends check the flag alone;
 * **on is a ring buffer**: records land in a preallocated ring of
   fixed-slot lists (drop-oldest, ``dropped()`` counts what fell off);
   writing a record assigns slots in place — the only per-record
@@ -33,6 +43,8 @@ from __future__ import annotations
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 #: record slot layout: [kind, name, ts_ns, dur_ns, tid, depth, parent,
 #: category, id, attrs]
@@ -74,6 +86,8 @@ class _Ring:
 
 
 _enabled = False
+#: whether a profiler session is recording (one flag read in C++)
+_profiling = TraceAnnotation.is_enabled
 _ring: Optional[_Ring] = None
 _origin_ns = 0
 _local = threading.local()
@@ -137,7 +151,7 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "t0", "depth", "parent")
+    __slots__ = ("name", "attrs", "t0", "depth", "parent", "annotation")
 
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]]):
         self.name = name
@@ -148,11 +162,17 @@ class _Span:
         self.depth = len(st)
         self.parent = st[-1] if st else None
         st.append(self.name)
+        self.annotation = None
+        if _profiling():
+            self.annotation = TraceAnnotation(self.name)
+            self.annotation.__enter__()
         self.t0 = _now()
         return self
 
     def __exit__(self, *exc):
         t1 = _now()
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
         st = _stack()
         if st and st[-1] == self.name:
             st.pop()
@@ -168,9 +188,11 @@ def span(name: str, **attrs):
 
     Spans nest through a thread-local stack (depth + parent recorded);
     wrap device work together with its ``block_until_ready`` fence so
-    the duration includes device wall.  Free no-op while disabled.
+    the duration includes device wall.  Goes to the ring while enabled
+    and to the profiler's trace while a profiler session is active; a
+    free no-op while neither is on.
     """
-    if not _enabled:
+    if not (_enabled or _profiling()):
         return _NULL
     return _Span(name, attrs or None)
 

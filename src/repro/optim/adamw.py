@@ -42,32 +42,36 @@ class AdamW:
         )
 
     def update(self, grads, state: AdamWState, params, lr_scale=1.0):
-        """Returns (new_params, new_state).  grads may be half precision."""
-        grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
-        if self.grad_clip_norm is not None:
-            gnorm = global_norm(grads)
-            scale = jnp.minimum(1.0, self.grad_clip_norm / (gnorm + 1e-9))
-            grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+        """Returns (new_params, new_state).  grads may be half precision.
 
-        count = state.count + 1
-        b1, b2 = self.b1, self.b2
-        mu = jax.tree_util.tree_map(
-            lambda m, g: b1 * m + (1 - b1) * g, state.mu, grads
-        )
-        nu = jax.tree_util.tree_map(
-            lambda v, g: b2 * v + (1 - b2) * (g * g), state.nu, grads
-        )
-        c = count.astype(jnp.float32)
-        mu_hat_scale = 1.0 / (1 - b1 ** c)
-        nu_hat_scale = 1.0 / (1 - b2 ** c)
-        lr = self.lr * lr_scale
+        The operations carry the ``optim/adamw`` scope into the compiled
+        program, where a device trace can tell the optimizer's time."""
+        with jax.named_scope("optim/adamw"):
+            grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
+            if self.grad_clip_norm is not None:
+                gnorm = global_norm(grads)
+                scale = jnp.minimum(1.0, self.grad_clip_norm / (gnorm + 1e-9))
+                grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
 
-        def step(p, m, v):
-            upd = (m * mu_hat_scale) / (jnp.sqrt(v * nu_hat_scale) + self.eps)
-            return (p - lr * (upd + self.weight_decay * p)).astype(p.dtype)
+            count = state.count + 1
+            b1, b2 = self.b1, self.b2
+            mu = jax.tree_util.tree_map(
+                lambda m, g: b1 * m + (1 - b1) * g, state.mu, grads
+            )
+            nu = jax.tree_util.tree_map(
+                lambda v, g: b2 * v + (1 - b2) * (g * g), state.nu, grads
+            )
+            c = count.astype(jnp.float32)
+            mu_hat_scale = 1.0 / (1 - b1 ** c)
+            nu_hat_scale = 1.0 / (1 - b2 ** c)
+            lr = self.lr * lr_scale
 
-        new_params = jax.tree_util.tree_map(step, params, mu, nu)
-        return new_params, AdamWState(count=count, mu=mu, nu=nu)
+            def step(p, m, v):
+                upd = (m * mu_hat_scale) / (jnp.sqrt(v * nu_hat_scale) + self.eps)
+                return (p - lr * (upd + self.weight_decay * p)).astype(p.dtype)
+
+            new_params = jax.tree_util.tree_map(step, params, mu, nu)
+            return new_params, AdamWState(count=count, mu=mu, nu=nu)
 
 
 def global_norm(tree) -> jnp.ndarray:

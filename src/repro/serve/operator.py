@@ -211,31 +211,39 @@ class OperatorEngine(EngineBase):
         return keys, compute
 
     def _tick_impl(self) -> List[FieldRequest]:
-        batch = self.scheduler.take(
-            self.max_batch, self._ticks, bucket_key=lambda r: r.resolution)
+        # phase spans inside serve/tick: take, stage, batch (dispatch,
+        # fetch), answer; the engine's host path is the tick less fetch
+        with obs_trace.span("serve/operator/take"):
+            batch = self.scheduler.take(
+                self.max_batch, self._ticks,
+                bucket_key=lambda r: r.resolution)
         self._occupancy_sum += len(batch) / self.max_batch
         if not batch:
             return []
         res = batch[0].resolution
-        keys, compute = self._memo_partition(batch)
+        with obs_trace.span("serve/operator/stage"):
+            keys, compute = self._memo_partition(batch)
+            if compute:
+                xb = jnp.stack([jnp.asarray(batch[j].x, jnp.float32)
+                                for j in compute])
+                if len(compute) < self.max_batch:
+                    # pad to the fixed micro-batch width: one compiled
+                    # kernel per resolution (no recompiles as occupancy
+                    # fluctuates), and the per-sample outputs stay
+                    # independent of batch fill — a solo request and a
+                    # full batch produce bit-identical fields.
+                    pad = self.max_batch - len(compute)
+                    xb = jnp.concatenate(
+                        [xb, jnp.zeros((pad, *xb.shape[1:]), xb.dtype)])
         computed: Dict[str, np.ndarray] = {}
         if compute:
-            xb = jnp.stack([jnp.asarray(batch[j].x, jnp.float32)
-                            for j in compute])
-            if len(compute) < self.max_batch:
-                # pad to the fixed micro-batch width: one compiled kernel
-                # per resolution (no recompiles as occupancy fluctuates),
-                # and the per-sample outputs stay independent of batch
-                # fill — a solo request and a full batch produce
-                # bit-identical fields.
-                pad = self.max_batch - len(compute)
-                xb = jnp.concatenate([xb, jnp.zeros((pad, *xb.shape[1:]),
-                                                    xb.dtype)])
             with obs_trace.span("serve/operator/batch",
                                 resolution="x".join(map(str, res)),
                                 fill=len(compute)):
-                yb, telem = self._step_for(res)(self.params, xb)
-                yb = np.asarray(yb)[:len(compute)]
+                with obs_trace.span("serve/operator/dispatch"):
+                    yb, telem = self._step_for(res)(self.params, xb)
+                with obs_trace.span("serve/operator/fetch"):
+                    yb = np.asarray(yb)[:len(compute)]
             self._n_batches += 1
             if self._telem is not None:
                 self._telem.update(telem)
@@ -258,31 +266,30 @@ class OperatorEngine(EngineBase):
                     self.policy = self.controller.policy()
                     self._steps.clear()
                     self._memo.clear()
-            if keys is None:
-                computed = {str(j): yb[pos]
+        with obs_trace.span("serve/operator/answer"):
+            if compute:
+                computed = {str(j) if keys is None else keys[j]: yb[pos]
                             for pos, j in enumerate(compute)}
-            else:
-                computed = {keys[j]: yb[pos]
-                            for pos, j in enumerate(compute)}
-        key = "x".join(map(str, res))
-        self._bucket_counts[key] = self._bucket_counts.get(key, 0) + len(batch)
-        self._n_fields += len(batch)
-        self._n_points += int(np.prod(res, dtype=np.int64)) * len(batch)
-        finished = []
-        for j, r in enumerate(batch):
-            if keys is None:
-                r.y = computed[str(j)]
-            else:
-                r.y = computed.get(keys[j], self._memo.get(keys[j]))
-            finished.append(r)
-        if keys is not None:
-            # admit this tick's fresh results, then LRU-trim — after the
-            # batch is answered, so an admission never evicts a key a
-            # later request in the same tick still needs
-            self._memo.update(computed)
-            while len(self._memo) > self.memo_window:
-                self._memo.popitem(last=False)
-                self._memo_evictions += 1
+            key = "x".join(map(str, res))
+            self._bucket_counts[key] = (self._bucket_counts.get(key, 0)
+                                        + len(batch))
+            self._n_fields += len(batch)
+            self._n_points += int(np.prod(res, dtype=np.int64)) * len(batch)
+            finished = []
+            for j, r in enumerate(batch):
+                if keys is None:
+                    r.y = computed[str(j)]
+                else:
+                    r.y = computed.get(keys[j], self._memo.get(keys[j]))
+                finished.append(r)
+            if keys is not None:
+                # admit this tick's fresh results, then LRU-trim — after
+                # the batch is answered, so an admission never evicts a
+                # key a later request in the same tick still needs
+                self._memo.update(computed)
+                while len(self._memo) > self.memo_window:
+                    self._memo.popitem(last=False)
+                    self._memo_evictions += 1
         return finished
 
     def _extra_stats(self) -> Dict[str, Any]:

@@ -77,10 +77,11 @@ class TrainerConfig:
     calibration_state: Optional[str] = None
     #: Observability (``repro.obs``): turn on the global trace ring (if
     #: not already on) and record per-step phase spans (``train/data``,
-    #: ``train/step``, ``train/telemetry``, ``train/controller``),
-    #: loss-scale numerics events, and step-wall histograms in the
-    #: metrics registry.  The span calls themselves are free no-ops when
-    #: this is off — bench_obs holds the on/off delta under 5%.
+    #: ``train/step`` with ``train/dispatch`` and ``train/sync``,
+    #: ``train/telemetry``, ``train/controller``), loss-scale numerics
+    #: events, and step-wall histograms in the metrics registry.  The
+    #: span calls are free no-ops when this is off and no profiler
+    #: session is active; what they cost on the chip is in PERF.md.
     obs: bool = False
 
 
@@ -333,15 +334,20 @@ class Trainer:
                 batch = batch_fn(self.step)
             t0 = time.perf_counter()
             # the span brackets the host call plus the float(loss) sync,
-            # so its duration carries the device wall of the step
+            # so its duration carries the device wall of the step:
+            # train/dispatch is the host's call (the batch's transfer
+            # starts there), train/sync the wait on the device
             with obs_trace.span("train/step", step=self.step,
                                 policy=policy.name):
-                self.params, self.opt_state, self.scale_state, loss, finite, telem = fn(
-                    self.params, self.opt_state, self.scale_state, batch
-                )
-                loss = float(loss)
+                with obs_trace.span("train/dispatch"):
+                    self.params, self.opt_state, self.scale_state, loss, finite, telem = fn(
+                        self.params, self.opt_state, self.scale_state, batch
+                    )
+                with obs_trace.span("train/sync"):
+                    loss = float(loss)
+                    finite = bool(finite)
             dt = time.perf_counter() - t0
-            if not bool(finite):
+            if not finite:
                 self.stats["skipped_steps"] += 1
             if ewma is not None and dt > self.cfg.straggler_factor * ewma:
                 self.stats["straggler_steps"] += 1

@@ -9,6 +9,7 @@ rerun to re-record after an intentional schema change.
 """
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +72,24 @@ class TestTrace:
         with s1:
             trace.event("x")
         assert trace.snapshot() == []
+
+    def test_off_without_profiler_is_the_shared_null(self):
+        assert not jax.profiler.TraceAnnotation.is_enabled()
+        assert trace.span("train/step", step=0) is trace._NULL
+
+    def test_profiler_session_gets_live_spans_ring_stays_empty(self, tmp_path):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            s = trace.span("serve/tick", tick=3)
+            assert s is not trace._NULL
+            with s:
+                with trace.span("serve/operator/take") as inner:
+                    assert inner.parent == "serve/tick"
+        finally:
+            jax.profiler.stop_trace()
+        # the ring is obs's own sink: off unless enabled
+        assert trace.snapshot() == []
+        assert trace.span("serve/tick") is trace._NULL
 
     def test_span_nesting_records_depth_and_parent(self):
         trace.enable()
@@ -398,6 +417,20 @@ class TestTrainerWiring:
         assert snap["histograms"]["repro_train_step_wall_ms"]["count"] == 2
         # publish_stats ran at end of run
         assert snap["gauges"]["repro_train_step"] == 2.0
+
+    def test_step_hlo_carries_fft_and_optimizer_scopes(self):
+        """The compiled step keeps the spectral layers' FFT scopes and the
+        optimizer's in its operations' ``op_name``: what a device trace
+        reads them from (the backward under ``transpose(...)``)."""
+        tr = _tiny_trainer()
+        batch = {"x": jnp.zeros((2, 1, 16, 16)),
+                 "t": jnp.zeros((2, 1, 16, 16))}
+        text = tr.lower(batch).compile().as_text()
+        names = set(re.findall(r'op_name="([^"]*)"', text))
+        for scope in ("/spectral/fft_in", "/spectral/fft_out", "optim/adamw"):
+            assert any(scope in n for n in names), scope
+        assert any("transpose(" in n and "/spectral/fft_in" in n
+                   for n in names)
 
     def test_obs_off_trainer_records_nothing(self):
         cfg = FNOConfig(in_channels=1, out_channels=1, hidden_channels=8,
