@@ -14,6 +14,14 @@ theory actually analyses — and matches what the MXU pipeline does: bf16
 storage, f32 accumulation.  The contraction genuinely executes at half
 precision via split-real einsums (``core.contraction``), optionally through
 the Pallas kernel (``repro.kernels``).
+
+On a TPU the staged path computes only the retained modes: the forward
+transform is a truncated DFT and the inverse starts from the retained
+corners, both as separable matmuls on the MXU at f32 ``Precision.HIGHEST``
+(``truncated_rfft`` / ``truncated_irfft``).  The TPU's FFT lowering would
+compute the whole spectrum and transform a zero-filled one back, most of
+a training step at the paper's widths.  Elsewhere, and where the corner
+blocks overlap, the path runs ``rfftn`` / ``irfftn``.
 """
 from __future__ import annotations
 
@@ -22,6 +30,7 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .precision import ComplexPair
 from repro.precision import FULL, PrecisionPolicy
@@ -197,6 +206,106 @@ def _corner_weight_ops(params: dict, corner: int, ndim: int):
 
 
 # ---------------------------------------------------------------------------
+# Truncated transforms as DFT matmuls (the TPU path)
+# ---------------------------------------------------------------------------
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def dft_transforms(spatial: Sequence[int], modes: Sequence[int]) -> bool:
+    """Whether the staged path runs its transforms as truncated-DFT
+    matmuls: on a TPU, and where the factorisation is exact (the corner
+    blocks do not overlap, where the FFT path's scatter would overwrite
+    what a sum of corners adds)."""
+    from repro.kernels.spectral_contract import fused_exact
+
+    return _on_tpu() and fused_exact(tuple(spatial), tuple(modes))
+
+
+def _full_axis_order(spatial: Sequence[int], modes: Sequence[int]):
+    """The full (non-rfft) axes in the order that costs the forward the
+    fewest FLOPs.  Transforming axis k costs the current size times m_k
+    and leaves the size shrunk by r_k = 2m_k/S_k, so by exchange of
+    neighbours the cheapest order sorts by m_k / (1 - r_k); the inverse
+    runs the reverse order."""
+    def cost(k):
+        r = 2 * modes[k] / spatial[k]
+        return math.inf if r >= 1 else modes[k] / (1 - r)
+
+    return sorted(range(len(modes) - 1), key=cost)
+
+
+def _complex_axis(re, im, mr, mi, axis: int):
+    """Apply the complex matrix ``mr + i·mi`` (rows out, columns in) along
+    ``axis`` of ``re + i·im``: one real matmul of ``[re; im]`` against its
+    real block form, whose output holds the real then the imaginary
+    rows."""
+    blk = np.block([[mr.T, mi.T], [-mi.T, mr.T]]).astype(np.float32)
+    z = jnp.tensordot(jnp.concatenate([re, im], axis=axis), blk,
+                      axes=[[axis], [0]], precision=jax.lax.Precision.HIGHEST)
+    return jnp.split(jnp.moveaxis(z, -1, axis), 2, axis=axis)
+
+
+def truncated_rfft(x: jnp.ndarray, modes: Sequence[int]) -> jnp.ndarray:
+    """The retained modes of ``rfftn(x)`` over the spatial axes of ``x``
+    (batch, ch, *spatial), computed as DFT matmuls: complex64 of shape
+    (batch, ch, 2m_0, ..., 2m_{d-2}, m_{d-1}).  Each full axis holds its
+    low block ``[:m]`` then its high block ``[-m:]``, so
+    ``_corner_slices(modes, result.shape[2:])`` cuts the corners of
+    ``_corner_slices(modes, rfftn(x).shape[2:])``.  The last (rfft) axis,
+    which shrinks most, goes first, as one real matmul against
+    ``[F_re | F_im]``; every matmul runs at f32 ``Precision.HIGHEST``."""
+    from repro.kernels.spectral_contract import fused_factors
+
+    ndim = len(modes)
+    spatial = x.shape[2:]
+    facs = fused_factors(spatial, modes)
+    fr, fi = facs[2 * ndim - 2], facs[2 * ndim - 1]
+    f = np.concatenate([fr.T, fi.T], axis=1).astype(np.float32)
+    z = jnp.tensordot(x, f, axes=[[x.ndim - 1], [0]],
+                      precision=jax.lax.Precision.HIGHEST)
+    re, im = jnp.split(z, 2, axis=-1)
+    for k in _full_axis_order(spatial, modes):
+        re, im = _complex_axis(re, im, facs[2 * k], facs[2 * k + 1], 2 + k)
+    return jax.lax.complex(re, im)
+
+
+def truncated_irfft(yh: jnp.ndarray, spatial: Sequence[int],
+                    modes: Sequence[int]) -> jnp.ndarray:
+    """``irfftn`` over ``spatial`` of the spectrum that holds the stacked
+    corners ``yh`` (batch, ch, 2m_0, ..., m_{d-1}; the layout
+    ``truncated_rfft`` returns) and zeros elsewhere, computed as
+    inverse-DFT matmuls from the corners alone: the full axes first
+    (2m -> S), then the real-output ``C_re``/``C_im`` pair, with its
+    hermitian weights, along the last axis.  f32 ``Precision.HIGHEST``."""
+    from repro.kernels.spectral_contract import fused_factors
+
+    ndim = len(modes)
+    inv = fused_factors(spatial, modes)[2 * ndim:]
+    re, im = jnp.real(yh), jnp.imag(yh)
+    for k in reversed(_full_axis_order(spatial, modes)):
+        re, im = _complex_axis(re, im, inv[2 * k].T, inv[2 * k + 1].T, 2 + k)
+    c = np.concatenate([inv[-2], inv[-1]], axis=0).astype(np.float32)
+    return jnp.tensordot(jnp.concatenate([re, im], axis=-1), c,
+                         axes=[[yh.ndim - 1], [0]],
+                         precision=jax.lax.Precision.HIGHEST)
+
+
+def _stack_corners(corners, ndim: int) -> jnp.ndarray:
+    """Join the per-corner blocks (``_corner_slices`` order: bit k of the
+    corner index picks the high block on axis k) into the stacked layout
+    of ``truncated_rfft``, the highest bit's axis first."""
+    for k in reversed(range(ndim - 1)):
+        half = len(corners) // 2
+        corners = [jnp.concatenate([lo, hi], axis=2 + k)
+                   for lo, hi in zip(corners[:half], corners[half:],
+                                     strict=True)]
+    return corners[0]
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
@@ -214,10 +323,12 @@ def spectral_conv_apply(
 
     Pipeline (Fig. 2): [stabiliser] -> FFT -> quantise -> truncate ->
     contract (memory-greedy, split-real half) -> scatter -> dequantise ->
-    iFFT.  Each stage resolves its precision through the rule table at
-    ``{site}/fft_in``, ``{site}/contract`` and ``{site}/fft_out`` — callers
-    pass a per-layer prefix (``"fno/layer2/spectral"``) so layers can be
-    addressed individually.  Under the ``full`` rule set every site
+    iFFT.  Where ``dft_transforms`` holds (a TPU), FFT + truncate is
+    ``truncated_rfft`` and scatter + iFFT is ``truncated_irfft``, under
+    the same scopes.  Each stage resolves its precision through the rule
+    table at ``{site}/fft_in``, ``{site}/contract`` and ``{site}/fft_out``
+    — callers pass a per-layer prefix (``"fno/layer2/spectral"``) so
+    layers can be addressed individually.  Under the ``full`` rule set every site
     resolves to f32/complex64 and this is the exact full-precision FNO
     reference.
 
@@ -266,21 +377,32 @@ def spectral_conv_apply(
     # 1. stabiliser before the forward FFT (only active for half spectral)
     x = fft_in.stabilize(x)
 
-    # 2. forward FFT in f32 (TPU has no half FFT); boundary quantisation
-    #    models the half (or simulated fp8) representation per Thm 3.2.
-    #    named_scope: the device trace's operations keep the site, so the
-    #    transform's time can be told from the rest of the step.
+    # 2. forward transform in f32 (TPU has no half FFT); boundary
+    #    quantisation models the half (or simulated fp8) representation per
+    #    Thm 3.2.  It is elementwise with no per-tensor scale, so on the DFT
+    #    path, which computes only the retained modes, it rounds every value
+    #    that reaches the output as the FFT path does.  named_scope: the
+    #    device trace's operations keep the site, so the transform's time
+    #    can be told from the rest of the step.
+    dft = dft_transforms(spatial, modes)
+    # counted at trace time, one per layer body traced (a layer scan
+    # traces its body once), as repro_kernels_calls_total counts kernels
+    from repro.obs import registry
+
+    registry().counter("repro_spectral_transforms_total",
+                       path="dft" if dft else "fft").inc()
     x = x.astype(jnp.float32)
     with jax.named_scope(f"{site}/fft_in"):
-        xf = jnp.fft.rfftn(x, axes=tuple(range(2, 2 + ndim)))
+        if dft:
+            xf = truncated_rfft(x, modes)
+        else:
+            xf = jnp.fft.rfftn(x, axes=tuple(range(2, 2 + ndim)))
     xf = fft_in.quantize(xf)
 
     spectrum_shape = xf.shape[2:]
     corners = _corner_slices(modes, spectrum_shape)
 
-    out_channels = _out_channels(params)
-    out_f = jnp.zeros((x.shape[0], out_channels, *spectrum_shape), jnp.complex64)
-
+    ycs = []
     for c, sl in enumerate(corners):
         xc = xf[(slice(None), slice(None), *sl)]
         ops, expr = _corner_weight_ops(params, c, ndim)
@@ -300,12 +422,21 @@ def spectral_conv_apply(
             yc = ctr.contract(expr, xc, *ops)
         if isinstance(yc, ComplexPair):
             yc = yc.to_complex()
-        out_f = out_f.at[(slice(None), slice(None), *sl)].set(yc.astype(jnp.complex64))
+        ycs.append(yc.astype(jnp.complex64))
+    if not dft:
+        out_f = jnp.zeros((x.shape[0], _out_channels(params),
+                           *spectrum_shape), jnp.complex64)
+        for yc, sl in zip(ycs, corners, strict=True):
+            out_f = out_f.at[(slice(None), slice(None), *sl)].set(yc)
 
-    # 3. inverse FFT back to physical space.  named_scope: the analyzer
-    # attributes the iFFT/storage-cast eqns to the fft_out site.
+    # 3. inverse transform back to physical space.  named_scope: the
+    # analyzer attributes the iFFT/storage-cast eqns to the fft_out site.
     with jax.named_scope(f"{site}/fft_out"):
-        y = jnp.fft.irfftn(out_f, s=spatial, axes=tuple(range(2, 2 + ndim)))
+        if dft:
+            y = truncated_irfft(_stack_corners(ycs, ndim), spatial, modes)
+        else:
+            y = jnp.fft.irfftn(out_f, s=spatial,
+                               axes=tuple(range(2, 2 + ndim)))
         from repro.autoprec.telemetry import fmt_of, tap
 
         tap(f"{site}/fft_out", y, fmt=fmt_of(fft_out))

@@ -105,6 +105,28 @@ class TestCollector:
         assert "fno/layer0/spectral/contract" in snap_e
         assert "fno/layer0/spectral/fft_out" in snap_e
 
+    def test_dft_transforms_keep_spectral_sites(self, monkeypatch):
+        """On the truncated-DFT transforms a TPU takes, the collector still
+        sees every spectral site of the layer; ``fft_in`` now taps the
+        retained modes, the only quantised values that reach the output."""
+        from repro.core import spectral
+
+        monkeypatch.setattr(spectral, "_on_tpu", lambda: True)
+        cfg = FNOConfig(in_channels=1, out_channels=1, hidden_channels=8,
+                        lifting_channels=8, projection_channels=8,
+                        n_layers=1, modes=(4, 4))
+        params = init_fno(jax.random.PRNGKey(0), cfg)
+        x = jnp.asarray(np.random.RandomState(0).randn(2, 1, 16, 16),
+                        jnp.float32)
+        col = TraceCollector()
+        with collecting(col):
+            fno_apply(params, x, cfg, get_policy("mixed_fno_bf16"))
+        snap = col.snapshot()
+        for stage in ("fft_in", "contract", "fft_out"):
+            assert f"fno/layer0/spectral/{stage}" in snap
+        # 2 x 8 channels x (2*4 x 4) retained modes, re and im
+        assert float(snap["fno/layer0/spectral/fft_in"].n) == 2 * 8 * 32 * 2
+
     def test_aggregator_window_and_totals(self):
         agg = TelemetryAggregator()
         col = TraceCollector(hist_stride=1)

@@ -127,6 +127,150 @@ class TestSpectralConv:
         assert rel < 0.05, rel
 
 
+def _force_tpu_transforms(monkeypatch):
+    """Make the backend predicate read "tpu", so the staged path takes the
+    truncated-DFT transforms on the CPU (kernels stay interpreted)."""
+    from repro.core import spectral
+
+    monkeypatch.setattr(spectral, "_on_tpu", lambda: True)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (pjit, custom_vjp, scan)
+    included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                if hasattr(sub, "jaxpr") and hasattr(sub.jaxpr, "eqns"):
+                    yield from _eqns(sub.jaxpr)
+                elif hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _transforms_total(path):
+    from repro.obs import registry
+
+    key = f'repro_spectral_transforms_total{{path="{path}"}}'
+    return registry().snapshot()["counters"].get(key, 0.0)
+
+
+class TestDFTPath:
+    """The staged path's truncated-DFT transforms (taken on a TPU) against
+    its FFT path, layer by layer."""
+
+    #: full: float32 transforms that differ only in rounding order (~1e-7
+    #: of the signal).  mixed_fno_bf16: those differences move a value
+    #: across a bfloat16 rounding boundary now and then, one bfloat16
+    #: step (2^-8) on that value, a small share of the values.
+    RTOL = {"full": 1e-5, "mixed_fno_bf16": 2.0 ** -8}
+
+    @pytest.mark.parametrize("policy_name", ["full", "mixed_fno_bf16"])
+    @pytest.mark.parametrize("fact,use_pallas", [
+        ("dense", False), ("dense", True), ("cp", True)],
+        ids=["dense-einsum", "dense-pallas", "cp-pallas"])
+    def test_layer_matches_fft_path(self, monkeypatch, fact, use_pallas,
+                                    policy_name):
+        rng = np.random.RandomState(7)
+        params = init_spectral_weights(jax.random.PRNGKey(7), 4, 6, (4, 5),
+                                       factorization=fact)
+        x = _x(rng, (2, 4, 16, 16))
+        r = _x(rng, (2, 6, 16, 16))
+        policy = get_policy(policy_name)
+
+        def run(p):
+            def loss(p):
+                y = spectral_conv_apply(p, x, (4, 5), policy,
+                                        use_pallas=use_pallas,
+                                        fuse_spectral=False)
+                return jnp.sum(y.astype(jnp.float32) * r), y
+
+            (_, y), g = jax.value_and_grad(loss, has_aux=True)(p)
+            return y, g
+
+        y_fft, g_fft = run(params)
+        _force_tpu_transforms(monkeypatch)
+        y_dft, g_dft = run(params)
+        tol = self.RTOL[policy_name]
+
+        def rel(a, b):
+            a = np.asarray(a, np.float32)
+            b = np.asarray(b, np.float32)
+            return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+        assert y_dft.dtype == y_fft.dtype
+        assert rel(y_dft, y_fft) <= tol
+        for k in g_fft:
+            assert rel(g_dft[k], g_fft[k]) <= tol, k
+
+    def test_jaxpr_has_no_fft_and_keeps_scopes(self, monkeypatch):
+        """No ``fft`` primitive on the DFT path, and the transforms keep the
+        ``{site}/fft_in`` / ``{site}/fft_out`` named scopes that the
+        benchmark's ``fft_ms.train`` reads from the device trace."""
+        params = init_spectral_weights(jax.random.PRNGKey(0), 4, 4, (4, 4))
+        x = jnp.zeros((2, 4, 16, 16), jnp.float32)
+        site = "fno/layer0/spectral"
+
+        def census():
+            # a new function each time: make_jaxpr caches by function
+            def fwd_bwd(p, x):
+                return jax.grad(lambda p: jnp.sum(spectral_conv_apply(
+                    p, x, (4, 4), MIXED_FNO_BF16, use_pallas=False,
+                    site=site)))(p)
+
+            eqns = list(_eqns(jax.make_jaxpr(fwd_bwd)(params, x).jaxpr))
+            prims = {e.primitive.name for e in eqns}
+            scopes = {str(e.source_info.name_stack) for e in eqns
+                      if e.primitive.name == "dot_general"}
+            return prims, scopes
+
+        prims, _ = census()
+        assert "fft" in prims
+        _force_tpu_transforms(monkeypatch)
+        prims, scopes = census()
+        assert "fft" not in prims
+        assert any(f"{site}/fft_in" in s for s in scopes), scopes
+        assert any(f"{site}/fft_out" in s for s in scopes), scopes
+
+    def test_overlapping_corners_keep_fft_path(self, monkeypatch):
+        """Where the low and high blocks overlap (2m > S) the factorisation
+        is not exact: the FFT path runs, even on a TPU."""
+        from repro.core.spectral import dft_transforms
+
+        _force_tpu_transforms(monkeypatch)
+        assert dft_transforms((16, 16), (8, 9))
+        assert not dft_transforms((12, 12), (7, 4))
+        params = init_spectral_weights(jax.random.PRNGKey(0), 2, 2, (7, 4))
+        x = jnp.zeros((1, 2, 12, 12), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda x: spectral_conv_apply(
+            params, x, (7, 4), FULL, use_pallas=False))(x)
+        assert "fft" in {e.primitive.name for e in _eqns(jaxpr.jaxpr)}
+
+    def test_transform_counter_per_layer_and_path(self, monkeypatch):
+        """``repro_spectral_transforms_total`` counts one per layer traced,
+        under the path it took."""
+        params = init_spectral_weights(jax.random.PRNGKey(0), 2, 2, (4, 4))
+        x = jnp.zeros((1, 2, 16, 16), jnp.float32)
+
+        def trace(spatial_x, modes):
+            jax.make_jaxpr(lambda x: spectral_conv_apply(
+                params, x, modes, FULL, use_pallas=False))(spatial_x)
+
+        before = {p: _transforms_total(p) for p in ("dft", "fft")}
+        trace(x, (4, 4))
+        assert _transforms_total("fft") == before["fft"] + 1
+        _force_tpu_transforms(monkeypatch)
+        trace(x, (4, 4))
+        trace(x, (4, 4))
+        assert _transforms_total("dft") == before["dft"] + 2
+        over = init_spectral_weights(jax.random.PRNGKey(0), 2, 2, (7, 4))
+        jax.make_jaxpr(lambda x: spectral_conv_apply(
+            over, x, (7, 4), FULL, use_pallas=False))(
+                jnp.zeros((1, 2, 12, 12), jnp.float32))
+        assert _transforms_total("fft") == before["fft"] + 2
+        assert _transforms_total("dft") == before["dft"] + 2
+
+
 class TestTheory:
     def test_disc_error_within_bounds_1d(self):
         v = lambda x: np.prod(x, axis=-1)  # the lower-bound witness v(x)=x1..xd

@@ -543,11 +543,13 @@ class TestCLI:
 # ---------------------------------------------------------------------------
 
 
-#: kernel-call series carry (family=...) labels that depend on which
-#: compiled paths a leg traces (REPRO_USE_PALLAS) — excluded from the
-#: schema golden so both CI legs pin the same key set.
+#: kernel-call and spectral-transform series carry (family=... /
+#: path=...) labels that depend on which compiled paths a leg traces
+#: (REPRO_USE_PALLAS; the megakernel runs no staged transform) —
+#: excluded from the schema golden so both CI legs pin the same key set.
 _VOLATILE_PREFIXES = ("repro_kernels_calls_total",
-                      "repro_kernels_bytes_moved")
+                      "repro_kernels_bytes_moved",
+                      "repro_spectral_transforms_total")
 
 
 def _golden_names():

@@ -207,14 +207,16 @@ def test_fno_darcy_train_step(one_chip, monkeypatch, policy_name):
     with the Pallas path forced on and compiled, under the paper's mixed
     policy and under ``full`` (f32 operand tiles, the largest dense
     kernel working set): it must compile, hold Mosaic kernels, and fit
-    one chip's HBM."""
-    from repro.core import get_policy
+    one chip's HBM.  The spectral transforms take the TPU's path,
+    truncated-DFT matmuls, so no ``fft`` is left in the program."""
+    from repro.core import get_policy, spectral
     from repro.kernels import ops
     from repro.models import fno_apply, init_fno
     from repro.optim import AdamW
     from repro.train.losses import relative_l2
 
     monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    monkeypatch.setattr(spectral, "_on_tpu", lambda: True)
     cfg = dataclasses.replace(FNO_DARCY, use_pallas=True)
     policy = get_policy(policy_name)
     opt = AdamW(lr=1e-3)
@@ -238,7 +240,9 @@ def test_fno_darcy_train_step(one_chip, monkeypatch, policy_name):
         new_p, new_o = opt.update(grads, o, p)
         return new_p, new_o, loss
 
-    compiled = jax.jit(step).lower(params, opt_state, batch).compile()
+    lowered = jax.jit(step).lower(params, opt_state, batch)
+    assert "stablehlo.fft" not in lowered.as_text()
+    compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
