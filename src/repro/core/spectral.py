@@ -248,6 +248,33 @@ def _complex_axis(re, im, mr, mi, axis: int):
     return jnp.split(jnp.moveaxis(z, -1, axis), 2, axis=axis)
 
 
+def rfft_rows(x: jnp.ndarray, m: int) -> jnp.ndarray:
+    """The first ``m`` orders of ``rfft`` along the last axis of the real
+    ``x``, as one real matmul against ``[F_re^T | F_im^T]`` at f32
+    ``Precision.HIGHEST``: shape (..., 2m), the real parts then the
+    imaginary ones."""
+    from repro.kernels.spectral_contract import fused_factors
+
+    fr, fi = fused_factors((x.shape[-1],), (m,))[:2]
+    f = np.concatenate([fr.T, fi.T], axis=1).astype(np.float32)
+    return jnp.tensordot(x, f, axes=[[x.ndim - 1], [0]],
+                         precision=jax.lax.Precision.HIGHEST)
+
+
+def irfft_rows(z: jnp.ndarray, n: int) -> jnp.ndarray:
+    """``irfft(·, n)`` along the last axis of the spectrum whose first
+    ``m`` orders ``z`` (..., 2m) holds, real parts then imaginary ones
+    (the layout ``rfft_rows`` returns), and zeros above: one real matmul
+    against the real-output pair ``[C_re; C_im]`` with its hermitian
+    weights, at f32 ``Precision.HIGHEST``."""
+    from repro.kernels.spectral_contract import fused_factors
+
+    cr, ci = fused_factors((n,), (z.shape[-1] // 2,))[2:]
+    c = np.concatenate([cr, ci], axis=0).astype(np.float32)
+    return jnp.tensordot(z, c, axes=[[z.ndim - 1], [0]],
+                         precision=jax.lax.Precision.HIGHEST)
+
+
 def truncated_rfft(x: jnp.ndarray, modes: Sequence[int]) -> jnp.ndarray:
     """The retained modes of ``rfftn(x)`` over the spatial axes of ``x``
     (batch, ch, *spatial), computed as DFT matmuls: complex64 of shape
@@ -259,14 +286,9 @@ def truncated_rfft(x: jnp.ndarray, modes: Sequence[int]) -> jnp.ndarray:
     ``[F_re | F_im]``; every matmul runs at f32 ``Precision.HIGHEST``."""
     from repro.kernels.spectral_contract import fused_factors
 
-    ndim = len(modes)
     spatial = x.shape[2:]
     facs = fused_factors(spatial, modes)
-    fr, fi = facs[2 * ndim - 2], facs[2 * ndim - 1]
-    f = np.concatenate([fr.T, fi.T], axis=1).astype(np.float32)
-    z = jnp.tensordot(x, f, axes=[[x.ndim - 1], [0]],
-                      precision=jax.lax.Precision.HIGHEST)
-    re, im = jnp.split(z, 2, axis=-1)
+    re, im = jnp.split(rfft_rows(x, modes[-1]), 2, axis=-1)
     for k in _full_axis_order(spatial, modes):
         re, im = _complex_axis(re, im, facs[2 * k], facs[2 * k + 1], 2 + k)
     return jax.lax.complex(re, im)
@@ -287,10 +309,7 @@ def truncated_irfft(yh: jnp.ndarray, spatial: Sequence[int],
     re, im = jnp.real(yh), jnp.imag(yh)
     for k in reversed(_full_axis_order(spatial, modes)):
         re, im = _complex_axis(re, im, inv[2 * k].T, inv[2 * k + 1].T, 2 + k)
-    c = np.concatenate([inv[-2], inv[-1]], axis=0).astype(np.float32)
-    return jnp.tensordot(jnp.concatenate([re, im], axis=-1), c,
-                         axes=[[yh.ndim - 1], [0]],
-                         precision=jax.lax.Precision.HIGHEST)
+    return irfft_rows(jnp.concatenate([re, im], axis=-1), spatial[-1])
 
 
 def _stack_corners(corners, ndim: int) -> jnp.ndarray:

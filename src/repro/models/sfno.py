@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from repro.core import PrecisionPolicy, FULL, ComplexPair
 from repro.dist.constrain import constrain_spatial
 from .fno import _linear, _linear_init, apply_block_loop
-from .sht import sht_forward, sht_inverse
+from .sht import longitude_dft, sht_forward, sht_inverse
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,8 +74,21 @@ def _spherical_conv(h, w, cfg: SFNOConfig, policy: PrecisionPolicy,
     fft_in = policy.at(f"{site}/fft_in")
     ctr = policy.at(f"{site}/contract")
     fft_out = policy.at(f"{site}/fft_out")
-    coeffs = sht_forward(fft_in.stabilize(h).astype(jnp.float32),
-                         cfg.lmax, cfg.mmax, precision=fft_in)  # (B,C,l,m)
+    # counted at trace time, one per spherical layer body traced (a layer
+    # scan traces its body once), as core/spectral.py counts its layers
+    from repro.obs import registry
+
+    registry().counter("repro_sht_transforms_total",
+                       path="dft" if longitude_dft(cfg.nlon, cfg.mmax)
+                       else "fft").inc()
+    h = fft_in.stabilize(h).astype(jnp.float32)
+    # named_scope: the device trace's operations keep the site, so the
+    # transform's time can be told from the rest of the step.  The
+    # transform runs in f32; the spectrum is then boundary-quantised onto
+    # the site's storage grid (Thm 3.2's representation error).
+    with jax.named_scope(f"{site}/fft_in"):
+        coeffs = sht_forward(h, cfg.lmax, cfg.mmax)  # (B,C,l,m)
+    coeffs = fft_in.quantize(coeffs)
     wc = jax.lax.complex(w["w_re"], w["w_im"])  # (i, o, l)
     from repro.kernels.ops import resolve_use_pallas
 

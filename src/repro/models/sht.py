@@ -1,11 +1,24 @@
 """Real spherical harmonic transform (SHT) built from scratch:
-longitude FFT + per-order Legendre matmul on a Gauss-Legendre grid.
+longitude transform + per-order Legendre matmul on a Gauss-Legendre grid.
 
 This is the substrate SFNO (Bonev et al. 2023) needs; torch-harmonics is
 not available in JAX, and re-deriving it makes the spherical path
 matmul-dominant — exactly the structure the paper's mixed-precision
 contraction accelerates (the Legendre transform is a (lat × l) GEMM per
 order m, batched over channels).
+
+Both stages run in float32 as real matmuls at ``Precision.HIGHEST`` (on
+a TPU a matmul at the default precision is one bfloat16 pass):
+
+* the Legendre transform multiplies the real and the imaginary parts of
+  the spectrum by the same real matrix ``P`` (``P·w`` for analysis), with
+  the contracted axis (latitude, or degree for synthesis) in the matmul's
+  contracting position and the order m as its batch axis;
+* the longitude transform keeps only the ``mmax`` retained orders.  On a
+  TPU it runs as truncated-DFT matmuls (``core.spectral.rfft_rows`` /
+  ``irfft_rows``; the TPU's FFT computes every order of the row, and at
+  SFNO_SWE's shape the matmuls are the faster), elsewhere as
+  ``jnp.fft.rfft`` / ``irfft`` (``longitude_dft`` selects).
 
 Conventions: fully-normalised spherical harmonics Y_lm = P̄_lm(cosθ)e^{imφ}
 with ∫|Y_lm|²dΩ = 1; Gauss-Legendre latitude nodes make the analysis/
@@ -17,8 +30,11 @@ import functools
 import math
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core import spectral
 
 
 @functools.lru_cache(maxsize=16)
@@ -44,43 +60,56 @@ def legendre_matrices(nlat: int, lmax: int, mmax: int) -> Tuple[np.ndarray, np.n
     return P, x, w
 
 
-def sht_forward(f: jnp.ndarray, lmax: int, mmax: int, precision=None) -> jnp.ndarray:
+def longitude_dft(nlon: int, mmax: int) -> bool:
+    """Whether the longitude transform runs as truncated-DFT matmuls: on
+    a TPU, where the retained orders lie within the row's spectrum."""
+    return spectral._on_tpu() and mmax <= nlon // 2 + 1
+
+
+def _legendre(mat: np.ndarray, z: jnp.ndarray, expr: str) -> jnp.ndarray:
+    """One real matmul of ``z`` (real and imaginary parts on the axis
+    before the order axis) against the real ``mat`` (m, l, lat), the
+    order m its batch axis, at f32 ``Precision.HIGHEST``."""
+    return jnp.einsum(expr, jnp.asarray(mat, jnp.float32), z,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def sht_forward(f: jnp.ndarray, lmax: int, mmax: int) -> jnp.ndarray:
     """Analysis: f (..., nlat, nlon) real -> coeffs (..., lmax, mmax) complex.
 
     coeffs[l,m] = Σ_lat w_lat P̄_lm(x_lat) · (2π/nlon)·rfft(f)[lat, m]
-
-    ``precision`` is an optional resolved ``SitePrecision`` (a
-    ``*/spectral/fft_in`` site): the transform itself runs in f32 — like
-    the planar FFT, there is no half SHT on TPU — and the output spectrum
-    is boundary-quantised onto the site's storage grid (Thm 3.2's
-    representation error).
     """
     nlat, nlon = f.shape[-2], f.shape[-1]
     P, _, w = legendre_matrices(nlat, lmax, mmax)
-    Pw = jnp.asarray((P * w[None, None, :]), jnp.float32)  # (m, l, lat)
-    Fm = jnp.fft.rfft(f.astype(jnp.float32), axis=-1) * (2.0 * math.pi / nlon)
-    Fm = Fm[..., :mmax]  # (..., lat, m)
-    # coeffs[..., l, m] = Σ_lat Pw[m, l, lat] Fm[..., lat, m]
-    coeffs = jnp.einsum("mlt,...tm->...lm", Pw.astype(jnp.complex64), Fm)
-    if precision is not None:
-        coeffs = precision.quantize(coeffs)
-    return coeffs
+    f = f.astype(jnp.float32)
+    if longitude_dft(nlon, mmax):
+        z = spectral.rfft_rows(f, mmax)
+        z = z.reshape(*z.shape[:-1], 2, mmax)  # (..., lat, re|im, m)
+    else:
+        Fm = jnp.fft.rfft(f, axis=-1)[..., :mmax]
+        z = jnp.stack([jnp.real(Fm), jnp.imag(Fm)], axis=-2)
+    Pw = P * w[None, None, :] * (2.0 * math.pi / nlon)
+    c = _legendre(Pw, z, "mlt,...tkm->...klm")
+    return jax.lax.complex(c[..., 0, :, :], c[..., 1, :, :])
 
 
 def sht_inverse(coeffs: jnp.ndarray, nlat: int, nlon: int) -> jnp.ndarray:
-    """Synthesis: coeffs (..., lmax, mmax) -> f (..., nlat, nlon) real."""
+    """Synthesis: coeffs (..., lmax, mmax) -> f (..., nlat, nlon) real.
+
+    f[lat, lon] = G_0 + 2 Re Σ_{m>0} G_m e^{imφ},  G_m = Σ_l P̄_lm(x_lat)·c[l,m]
+    """
     lmax, mmax = coeffs.shape[-2], coeffs.shape[-1]
-    P, _, _ = legendre_matrices(nlat, lmax, mmax)
-    Pj = jnp.asarray(P, jnp.float32)  # (m, l, lat)
-    G = jnp.einsum("mlt,...lm->...tm", Pj.astype(jnp.complex64), coeffs)
     nfreq = nlon // 2 + 1
     if mmax > nfreq:  # orders beyond the grid's Nyquist cannot be realised
-        G = G[..., :nfreq]
-    pad = nfreq - G.shape[-1]
-    if pad > 0:
-        G = jnp.pad(G, [(0, 0)] * (G.ndim - 1) + [(0, pad)])
-    # irfft applies the hermitian doubling and 1/nlon; the real-field
-    # synthesis needs G_0 + 2ReΣ_{m>0} G_m e^{imφ}, i.e. scale by nlon.
+        coeffs, mmax = coeffs[..., :nfreq], nfreq
+    P, _, _ = legendre_matrices(nlat, lmax, mmax)
+    # irfft applies the hermitian doubling and 1/nlon, so the real-field
+    # synthesis scales by nlon, folded into the Legendre matrix.
     # (Roundtrip identity: rfft∘irfft = id, quadrature ∫p̄²dx = 1/2π.)
-    f = jnp.fft.irfft(G, n=nlon, axis=-1) * float(nlon)
-    return f
+    z = jnp.stack([jnp.real(coeffs), jnp.imag(coeffs)], axis=-2)
+    g = _legendre(P * float(nlon), z, "mlt,...lkm->...tkm")  # (..., lat, 2, m)
+    if longitude_dft(nlon, mmax):
+        return spectral.irfft_rows(g.reshape(*g.shape[:-2], 2 * mmax), nlon)
+    G = jax.lax.complex(g[..., 0, :], g[..., 1, :])
+    G = jnp.pad(G, [(0, 0)] * (G.ndim - 1) + [(0, nfreq - mmax)])
+    return jnp.fft.irfft(G, n=nlon, axis=-1)

@@ -319,6 +319,24 @@ class TestCleanTree:
                                   use_pallas=True)
         assert [f for f in findings if f.severity == ERROR] == []
 
+    @pytest.mark.parametrize("on_tpu", [False, True], ids=["fft", "dft"])
+    def test_sfno_findings_need_no_new_suppression(self, monkeypatch,
+                                                   on_tpu):
+        """The SFNO under the paper's policy, on either longitude path of
+        its spherical transform: no error, and nothing suppressed beyond
+        the boundary quantisation's reviewed round trips."""
+        from repro.analyze import model_findings
+        from repro.core import spectral
+
+        monkeypatch.setattr(spectral, "_on_tpu", lambda: on_tpu)
+        findings = model_findings("sfno", get_policy("mixed_fno_bf16"),
+                                  use_pallas=True)
+        active, suppressed = partition(
+            findings, load_suppressions(os.path.join(
+                os.path.dirname(_REPO_SRC), "analyze.toml")))
+        assert [f for f in active if f.severity == ERROR] == []
+        assert {f.check for f in suppressed} == {"round-trip-cast"}
+
 
 # ---------------------------------------------------------------------------
 # Suppression machinery
