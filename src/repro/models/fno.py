@@ -4,17 +4,24 @@ mixed-precision spectral pipeline as a first-class feature.
 Architecture (matches the neuraloperator reference):
   lifting MLP  ->  n_layers x [ SpectralConv + (1x1 conv skip) + GELU ]
                ->  projection MLP
-Per-layer weights are stacked on a leading axis and the block loop runs
-under ``lax.scan`` so the HLO stays one-layer-sized (critical for the
-512-device dry-run compile times and for remat).
+Per-layer weights are stacked on a leading axis and the block loop is one
+``lax.scan`` whose lowering depends on the entry point.  A differentiated
+forward (``fno_apply``, the training path) unrolls it: a rolled scan under
+``grad`` stacks every layer's residuals into ``[n_layers, ...]`` buffers,
+written with ``dynamic_update_slice`` and read back by the backward
+``while`` with ``dynamic_slice``, which XLA can neither fuse into their
+consumers nor free early; unrolled, the backward sees each layer's
+residuals whole.  A forward that nothing differentiates (``fno_infer``,
+the serving path) keeps the rolled scan, so only one layer's temporaries
+are live at a time.
 
 Precision is site-addressed: dense (real) ops resolve ``fno/dense`` /
 ``fno/layer<i>/dense`` (the AMP set), the spectral pipeline resolves
 ``fno/layer<i>/spectral/{fft_in,contract,fft_out}``, and the output head
 ``fno/proj_out`` (f32 by default); parameters are f32 masters.  When a
 ``precision_rules(...)`` override makes layers heterogeneous (e.g. the
-last layer pinned to full precision), the block loop automatically
-unrolls instead of scanning so each layer can compile at its own
+last layer pinned to full precision), the block loop runs as a Python
+loop over the layers instead, so each layer can compile at its own
 formats.
 """
 from __future__ import annotations
@@ -120,30 +127,44 @@ def _layer_sites(policy: PrecisionPolicy, model: str, layer: int):
 def layers_uniform(policy: PrecisionPolicy, model: str, n_layers: int) -> bool:
     """True when every layer resolves to the same formats, so the block
     loop can run as one ``lax.scan``; per-layer ``precision_rules``
-    overrides make this False and the caller unrolls instead."""
+    overrides make this False and the caller loops over the layers."""
     first = _layer_sites(policy, model, 0)
     return all(_layer_sites(policy, model, l) == first for l in range(1, n_layers))
 
 
 def apply_block_loop(block, h, stacked, policy: PrecisionPolicy, model: str,
-                     n_layers: int):
+                     n_layers: int, *, rolled: bool):
     """Run ``block(h, layer_params, layer_idx)`` over a stacked layer pytree.
 
-    One ``lax.scan`` when every layer resolves to the same formats (HLO
-    stays one-layer-sized); an unrolled loop when per-layer
-    ``precision_rules`` make the layers heterogeneous, so each layer
-    lowers at its own formats.  Shared by the FNO and SFNO block loops.
+    When every layer resolves to the same formats the layers run as one
+    ``lax.scan``, whose body is traced once.  ``rolled`` picks its
+    lowering by entry point: a forward alone (``rolled=True``) keeps the
+    scan rolled, so one layer's temporaries are live at a time; a
+    differentiated forward (``rolled=False``) unrolls it, so its
+    residuals are not stacked into ``[n_layers, ...]`` buffers that the
+    backward ``while`` slices one layer at a time.  Shared by the FNO and
+    SFNO block loops.
 
-    Also unrolls while an autoprec telemetry collector is in scope: taps
-    inside a scan body would be invisible to the outer trace, and the
-    controller needs each ``<model>/layer<i>/spectral/*`` site reported
-    under its own address.
+    A Python loop over the layers when per-layer ``precision_rules`` make
+    them heterogeneous, so each layer lowers at its own formats, and
+    while an autoprec telemetry collector is in scope: taps inside a scan
+    body would be invisible to the outer trace, and the controller needs
+    each ``<model>/layer<i>/spectral/*`` site reported under its own
+    address.
+
+    Counted at trace time, one per loop traced, under the lowering taken:
+    ``repro_block_loop_total{path="unrolled"|"scan"|"layers"}``.
     """
     from repro.autoprec.telemetry import telemetry_active
+    from repro.obs import registry
 
     if layers_uniform(policy, model, n_layers) and not telemetry_active():
-        h, _ = jax.lax.scan(lambda c, lp: (block(c, lp, 0), None), h, stacked)
+        registry().counter("repro_block_loop_total",
+                           path="scan" if rolled else "unrolled").inc()
+        h, _ = jax.lax.scan(lambda c, lp: (block(c, lp, 0), None), h, stacked,
+                            unroll=not rolled)
         return h
+    registry().counter("repro_block_loop_total", path="layers").inc()
     for l in range(n_layers):
         lp = jax.tree_util.tree_map(operator.itemgetter(l), stacked)
         h = block(h, lp, l)
@@ -156,7 +177,15 @@ def fno_apply(
     cfg: FNOConfig,
     policy: PrecisionPolicy = FULL,
 ) -> jnp.ndarray:
-    """x: (batch, in_channels, *spatial) -> (batch, out_channels, *spatial)."""
+    """x: (batch, in_channels, *spatial) -> (batch, out_channels, *spatial).
+
+    The entry point of training and of every differentiated caller: the
+    block loop is unrolled (see ``apply_block_loop``)."""
+    return _forward(params, x, cfg, policy, rolled=False)
+
+
+def _forward(params: dict, x: jnp.ndarray, cfg: FNOConfig,
+             policy: PrecisionPolicy, rolled: bool) -> jnp.ndarray:
     B = x.shape[0]
     spatial = x.shape[2:]
     cdt = policy.at("fno/dense").compute_dtype
@@ -196,7 +225,7 @@ def fno_apply(
 
     h = h.astype(cdt)
     h = apply_block_loop(block, h, (params["spectral"], params["skips"]),
-                         policy, "fno", cfg.n_layers)
+                         policy, "fno", cfg.n_layers, rolled=rolled)
 
     # projection
     h = jnp.moveaxis(h, 1, -1)
@@ -219,9 +248,10 @@ def fno_infer(
     base table).  Every op in the forward is per-sample independent
     (batched GEMMs, FFTs, pointwise), so the operator engine's
     micro-batching is bit-identical to serving each field alone under
-    the same precision policy.
+    the same precision policy.  Nothing differentiates it, so the block
+    loop stays a rolled scan (see ``apply_block_loop``).
     """
-    y = fno_apply(params, x, cfg, policy)
+    y = _forward(params, x, cfg, policy, rolled=True)
     return y.astype(policy.at("serve/operator").compute_dtype)
 
 

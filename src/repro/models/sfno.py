@@ -118,7 +118,15 @@ def _spherical_conv(h, w, cfg: SFNOConfig, policy: PrecisionPolicy,
 def sfno_apply(
     params: dict, x: jnp.ndarray, cfg: SFNOConfig, policy: PrecisionPolicy = FULL
 ) -> jnp.ndarray:
-    """x: (B, in_channels, nlat, nlon) -> (B, out_channels, nlat, nlon)."""
+    """x: (B, in_channels, nlat, nlon) -> (B, out_channels, nlat, nlon).
+
+    The entry point of training and of every differentiated caller: the
+    block loop is unrolled (see ``fno.apply_block_loop``)."""
+    return _forward(params, x, cfg, policy, rolled=False)
+
+
+def _forward(params: dict, x: jnp.ndarray, cfg: SFNOConfig,
+             policy: PrecisionPolicy, rolled: bool) -> jnp.ndarray:
     cdt = policy.at("sfno/dense").compute_dtype
     h = jnp.moveaxis(x, 1, -1)
     h = _linear(params["lift1"], h, cdt)
@@ -137,7 +145,7 @@ def sfno_apply(
 
     h = h.astype(cdt)
     h = apply_block_loop(block, h, (params["spectral"], params["skips"]),
-                         policy, "sfno", cfg.n_layers)
+                         policy, "sfno", cfg.n_layers, rolled=rolled)
 
     h = jnp.moveaxis(h, 1, -1)
     h = _linear(params["proj1"], h, cdt)
@@ -151,6 +159,7 @@ def sfno_infer(
 ) -> jnp.ndarray:
     """Batched-inference entry point for serving (see ``fno_infer``):
     (B, in_channels, nlat, nlon) -> (B, out_channels, nlat, nlon) at the
-    ``serve/operator`` transport dtype."""
-    y = sfno_apply(params, x, cfg, policy)
+    ``serve/operator`` transport dtype.  The block loop stays a rolled
+    scan."""
+    y = _forward(params, x, cfg, policy, rolled=True)
     return y.astype(policy.at("serve/operator").compute_dtype)

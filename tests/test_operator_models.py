@@ -11,6 +11,7 @@ from repro.models import (
     SFNOConfig,
     UNetConfig,
     fno_apply,
+    fno_infer,
     gino_apply,
     init_fno,
     init_gino,
@@ -18,6 +19,7 @@ from repro.models import (
     init_unet,
     param_count,
     sfno_apply,
+    sfno_infer,
     unet_apply,
 )
 
@@ -114,6 +116,73 @@ class TestSFNO:
         x = jnp.asarray(np.random.RandomState(2).randn(1, 1, 16, 32) * 100, jnp.float32)
         y = sfno_apply(params, x, cfg, get_policy("mixed_fno_fp16"))
         assert np.isfinite(np.asarray(y, np.float32)).all()
+
+
+def _smoke_model(model):
+    """A 2-layer FNO or SFNO at smoke widths, with its input."""
+    if model == "fno":
+        cfg = FNOConfig(in_channels=1, out_channels=1, hidden_channels=8,
+                        lifting_channels=8, projection_channels=8,
+                        n_layers=2, modes=(4, 4))
+        return (cfg, init_fno(jax.random.PRNGKey(0), cfg),
+                jnp.ones((2, 1, 16, 16)), fno_apply, fno_infer)
+    cfg = SFNOConfig(in_channels=1, out_channels=1, hidden_channels=8,
+                     n_layers=2, nlat=16, nlon=32, lmax=8, mmax=8,
+                     lifting_channels=8, projection_channels=8)
+    return (cfg, init_sfno(jax.random.PRNGKey(0), cfg),
+            jnp.ones((2, 1, 16, 32)), sfno_apply, sfno_infer)
+
+
+def _block_loops(path):
+    from repro.obs import registry
+
+    key = f'repro_block_loop_total{{path="{path}"}}'
+    return registry().snapshot()["counters"].get(key, 0.0)
+
+
+class TestBlockLoopLowering:
+    """The block loop is lowered by entry point: unrolled under a
+    differentiated forward (no residuals stacked across a ``while``),
+    one rolled scan for the serving forward."""
+
+    @pytest.mark.parametrize("model", ["fno", "sfno"])
+    def test_training_step_stacks_no_residuals(self, model):
+        cfg, params, x, apply, _ = _smoke_model(model)
+        policy = get_policy("mixed_fno_bf16")
+
+        def loss(p):
+            return jnp.sum(apply(p, x, cfg, policy).astype(jnp.float32) ** 2)
+
+        text = jax.jit(jax.value_and_grad(loss)).lower(params).as_text()
+        assert "stablehlo.while" not in text
+        assert "dynamic_update_slice" not in text
+        assert "dynamic_slice" not in text
+
+    @pytest.mark.parametrize("model", ["fno", "sfno"])
+    def test_serving_forward_keeps_one_scan(self, model):
+        cfg, params, x, _, infer = _smoke_model(model)
+        policy = get_policy("mixed_fno_bf16")
+        text = jax.jit(lambda p, v: infer(p, v, cfg, policy)).lower(
+            params, x).as_text()
+        assert text.count("stablehlo.while") == 1
+
+    @pytest.mark.parametrize("model", ["fno", "sfno"])
+    def test_block_loop_counter_names_the_lowering(self, model):
+        """``repro_block_loop_total`` counts one loop per step traced,
+        under the lowering it took."""
+        from repro.autoprec import TraceCollector, collecting
+
+        cfg, params, x, apply, infer = _smoke_model(model)
+        policy = get_policy("mixed_fno_bf16")
+        paths = ("unrolled", "scan", "layers")
+        before = {p: _block_loops(p) for p in paths}
+        jax.make_jaxpr(jax.value_and_grad(
+            lambda p: jnp.sum(apply(p, x, cfg, policy))))(params)
+        jax.make_jaxpr(lambda p: infer(p, x, cfg, policy))(params)
+        with collecting(TraceCollector()):
+            jax.make_jaxpr(lambda p: apply(p, x, cfg, policy))(params)
+        assert {p: _block_loops(p) - before[p] for p in paths} == {
+            "unrolled": 1, "scan": 1, "layers": 1}
 
 
 class TestGINO:

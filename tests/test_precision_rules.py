@@ -163,12 +163,13 @@ def _old_spectral_conv(params, x, modes, pol):
 def _old_fno_apply(params, x, cfg, pol, spectral_pols=None):
     """Reference: the seed's fno_apply with flat-field casts.
 
-    Mirrors the seed's structure exactly: a ``lax.scan`` block loop when
-    every layer shares one flat policy (XLA fuses scan and unrolled
-    bodies differently under bf16, so structure matters for bitwise
-    comparison), and an unrolled loop when ``spectral_pols`` gives a
-    per-layer flat policy (cross-checking per-site overrides, where the
-    new API unrolls too).
+    Mirrors the program's loop structure exactly: a ``lax.scan`` block
+    loop, unrolled as ``fno_apply`` lowers it, when every layer shares one
+    flat policy (XLA fuses rolled-scan and unrolled bodies differently
+    under bf16, so structure matters for bitwise comparison), and a
+    Python loop over the layers when ``spectral_pols`` gives a per-layer
+    flat policy (cross-checking per-site overrides, where the new API
+    loops over the layers too).
     """
     B, spatial = x.shape[0], x.shape[2:]
     cdt = pol.compute_dtype
@@ -193,7 +194,7 @@ def _old_fno_apply(params, x, cfg, pol, spectral_pols=None):
     if spectral_pols is None:
         h, _ = jax.lax.scan(
             lambda c, lp: (block(c, lp[0], lp[1], pol), None),
-            h, (params["spectral"], params["skips"]),
+            h, (params["spectral"], params["skips"]), unroll=True,
         )
     else:
         for l in range(cfg.n_layers):
