@@ -43,15 +43,6 @@ CASES = {
     "cp-2d": (4, 32, 32, (12, 12)),
 }
 
-FUSED_CASES = {
-    # name: (B, I, O, spatial, modes) — the whole spectral layer, not
-    # just the contraction.  fused-2d matches the tune CLI's default
-    # spectral_fused key so a calibration state from `tune` covers it.
-    "fused-2d": (4, 16, 16, (24, 24), (6, 6)),
-    "fused-1d": (4, 16, 16, (48,), (9,)),
-}
-
-
 def _randc(rng, shape, scale=0.5):
     return jnp.asarray(
         scale * (rng.randn(*shape) + 1j * rng.randn(*shape)), jnp.complex64)
@@ -178,79 +169,11 @@ def bench_case(name: str, policy_name: str, seed: int = 0,
     return row
 
 
-def bench_fused_case(name: str, policy_name: str, seed: int = 0) -> dict:
-    """The spectral megakernel vs the 3-stage path, whole-layer legs:
-    ``einsum`` (no Pallas anywhere), ``staged`` (Pallas contraction,
-    HBM-resident spectrum) and ``fused`` (one grid, spectrum in VMEM).
-    Walls + compiled temp bytes per leg, plus the tune traffic model's
-    HBM bytes for both pipelines — the fused pipeline must move strictly
-    fewer bytes at every benchmarked shape."""
-    from repro.core.spectral import init_spectral_weights, spectral_conv_apply
-    from repro.kernels.spectral_contract import pick_block_b
-
-    B, I, O, spatial, modes = FUSED_CASES[name]
-    policy = get_policy(policy_name)
-    rng = np.random.RandomState(seed)
-    x = jnp.asarray(rng.randn(B, I, *spatial), jnp.float32)
-    params = init_spectral_weights(jax.random.PRNGKey(seed), I, O, modes,
-                                   "dense")
-    block_b = pick_block_b(B, I, O, spatial, modes)
-
-    def loss_at(use_pallas, fuse):
-        def loss(x, params):
-            y = spectral_conv_apply(
-                params, x, modes, policy, use_pallas=use_pallas,
-                fuse_spectral=fuse, site="fno/layer0/spectral")
-            return jnp.sum(y.astype(jnp.float32) ** 2)
-        return loss
-
-    traffic_shape = (B, I, O, *spatial, *modes)
-    moved = {
-        "fused": bytes_moved("spectral_fused", traffic_shape, "float32"),
-        "staged": bytes_moved("spectral_staged", traffic_shape, "float32"),
-    }
-    assert moved["fused"] < moved["staged"], (
-        "the megakernel must move strictly fewer HBM bytes", name, moved)
-
-    row = {
-        "case": name, "policy": policy_name,
-        "B": B, "I": I, "O": O, "spatial": list(spatial),
-        "modes": list(modes), "block_b": block_b,
-        "bytes_moved": moved,
-        "interpret": jax.default_backend() != "tpu",
-    }
-    legs = [("einsum", loss_at(False, False)),
-            ("staged", loss_at(True, False)),
-            ("fused", loss_at(True, True))]
-    for label, loss in legs:
-        fwd = jax.jit(loss)
-        bwd = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
-        entry = {
-            "fwd_us": time_fn(fwd, x, params),
-            "fwd_bwd_us": time_fn(bwd, x, params),
-            "fwd_temp_bytes": _temp_bytes(loss, x, params),
-            "fwd_bwd_temp_bytes": _temp_bytes(
-                jax.value_and_grad(loss, argnums=(0, 1)), x, params),
-        }
-        if label != "einsum":
-            traffic = moved["fused"] if label == "fused" else moved["staged"]
-            gbps = traffic / (entry["fwd_bwd_us"] * 1e-6) / 1e9
-            entry["gbps"] = round(gbps, 3)
-            entry["roofline_fraction"] = _hbm_share(gbps)
-        row[label] = entry
-    row["fused_over_staged_wall"] = round(
-        row["fused"]["fwd_bwd_us"] / max(row["staged"]["fwd_bwd_us"], 1e-9), 3)
-    row["fused_over_staged_hbm_bytes"] = round(
-        moved["fused"] / moved["staged"], 4)
-    return row
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--policy", nargs="*",
                     default=["full", "mixed_fno_bf16"])
-    ap.add_argument("--case", nargs="*",
-                    default=sorted(CASES) + sorted(FUSED_CASES))
+    ap.add_argument("--case", nargs="*", default=sorted(CASES))
     ap.add_argument("--calibration-state", default=None,
                     help="activate a repro.tune state and add a tuned-"
                          "tiles comparison leg per row")
@@ -269,19 +192,6 @@ def main():
           f"{'temp MiB e/p':>14s}")
     for case in args.case:
         for pol in args.policy:
-            if case in FUSED_CASES:
-                row = bench_fused_case(case, pol)
-                rows.append(row)
-                print(f"{case:>10s} {pol:>16s} "
-                      f"{row['staged']['fwd_bwd_us']:>14.0f} "
-                      f"{row['fused']['fwd_bwd_us']:>14.0f} "
-                      f"{row['fused_over_staged_wall']:>7.2f} "
-                      f"{row['fused']['gbps']:>7.2f} "
-                      f"{row['staged']['fwd_bwd_temp_bytes'] / 2**20:>6.1f}/"
-                      f"{row['fused']['fwd_bwd_temp_bytes'] / 2**20:<6.1f}"
-                      f"  (hbm bytes x"
-                      f"{row['fused_over_staged_hbm_bytes']:.2f})")
-                continue
             row = bench_case(case, pol, tuned_leg=tuned_leg)
             rows.append(row)
             print(f"{case:>10s} {pol:>16s} "

@@ -379,15 +379,12 @@ def _trace_entries(jaxpr: jcore.Jaxpr, inherited: Optional[str],
 
 
 def dtype_trace(policy, use_pallas: bool = False,
-                factorization: str = "dense",
-                fuse_spectral: Optional[bool] = False) -> List[str]:
+                factorization: str = "dense") -> List[str]:
     """The exact cast/contract/FFT dtype sequence of one FNO spectral
     layer under ``policy`` — the golden-snapshot surface: a policy or
     model refactor that silently changes numerics changes this list.
-
-    ``fuse_spectral`` defaults to *False* (not auto) so the staged
-    traces stay pinned to the staged pipeline whatever the environment;
-    pass ``True`` to snapshot the fused megakernel's dispatch."""
+    The transforms follow ``core.spectral.dft_transforms``, so a caller
+    that makes ``_on_tpu`` read True traces the TPU's DFT path."""
     from repro.core.spectral import init_spectral_weights, spectral_conv_apply
 
     params = init_spectral_weights(
@@ -396,7 +393,7 @@ def dtype_trace(policy, use_pallas: bool = False,
     closed = jax.make_jaxpr(
         lambda p, xx: spectral_conv_apply(
             p, xx, (4, 4), policy, use_pallas=use_pallas,
-            site="model/spectral", fuse_spectral=fuse_spectral,
+            site="model/spectral",
         )
     )(params, x)
     out: List[str] = []

@@ -230,12 +230,6 @@ _M, _BLOCK_M = 40, 16          # pads 40 -> 48, grid (3,)
 _L, _MM, _BLOCK_L = 12, 9, 8   # pads 12 -> 16, grid (2,)
 _DT = jnp.float16
 
-# the fused spectral megakernel tiles the batch: 3 pads -> 4, grid (2,)
-_FB, _FBLOCK_B = 3, 2
-_FI, _FO = 4, 4
-_FSPATIAL, _FMODES = (8, 8), (3, 3)   # odd modes on an even grid
-_FMH = 6 * 3                          # prod(2m, ..., m_last) retained rows
-
 
 def _sds(*shape):
     return jax.ShapeDtypeStruct(shape, _DT)
@@ -273,13 +267,10 @@ def kernel_families() -> List[Tuple[str, Callable[[], List[KernelCall]], Callabl
     the tracer uses; the pass checks it covers the recorded tiles."""
     from repro.kernels.spectral_contract import (
         cp_vmem_bytes,
-        fused_vmem_bytes,
-        fused_vmem_bytes_bwd,
         lshared_vmem_bytes,
         spectral_contract_cp_pallas,
         spectral_contract_lshared_pallas,
         spectral_contract_pallas,
-        spectral_fused_pallas,
         vmem_bytes,
         vmem_bytes_bwd,
     )
@@ -309,19 +300,6 @@ def kernel_families() -> List[Tuple[str, Callable[[], List[KernelCall]], Callabl
         block_l=_BLOCK_L, interpret=True, out_dtype=_DT)
     lsh_args = (_sds(_B, _I, _L, _MM), _sds(_B, _I, _L, _MM),
                 _sds(_I, _O, _L), _sds(_I, _O, _L))
-    # the fused megakernel: f32 streamed operands, half quantise in-tile
-    fused = functools.partial(
-        _unwrap(spectral_fused_pallas),
-        modes=_FMODES, block_b=_FBLOCK_B, interpret=True, cast_to=_DT)
-    fused_args = (_sds32(_FB, _FI, *_FSPATIAL),
-                  _sds32(_FI, _FO, _FMH), _sds32(_FI, _FO, _FMH))
-
-    def _fused_grad(*args):
-        def loss(*a):
-            return fused(*a).astype(jnp.float32).sum()
-
-        return jax.grad(loss, argnums=(0, 1, 2))(*args)
-
     return [
         ("dense/fwd", lambda: _trace(dense, *dense_args),
          lambda: vmem_bytes(_B, _I, _O, _BLOCK_M, item)),
@@ -340,12 +318,6 @@ def kernel_families() -> List[Tuple[str, Callable[[], List[KernelCall]], Callabl
          lambda: lshared_vmem_bytes(_B, _I, _O, _MM, _BLOCK_L, item)),
         ("lshared/bwd", lambda: _trace(_grad_sum(lsh, 4), *lsh_args),
          lambda: lshared_vmem_bytes(_B, _I, _O, _MM, _BLOCK_L, item)),
-        ("spectral_fused/fwd", lambda: _trace(fused, *fused_args),
-         lambda: fused_vmem_bytes(_FBLOCK_B, _FI, _FO, _FSPATIAL,
-                                  _FMODES, itemsize=4)),
-        ("spectral_fused/bwd", lambda: _trace(_fused_grad, *fused_args),
-         lambda: fused_vmem_bytes_bwd(_FBLOCK_B, _FI, _FO, _FSPATIAL,
-                                      _FMODES, itemsize=4)),
     ]
 
 

@@ -214,14 +214,59 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def dft_exact(spatial: Sequence[int], modes: Sequence[int]) -> bool:
+    """Whether the truncated-DFT factorisation is exact for this shape:
+    corner blocks must not overlap (2m_k <= S_k) and the last axis must
+    retain no more than the rfft spectrum holds."""
+    if len(spatial) != len(modes) or not modes:
+        return False
+    if any(2 * m > s for m, s in zip(modes[:-1], spatial[:-1])):
+        return False
+    return modes[-1] <= spatial[-1] // 2 + 1
+
+
+def dft_factors(spatial: Sequence[int], modes: Sequence[int]) -> tuple:
+    """Precomputed DFT / inverse-DFT factor matrices (numpy, float64).
+
+    Returns a flat tuple: per axis k the forward pair (re, im) of
+    ``F_k[mu, t] = exp(-2*pi*i*f_mu*t/S_k)`` over the retained rows (the
+    low block ``[0, m_k)`` then the high block ``[S_k-m_k, S_k)`` for
+    every axis but the last, the rfft rows ``[0, m_d)`` for the last),
+    then per axis the inverse pair — ``G_k[mu, t] =
+    exp(+2*pi*i*f_mu*t/S_k)/S_k`` for full-FFT axes and, for the last
+    axis, the real-output pair ``C_re[mu, t] = w_mu*cos(2*pi*mu*t/S_d)/S_d``,
+    ``C_im[mu, t] = -w_mu*sin(...)/S_d`` with hermitian weights w (1 at
+    DC and Nyquist, 2 elsewhere) — so ``y = yh_re@C_re + yh_im@C_im`` is
+    exactly ``irfftn`` of the zero-scattered truncated spectrum."""
+    ndim = len(modes)
+    fwd, inv = [], []
+    for k in range(ndim):
+        S, m = int(spatial[k]), int(modes[k])
+        last = k == ndim - 1
+        if last:
+            freqs = np.arange(m)
+        else:
+            freqs = np.concatenate([np.arange(m), np.arange(S - m, S)])
+        ang = 2.0 * np.pi * np.outer(freqs, np.arange(S)) / S
+        fwd.append((np.cos(ang), -np.sin(ang)))
+        if not last:
+            inv.append((np.cos(ang) / S, np.sin(ang) / S))
+        else:
+            w = np.full(m, 2.0)
+            w[0] = 1.0
+            if S % 2 == 0 and m - 1 == S // 2:
+                w[m - 1] = 1.0  # Nyquist row is its own conjugate
+            inv.append((w[:, None] * np.cos(ang) / S,
+                        -w[:, None] * np.sin(ang) / S))
+    return tuple(x for pair in fwd + inv for x in pair)
+
+
 def dft_transforms(spatial: Sequence[int], modes: Sequence[int]) -> bool:
     """Whether the staged path runs its transforms as truncated-DFT
     matmuls: on a TPU, and where the factorisation is exact (the corner
     blocks do not overlap, where the FFT path's scatter would overwrite
     what a sum of corners adds)."""
-    from repro.kernels.spectral_contract import fused_exact
-
-    return _on_tpu() and fused_exact(tuple(spatial), tuple(modes))
+    return _on_tpu() and dft_exact(spatial, modes)
 
 
 def _full_axis_order(spatial: Sequence[int], modes: Sequence[int]):
@@ -253,9 +298,7 @@ def rfft_rows(x: jnp.ndarray, m: int) -> jnp.ndarray:
     ``x``, as one real matmul against ``[F_re^T | F_im^T]`` at f32
     ``Precision.HIGHEST``: shape (..., 2m), the real parts then the
     imaginary ones."""
-    from repro.kernels.spectral_contract import fused_factors
-
-    fr, fi = fused_factors((x.shape[-1],), (m,))[:2]
+    fr, fi = dft_factors((x.shape[-1],), (m,))[:2]
     f = np.concatenate([fr.T, fi.T], axis=1).astype(np.float32)
     return jnp.tensordot(x, f, axes=[[x.ndim - 1], [0]],
                          precision=jax.lax.Precision.HIGHEST)
@@ -267,9 +310,7 @@ def irfft_rows(z: jnp.ndarray, n: int) -> jnp.ndarray:
     (the layout ``rfft_rows`` returns), and zeros above: one real matmul
     against the real-output pair ``[C_re; C_im]`` with its hermitian
     weights, at f32 ``Precision.HIGHEST``."""
-    from repro.kernels.spectral_contract import fused_factors
-
-    cr, ci = fused_factors((n,), (z.shape[-1] // 2,))[2:]
+    cr, ci = dft_factors((n,), (z.shape[-1] // 2,))[2:]
     c = np.concatenate([cr, ci], axis=0).astype(np.float32)
     return jnp.tensordot(z, c, axes=[[z.ndim - 1], [0]],
                          precision=jax.lax.Precision.HIGHEST)
@@ -284,10 +325,8 @@ def truncated_rfft(x: jnp.ndarray, modes: Sequence[int]) -> jnp.ndarray:
     ``_corner_slices(modes, rfftn(x).shape[2:])``.  The last (rfft) axis,
     which shrinks most, goes first, as one real matmul against
     ``[F_re | F_im]``; every matmul runs at f32 ``Precision.HIGHEST``."""
-    from repro.kernels.spectral_contract import fused_factors
-
     spatial = x.shape[2:]
-    facs = fused_factors(spatial, modes)
+    facs = dft_factors(spatial, modes)
     re, im = jnp.split(rfft_rows(x, modes[-1]), 2, axis=-1)
     for k in _full_axis_order(spatial, modes):
         re, im = _complex_axis(re, im, facs[2 * k], facs[2 * k + 1], 2 + k)
@@ -302,10 +341,8 @@ def truncated_irfft(yh: jnp.ndarray, spatial: Sequence[int],
     inverse-DFT matmuls from the corners alone: the full axes first
     (2m -> S), then the real-output ``C_re``/``C_im`` pair, with its
     hermitian weights, along the last axis.  f32 ``Precision.HIGHEST``."""
-    from repro.kernels.spectral_contract import fused_factors
-
     ndim = len(modes)
-    inv = fused_factors(spatial, modes)[2 * ndim:]
+    inv = dft_factors(spatial, modes)[2 * ndim:]
     re, im = jnp.real(yh), jnp.imag(yh)
     for k in reversed(_full_axis_order(spatial, modes)):
         re, im = _complex_axis(re, im, inv[2 * k].T, inv[2 * k + 1].T, 2 + k)
@@ -336,7 +373,6 @@ def spectral_conv_apply(
     policy: PrecisionPolicy = FULL,
     use_pallas: Optional[bool] = None,
     site: str = "model/spectral",
-    fuse_spectral: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Apply the Fourier convolution to ``x`` of shape (batch, ch, *spatial).
 
@@ -356,15 +392,8 @@ def spectral_conv_apply(
     ``REPRO_USE_PALLAS=1``); when on, dense and CP contractions run the
     training-grade Pallas kernels (custom-VJP backward, same telemetry
     taps), while Tucker keeps the einsum path — its core tensor has no
-    mode-major kernel layout.
-
-    ``fuse_spectral``: tri-state (``kernels.ops.resolve_fuse_spectral``;
-    kill switch ``REPRO_FUSE_SPECTRAL=0``).  When it resolves on — and
-    the Pallas path is active, the layer is dense, and
-    ``fused_spectral_viable`` admits the shape/policy (VMEM fit at the
-    floor tile, no active autoprec collector) — the *whole* pipeline
-    runs as the one-grid ``spectral_fused`` megakernel instead of
-    rFFT/contract/irFFT round-tripping HBM between stages.
+    mode-major kernel layout.  The transforms around the contraction are
+    chosen from the backend and the shape alone (``dft_transforms``).
     """
     ndim = len(modes)
     spatial = x.shape[2:]
@@ -379,19 +408,6 @@ def spectral_conv_apply(
     fft_in = policy.at(f"{site}/fft_in")
     ctr = policy.at(f"{site}/contract")
     fft_out = policy.at(f"{site}/fft_out")
-
-    if use_pallas and kind == "dense":
-        from repro.kernels import ops as kops
-
-        if kops.resolve_fuse_spectral(fuse_spectral) and \
-                kops.fused_spectral_viable(
-                    fft_in, ctr, x.shape[0], x.shape[1],
-                    _out_channels(params), spatial, modes):
-            # the megakernel: one Pallas grid for the whole pipeline —
-            # the spectrum lives in VMEM between the transform stages
-            return kops.spectral_conv_fused(
-                x, params["w_re"], params["w_im"], modes,
-                policy=policy, site=site)
 
     # 1. stabiliser before the forward FFT (only active for half spectral)
     x = fft_in.stabilize(x)

@@ -27,19 +27,13 @@ from .spectral_contract import (
     LANES,
     VMEM_BUDGET,
     cp_vmem_bytes,
-    fused_exact,
-    fused_supported,
-    fused_vmem_bytes,
-    fused_vmem_bytes_bwd,
     lshared_vmem_bytes,
-    pick_block_b,
     pick_block_l,
     pick_block_m,
     resolve_interpret,
     spectral_contract_cp_pallas,
     spectral_contract_lshared_pallas,
     spectral_contract_pallas,
-    spectral_fused_pallas,
     vmem_bytes,
     vmem_bytes_bwd,
 )
@@ -66,123 +60,6 @@ def resolve_use_pallas(flag: Optional[bool] = None) -> bool:
     if env is not None and env != "":
         return env.lower() not in ("0", "false", "no")
     return jax.default_backend() == "tpu"
-
-
-def resolve_fuse_casts(flag: Optional[bool] = None) -> bool:
-    """Resolve the tri-state ``fuse_casts`` setting for the dense path.
-
-    Explicit True/False wins; ``None`` means *auto*: on unless the env
-    var ``REPRO_FUSE_CASTS`` is falsy (kill switch).  When on — and the
-    site quantises to half and the operands arrive as complex (not
-    pre-cast ComplexPairs) — the storage rounding happens inside the
-    kernel's tile prologue instead of as a separate HBM-resident cast.
-    """
-    if flag is not None:
-        return bool(flag)
-    env = os.environ.get("REPRO_FUSE_CASTS")
-    if env is not None and env != "":
-        return env.lower() not in ("0", "false", "no")
-    return True
-
-
-def resolve_fuse_spectral(flag: Optional[bool] = None) -> bool:
-    """Resolve the tri-state ``fuse_spectral`` setting.
-
-    Explicit True/False wins; ``None`` means *auto*: on unless the env
-    var ``REPRO_FUSE_SPECTRAL`` is falsy (kill switch).  When on — and
-    the layer is dense, the shape passes :func:`fused_supported`, the
-    batch=1 working set fits VMEM, and no autoprec collector is active
-    (the fused spectrum never touches HBM, so the per-stage taps have
-    nothing to observe) — ``core/spectral`` dispatches the whole
-    rFFT → contract → irFFT pipeline into one Pallas grid
-    (``spectral_fused``) instead of the three-stage path.
-    """
-    if flag is not None:
-        return bool(flag)
-    env = os.environ.get("REPRO_FUSE_SPECTRAL")
-    if env is not None and env != "":
-        return env.lower() not in ("0", "false", "no")
-    return True
-
-
-def fused_spectral_viable(fft_in, ctr, B: int, I: int, O: int,
-                          spatial: Sequence[int],
-                          modes: Sequence[int]) -> bool:
-    """Can this dense layer run the fused megakernel bit-for-spec?
-
-    Requires structural support (modes fit the truncated-DFT factor
-    layout and, where the kernels compile to Mosaic, a layout Mosaic
-    lowers: ``fused_layout_compiles``), a VMEM fit for the training
-    working set at the floor tile
-    ``block_b=1``, an inactive autoprec collector (the staged path owns
-    telemetry — its taps see the HBM spectrum the fused path never
-    materialises), and spectral sites that agree on one quantisation
-    spec (every registry policy does; bespoke overlays that quantise
-    ``fft_in`` differently from ``contract`` keep the staged path,
-    whose per-stage semantics they address).
-    """
-    from repro.autoprec.telemetry import telemetry_active
-
-    if telemetry_active():
-        return False
-    if not fused_supported(tuple(spatial), tuple(modes),
-                           compiled=not _use_interpret()):
-        return False
-    if fused_vmem_bytes_bwd(1, I, O, tuple(spatial), tuple(modes),
-                            itemsize=4) > VMEM_BUDGET:
-        return False
-    if fft_in.quantize_fmt != ctr.quantize_fmt:
-        return False
-    if fft_in.quantize_fmt is not None and fft_in.compute != ctr.compute:
-        return False
-    return True
-
-
-def _fused_qspec(ctr):
-    """(cast_to, sim_fmt) static kernel params for a contract-site rule.
-
-    ``half`` quantisation → round operand tiles onto the half grid in
-    VMEM (``cast_to``); simulated fp8 → fp8-grid rounding of the
-    spectrum (``sim_fmt``) *then* the half storage cast, exactly the
-    staged ``fft_in.quantize → half contraction`` composition.
-    """
-    fmt = ctr.quantize_fmt
-    if fmt is None:
-        return None, None
-    if fmt == "half":
-        return ctr.compute, None
-    return ctr.compute, fmt
-
-
-def gather_corner_weights(w_re, w_im, modes: Sequence[int]):
-    """Fold per-corner dense weights into the fused kernel's layout.
-
-    ``w_re``/``w_im``: (corners, I, O, *modes) split-real corner
-    weights.  The fused kernel's forward DFT keeps, per truncated axis,
-    the low block ``[0, m)`` then the high block ``[S-m, S)`` — so
-    corner ``c``'s weight lands at axis-``k`` rows ``[m, 2m)`` when bit
-    ``k`` of ``c`` is set, ``[0, m)`` otherwise (last axis: always
-    ``[0, m)``).  Returns ``(wgr, wgi)`` of shape (I, O, Mh) with the
-    row-major flattening the kernel contracts over.  Pure differentiable
-    ``jnp`` — gradients scatter back to the per-corner params.
-    """
-    nc, I, O, *mlist = w_re.shape
-    ndim = len(modes)
-    rows = tuple(2 * m for m in modes[:-1]) + (modes[-1],)
-    out_r = jnp.zeros((I, O, *rows), w_re.dtype)
-    out_i = jnp.zeros((I, O, *rows), w_im.dtype)
-    for c in range(nc):
-        sl = [slice(None), slice(None)]
-        for ax in range(ndim - 1):
-            m = modes[ax]
-            sl.append(slice(m, 2 * m) if (c >> ax) & 1 else slice(0, m))
-        sl.append(slice(0, modes[-1]))
-        out_r = out_r.at[tuple(sl)].set(w_re[c])
-        out_i = out_i.at[tuple(sl)].set(w_im[c])
-    Mh = 1
-    for r in rows:
-        Mh *= r
-    return out_r.reshape(I, O, Mh), out_i.reshape(I, O, Mh)
 
 
 def _site_of(policy, site: str):
@@ -360,7 +237,7 @@ def _fused_cast_fits(x_shape, O: int) -> bool:
 
 def spectral_contract(
     x, w, *, policy=FULL, block_m: Optional[int] = None,
-    block_m_bwd: Optional[int] = None, fuse_casts: Optional[bool] = None,
+    block_m_bwd: Optional[int] = None,
     site: str = "model/spectral/contract",
 ):
     """Dense spectral contraction ``bi<modes>,io<modes>->bo<modes>``.
@@ -374,11 +251,11 @@ def spectral_contract(
     two backward kernels independently (default: the forward tile, or
     the calibrated backward tile when one resolved).
 
-    ``fuse_casts``: tri-state (see ``resolve_fuse_casts``).  When it
-    resolves on — and the site quantises and ``x``/``w`` arrive as
-    complex — the half storage rounding runs inside the kernel's tile
-    prologue (``cast_to``), so the half operand copies never round-trip
-    through HBM; numerically it is the same Thm 3.2 rounding.
+    Where the site quantises to half, ``x``/``w`` arrive as complex (not
+    pre-cast ComplexPairs) and the f32 operand tiles fit VMEM
+    (``_fused_cast_fits``), the half storage rounding runs inside the
+    kernel's tile prologue (``cast_to``), so the half operand copies never
+    round-trip through HBM; numerically it is the same Thm 3.2 rounding.
 
     ``x``: complex64 or ComplexPair, shape (B, I, *modes);
     ``w``: complex64 or ComplexPair (the layer's dense corner weight),
@@ -415,7 +292,6 @@ def spectral_contract(
         policy.spectral_is_half
         and not was_pair
         and not isinstance(w, ComplexPair)
-        and resolve_fuse_casts(fuse_casts)
         and _fused_cast_fits(x.shape, w.shape[1])
     )
     if fused:
@@ -471,93 +347,6 @@ def spectral_contract(
     if was_pair and policy.spectral_is_half:
         return pair
     return pair.to_complex()
-
-
-def spectral_conv_fused(
-    x, w_re, w_im, modes: Sequence[int], *, policy=FULL,
-    block_b: Optional[int] = None, block_b_bwd: Optional[int] = None,
-    site: str = "model/spectral",
-):
-    """The whole dense Fourier convolution in one Pallas grid.
-
-    Fused rFFT → mode contraction → irFFT: the forward/inverse
-    transforms run as precomputed truncated-DFT factor matmuls over the
-    VMEM-resident batch tile, the contraction reuses the dense 4-real-
-    matmul schedule with the ``cast_to``/``sim_fmt`` quantise prologue,
-    and the spectrum never round-trips HBM between stages.  Semantically
-    this is ``spectral_conv_apply`` for a dense layer: the stabiliser,
-    boundary quantisation, per-corner contraction and output cast all
-    happen — the corners as row blocks of the gathered weight, the
-    quantisation on in-VMEM tiles.
-
-    ``x``: real (B, I, *spatial); ``w_re``/``w_im``: (corners, I, O,
-    *modes) split-real corner weights (the layer's ``params``);
-    ``modes``: retained modes per axis.  ``policy``: a
-    ``PrecisionPolicy`` resolved here at ``{site}/fft_in|contract|
-    fft_out``, exactly like the staged pipeline.  ``block_b`` tiles the
-    batch axis (``None`` → calibration cache, then the VMEM ladder).
-    Returns real (B, O, *spatial) at ``x``'s dtype.
-    """
-    if not isinstance(policy, PrecisionPolicy):
-        raise ValueError(
-            "spectral_conv_fused resolves fft_in/contract/fft_out sites "
-            "itself: pass the PrecisionPolicy, not a SitePrecision")
-    fft_in = policy.at(f"{site}/fft_in")
-    ctr = policy.at(f"{site}/contract")
-    fft_out = policy.at(f"{site}/fft_out")
-
-    ndim = len(modes)
-    spatial = tuple(x.shape[2:])
-    if len(spatial) != ndim:
-        raise ValueError(
-            f"spectral_conv_fused: x {x.shape} vs modes {tuple(modes)}")
-    if not fused_exact(spatial, modes):
-        raise ValueError(
-            f"spectral_conv_fused: spatial {spatial} cannot retain modes "
-            f"{tuple(modes)} in the fused factor layout — the staged "
-            f"path in core/spectral.py handles this shape")
-    in_dtype = x.dtype
-    B, I = x.shape[:2]
-    O = w_re.shape[2]
-
-    # 1. stabiliser before the forward transform (half spectral only) —
-    #    the one stage that stays outside the grid: it reads/writes the
-    #    HBM-resident physical input the caller already owns.
-    x = fft_in.stabilize(x)
-
-    # 2–6. everything else is one kernel launch.
-    wgr, wgi = gather_corner_weights(w_re, w_im, modes)
-    cast_to, sim_fmt = _fused_qspec(ctr)
-    half = ctr.spectral_dtype or jnp.float32
-    if block_b is None:
-        # the fused family streams f32 operands (quantisation happens on
-        # tiles in VMEM), so shape keys and working sets price at
-        # itemsize 4 whatever the policy's storage dtype
-        block_b, tuned_bwd, _src = _resolve_blocks(
-            "spectral_fused", (B, I, O, *spatial, *modes), half,
-            lambda: pick_block_b(B, I, O, spatial, modes, itemsize=4),
-        )
-        block_b_bwd = block_b_bwd or tuned_bwd
-
-    interpret = _use_interpret()
-
-    def kernel(xb, wr, wi):
-        return (spectral_fused_pallas(
-            xb, wr, wi, modes=tuple(modes), block_b=block_b,
-            block_b_bwd=block_b_bwd, interpret=interpret, cast_to=cast_to,
-            sim_fmt=sim_fmt,
-        ),)
-
-    with jax.named_scope(ctr.site):
-        (y,) = _run_batched(kernel, B, (x.astype(jnp.float32),),
-                            (wgr, wgi))
-
-    from repro.autoprec.telemetry import fmt_of, tap
-
-    tap(f"{site}/fft_out", y, fmt=fmt_of(fft_out))
-    if fft_out.spectral_is_half:
-        y = y.astype(fft_out.compute_dtype)
-    return y.astype(in_dtype)
 
 
 def cp_mode_factor(lam, mode_factors: Sequence) -> jnp.ndarray:
@@ -717,11 +506,8 @@ def rmsnorm(x, w, *, eps: float = 1e-6, block_rows: int = 256):
 
 __all__ = [
     "spectral_contract", "spectral_contract_cp", "spectral_contract_lshared",
-    "spectral_conv_fused", "gather_corner_weights", "fused_spectral_viable",
     "cp_mode_factor", "flash_attention", "rmsnorm", "resolve_use_pallas",
-    "resolve_fuse_casts", "resolve_fuse_spectral", "tile_resolution_stats",
-    "reset_tile_resolution_stats",
+    "tile_resolution_stats", "reset_tile_resolution_stats",
     "vmem_bytes", "vmem_bytes_bwd", "cp_vmem_bytes", "lshared_vmem_bytes",
-    "fused_vmem_bytes", "fused_vmem_bytes_bwd",
-    "pick_block_m", "pick_block_l", "pick_block_b",
+    "pick_block_m", "pick_block_l",
 ]

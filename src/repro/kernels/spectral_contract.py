@@ -77,8 +77,6 @@ MOSAIC_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT)
 #: shift the perf landscape — every ``repro.tune`` calibration entry is
 #: keyed by this value, so a bump invalidates stale tuning results
 #: without anyone having to remember to delete the cache file.
-#: v3: the fused rFFT→contract→irFFT family (``spectral_fused``) joins
-#: the registry, batch-tiled with its own VMEM estimators.
 #: v4: Mosaic-compilable layouts — lane-legal mode tiles, the CP backward
 #: without two-dim contractions, the l-shared weight degree-major on a
 #: (degree, batch-row) grid — and estimators that count double buffers.
@@ -762,427 +760,11 @@ def spectral_contract_lshared_pallas(
 
 
 # ---------------------------------------------------------------------------
-# Fused spectral megakernel: rFFT -> contract -> irFFT in one Pallas grid
-# ---------------------------------------------------------------------------
-#
-# The staged pipeline round-trips HBM three times per layer: rfftn writes
-# the full spectrum, the boundary quantise writes the half copy, the
-# contraction writes the truncated output which the scatter + irfftn read
-# back.  This family runs the whole pipeline per *batch tile* with the
-# spectral activations resident in VMEM throughout:
-#
-#   1. truncated DFT as matmuls: per axis k the factor F_k holds only the
-#      retained mode rows — the low [0, m_k) and high [S_k-m_k, S_k)
-#      frequency blocks for every axis but the last, the rfft rows
-#      [0, m_d) for the last — so truncation and the 2^(d-1) corner
-#      gather cost nothing: they are rows that simply do not exist.
-#   2. the boundary quantise (Thm 3.2's representation error) applies to
-#      the VMEM-resident spectrum: the half grid via the same ``astype``
-#      rounding as ``_cast_tiles`` / the simulated fp8 grid via
-#      ``simulate_fp8`` — bit-identical values to the staged boundary,
-#      zero HBM-visible casts.
-#   3. the mode contraction reuses the dense 4-real-matmul schedule
-#      (rr−ii / ri+ir, f32 accumulation) against the corner-gathered
-#      weight (I, O, Mh).
-#   4. the inverse transform applies per-axis iDFT factors; the last axis
-#      folds the hermitian weights (1 for DC/Nyquist, 2 elsewhere) into a
-#      real-output cos/sin pair, exactly ``irfftn`` of the zero-scattered
-#      spectrum.
-#
-# The custom VJP runs the transposed pipeline in one backward kernel:
-# cotangent -> adjoint iDFT -> (dx via conj(w), dw via conj(xh)) ->
-# adjoint DFT -> real part.  dw is mode-independent of the batch grid, so
-# its output block revisits every grid step and accumulates in place
-# (init-or-accumulate discipline, as the CP backward does).
-
-
-def _fused_rows(spatial, modes):
-    """Retained spectrum rows per axis: 2m for truncated full-FFT axes
-    (low+high corner blocks), m for the last (rfft) axis."""
-    return tuple(2 * m for m in modes[:-1]) + (modes[-1],)
-
-
-def fused_exact(spatial, modes) -> bool:
-    """Whether the truncated-DFT factorisation is exact for this shape:
-    corner blocks must not overlap (2m_k <= S_k) and the last axis must
-    retain no more than the rfft spectrum holds."""
-    if len(spatial) != len(modes) or not modes:
-        return False
-    if any(2 * m > s for m, s in zip(modes[:-1], spatial[:-1])):
-        return False
-    return modes[-1] <= spatial[-1] // 2 + 1
-
-
-def fused_layout_compiles(modes) -> bool:
-    """Whether Mosaic lowers the megakernel's vector reshapes for these
-    modes.  On a multi-axis grid the kernel reshapes the flattened
-    retained modes to per-axis row blocks and back; Mosaic's layout pass
-    refuses that ("infer-vector-layout: unsupported shape cast") unless
-    the minor block — the last axis's retained modes — is whole 128-lane
-    rows.  A 1-D grid needs no such reshape."""
-    return len(modes) == 1 or modes[-1] % LANES == 0
-
-
-def fused_supported(spatial, modes, *, compiled: bool | None = None) -> bool:
-    """Whether the dispatch may run this shape on the megakernel: the
-    factorisation is exact and, where the kernel compiles to Mosaic
-    (``compiled``; None resolves from the backend), its layout lowers."""
-    if not fused_exact(spatial, modes):
-        return False
-    if compiled is None:
-        compiled = not resolve_interpret(None)
-    return not compiled or fused_layout_compiles(modes)
-
-
-def fused_factors(spatial, modes):
-    """Precomputed DFT / inverse-DFT factor matrices (numpy, float64).
-
-    Returns a flat tuple: per axis k the forward pair (re, im) of
-    ``F_k[mu, t] = exp(-2*pi*i*f_mu*t/S_k)`` over the retained rows, then
-    per axis the inverse pair — ``G_k[mu, t] = exp(+2*pi*i*f_mu*t/S_k)/S_k``
-    for full-FFT axes and, for the last axis, the real-output pair
-    ``C_re[mu, t] = w_mu*cos(2*pi*mu*t/S_d)/S_d``,
-    ``C_im[mu, t] = -w_mu*sin(...)/S_d`` with hermitian weights w
-    (1 at DC and Nyquist, 2 elsewhere) — so ``y = yh_re@C_re + yh_im@C_im``
-    is exactly ``irfftn`` of the zero-scattered truncated spectrum."""
-    import numpy as np
-
-    ndim = len(modes)
-    fwd, inv = [], []
-    for k in range(ndim):
-        S, m = int(spatial[k]), int(modes[k])
-        last = k == ndim - 1
-        if last:
-            freqs = np.arange(m)
-        else:
-            freqs = np.concatenate([np.arange(m), np.arange(S - m, S)])
-        ang = 2.0 * np.pi * np.outer(freqs, np.arange(S)) / S
-        fwd.append((np.cos(ang), -np.sin(ang)))
-        if not last:
-            inv.append((np.cos(ang) / S, np.sin(ang) / S))
-        else:
-            w = np.full(m, 2.0)
-            w[0] = 1.0
-            if S % 2 == 0 and m - 1 == S // 2:
-                w[m - 1] = 1.0  # Nyquist row is its own conjugate
-            inv.append((w[:, None] * np.cos(ang) / S,
-                        -w[:, None] * np.sin(ang) / S))
-    return tuple(x for pair in fwd + inv for x in pair)
-
-
-def _cplx_apply(ar, ai, fr, fi, axis, f_axis, conj=False):
-    """Apply one (split-real) complex factor matrix along ``axis``:
-    contract that axis of (ar, ai) with axis ``f_axis`` of the factor and
-    put the factor's other axis back in its place.  ``ai=None`` encodes a
-    real operand (the pipeline entry).  ``conj`` multiplies by the
-    conjugated factor — the adjoint the backward pipeline applies."""
-
-    def td(a, f):
-        return jnp.tensordot(a, f, axes=[[axis], [f_axis]])
-
-    if ai is None:
-        br, bi = td(ar, fr), td(ar, fi)
-        if conj:
-            bi = -bi
-    elif conj:
-        br = td(ar, fr) + td(ai, fi)
-        bi = td(ai, fr) - td(ar, fi)
-    else:
-        br = td(ar, fr) - td(ai, fi)
-        bi = td(ar, fi) + td(ai, fr)
-    return jnp.moveaxis(br, -1, axis), jnp.moveaxis(bi, -1, axis)
-
-
-def _fused_quantize(xhr, xhi, cast_to, sim_fmt, acc):
-    """The fft_in boundary quantisation on the VMEM-resident spectrum:
-    the simulated fp8 grid (Appendix B.11) and/or the half storage grid —
-    value-identical to the staged ``fft_in.quantize`` + operand cast."""
-    if sim_fmt is not None:
-        from repro.core.precision import simulate_fp8
-
-        xhr = simulate_fp8(xhr.astype(jnp.float32), sim_fmt).astype(acc)
-        xhi = simulate_fp8(xhi.astype(jnp.float32), sim_fmt).astype(acc)
-    return _cast_tiles(cast_to, xhr, xhi)
-
-
-def _fused_spectrum(x_ref, fwd, cast_to, sim_fmt):
-    """x tile -> quantised, mode-flattened split-real spectrum."""
-    x = x_ref[...]
-    acc = _acc_dtype(x.dtype)
-    ar, ai = x.astype(acc), None
-    for k, (fr, fi) in enumerate(fwd):
-        ar, ai = _cplx_apply(ar, ai, fr, fi, 2 + k, 1)
-    lead = ar.shape[:2]
-    xhr = ar.reshape(*lead, -1)
-    xhi = ai.reshape(*lead, -1)
-    xhr, xhi = _fused_quantize(xhr, xhi, cast_to, sim_fmt, acc)
-    return xhr, xhi, ar.shape[2:], acc
-
-
-def _split_factor_refs(fac_refs, ndim):
-    vals = [f[...] for f in fac_refs]
-    fwd = [(vals[2 * k], vals[2 * k + 1]) for k in range(ndim)]
-    inv = [(vals[2 * ndim + 2 * k], vals[2 * ndim + 2 * k + 1])
-           for k in range(ndim)]
-    return fwd, inv
-
-
-def _fused_fwd_kernel(*refs, ndim, cast_to=None, sim_fmt=None):
-    """One batch-tile step of the fused pipeline.
-
-    Refs: x (BB, I, *spatial) f32, wg re/im (I, O, Mh) f32, then the
-    2*ndim forward + 2*ndim inverse factor matrices -> y (BB, O, *spatial).
-    ``Mh`` is the flattened retained-row count (2^(ndim-1) * prod(modes)).
-    """
-    x_ref, wr_ref, wi_ref = refs[:3]
-    fwd, inv = _split_factor_refs(refs[3:3 + 4 * ndim], ndim)
-    y_ref = refs[-1]
-
-    xhr, xhi, mode_shape, acc = _fused_spectrum(x_ref, fwd, cast_to, sim_fmt)
-    wr, wi = _cast_tiles(cast_to, wr_ref[...], wi_ref[...])
-
-    def bmm(a, b):
-        # contract I; batch over flattened modes -> (Mh, BB, O)
-        return jax.lax.dot_general(
-            a, b, (((1,), (0,)), ((2,), (2,))), preferred_element_type=acc)
-
-    yhr = jnp.transpose(bmm(xhr, wr) - bmm(xhi, wi), (1, 2, 0)).astype(acc)
-    yhi = jnp.transpose(bmm(xhr, wi) + bmm(xhi, wr), (1, 2, 0)).astype(acc)
-    BB, O = yhr.shape[:2]
-    br = yhr.reshape(BB, O, *mode_shape)
-    bi = yhi.reshape(BB, O, *mode_shape)
-    for k in range(ndim - 1):
-        br, bi = _cplx_apply(br, bi, *inv[k], 2 + k, 0)
-    cr, ci = inv[ndim - 1]
-    ax = 2 + ndim - 1
-    # real-output last axis: y = yh_re@C_re + yh_im@C_im (hermitian fold)
-    y = (jnp.tensordot(br, cr, axes=[[ax], [0]])
-         + jnp.tensordot(bi, ci, axes=[[ax], [0]]))
-    y_ref[...] = jnp.moveaxis(y, -1, ax).astype(y_ref.dtype)
-
-
-def _fused_bwd_kernel(*refs, ndim, cast_to=None, sim_fmt=None):
-    """Transposed pipeline for one batch tile: cotangent -> adjoint iDFT
-    -> contraction VJPs -> adjoint DFT -> real part.
-
-    Refs: x, wg re/im, the 4*ndim factors, g (BB, O, *spatial) ->
-    dx (BB, I, *spatial), dwg re/im (I, O, Mh).  The dw blocks revisit
-    across the batch grid: zero-init on the first step, then accumulate.
-    """
-    x_ref, wr_ref, wi_ref = refs[:3]
-    fwd, inv = _split_factor_refs(refs[3:3 + 4 * ndim], ndim)
-    g_ref = refs[3 + 4 * ndim]
-    dx_ref, dwr_ref, dwi_ref = refs[-3:]
-
-    # recompute the quantised spectrum in-tile (cheaper than saving the
-    # VMEM-resident intermediate to HBM, which would defeat the fusion)
-    xhr, xhi, mode_shape, acc = _fused_spectrum(x_ref, fwd, cast_to, sim_fmt)
-    wr, wi = _cast_tiles(cast_to, wr_ref[...], wi_ref[...])
-
-    # adjoint of the inverse transform: gh = dL/dyh
-    g = g_ref[...].astype(acc)
-    cr, ci = inv[ndim - 1]
-    ax = 2 + ndim - 1
-    ghr = jnp.moveaxis(jnp.tensordot(g, cr, axes=[[ax], [1]]), -1, ax)
-    ghi = jnp.moveaxis(jnp.tensordot(g, ci, axes=[[ax], [1]]), -1, ax)
-    for k in reversed(range(ndim - 1)):
-        ghr, ghi = _cplx_apply(ghr, ghi, *inv[k], 2 + k, 1, conj=True)
-    BB = ghr.shape[0]
-    ghr = ghr.reshape(BB, ghr.shape[1], -1)
-    ghi = ghi.reshape(BB, ghi.shape[1], -1)
-    # same storage grid as the forward tiles (the dense backward rounds
-    # its g tiles identically) — and the matmul operand dtypes must agree
-    ghr, ghi = _cast_tiles(cast_to, ghr, ghi)
-
-    def bmm(a, b, dims):
-        return jax.lax.dot_general(
-            a, b, (dims, ((2,), (2,))), preferred_element_type=acc)
-
-    # dxh = gh . conj(wg): contract O, batch modes -> (Mh, BB, I)
-    d_x = ((1,), (1,))
-    dxhr = jnp.transpose(bmm(ghr, wr, d_x) + bmm(ghi, wi, d_x), (1, 2, 0))
-    dxhi = jnp.transpose(bmm(ghi, wr, d_x) - bmm(ghr, wi, d_x), (1, 2, 0))
-    # dwg = conj(xh) . gh: contract BB, batch modes -> (Mh, I, O);
-    # batch-independent, so accumulate across the grid
-    d_w = ((0,), (0,))
-    dwr = jnp.transpose(bmm(xhr, ghr, d_w) + bmm(xhi, ghi, d_w), (1, 2, 0))
-    dwi = jnp.transpose(bmm(xhr, ghi, d_w) - bmm(xhi, ghr, d_w), (1, 2, 0))
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        dwr_ref[...] = jnp.zeros(dwr_ref.shape, dwr_ref.dtype)
-        dwi_ref[...] = jnp.zeros(dwi_ref.shape, dwi_ref.dtype)
-
-    dwr_ref[...] += dwr.astype(dwr_ref.dtype)
-    dwi_ref[...] += dwi.astype(dwi_ref.dtype)
-
-    # adjoint of the forward DFT, then project to the real input space
-    dar = dxhr.reshape(BB, dxhr.shape[1], *mode_shape).astype(acc)
-    dai = dxhi.reshape(BB, dxhi.shape[1], *mode_shape).astype(acc)
-    for k in reversed(range(ndim)):
-        dar, dai = _cplx_apply(dar, dai, *fwd[k], 2 + k, 0, conj=True)
-    dx_ref[...] = dar.astype(dx_ref.dtype)
-
-
-def _pad_batch(a: jnp.ndarray, block_b: int) -> jnp.ndarray:
-    pad = (-a.shape[0]) % block_b
-    if not pad:
-        return a
-    return jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
-
-
-def _fused_specs(B_block, I, O, Mh, spatial, factors):
-    ndim = len(spatial)
-    zeros = (0,) * ndim
-
-    def batch_spec(ch):
-        return pl.BlockSpec((B_block, ch, *spatial),
-                            lambda b: (b, 0, *zeros))
-
-    w_spec = pl.BlockSpec((I, O, Mh), lambda b: (0, 0, 0))
-    f_specs = [pl.BlockSpec(f.shape, lambda b: (0, 0)) for f in factors]
-    return batch_spec(I), batch_spec(O), w_spec, f_specs
-
-
-def _fused_fwd_call(config, x, wgr, wgi):
-    modes, block_b, _bb_bwd, interpret, out_dtype, cast_to, sim_fmt = config
-    B, I = x.shape[:2]
-    spatial = x.shape[2:]
-    O, Mh = wgr.shape[1], wgr.shape[2]
-    acc = _acc_dtype(x.dtype)
-    factors = tuple(jnp.asarray(f, acc)
-                    for f in fused_factors(spatial, modes))
-    xp = _pad_batch(x, block_b)
-    Bp = xp.shape[0]
-    x_s, y_s, w_s, f_s = _fused_specs(block_b, I, O, Mh, spatial, factors)
-    y = pl.pallas_call(
-        functools.partial(_fused_fwd_kernel, ndim=len(modes),
-                          cast_to=cast_to, sim_fmt=sim_fmt),
-        grid=(Bp // block_b,),
-        in_specs=[x_s, w_s, w_s, *f_s],
-        out_specs=y_s,
-        out_shape=jax.ShapeDtypeStruct((Bp, O, *spatial), out_dtype),
-        interpret=interpret,
-        compiler_params=MOSAIC_PARAMS,
-    )(xp, wgr, wgi, *factors)
-    return y[:B]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _fused_op(config, x, wgr, wgi):
-    return _fused_fwd_call(config, x, wgr, wgi)
-
-
-def _fused_op_fwd(config, x, wgr, wgi):
-    return _fused_fwd_call(config, x, wgr, wgi), (x, wgr, wgi)
-
-
-def _fused_op_bwd(config, res, g):
-    x, wgr, wgi = res
-    modes, _bb, block_b, interpret, _out, cast_to, sim_fmt = config
-    B, I = x.shape[:2]
-    spatial = x.shape[2:]
-    O, Mh = wgr.shape[1], wgr.shape[2]
-    acc = _acc_dtype(x.dtype)
-    factors = tuple(jnp.asarray(f, acc)
-                    for f in fused_factors(spatial, modes))
-    xp = _pad_batch(x, block_b)
-    gp = _pad_batch(g.astype(acc), block_b)
-    Bp = xp.shape[0]
-    x_s, g_s, w_s, f_s = _fused_specs(block_b, I, O, Mh, spatial, factors)
-    dx, dwr, dwi = pl.pallas_call(
-        functools.partial(_fused_bwd_kernel, ndim=len(modes),
-                          cast_to=cast_to, sim_fmt=sim_fmt),
-        grid=(Bp // block_b,),
-        in_specs=[x_s, w_s, w_s, *f_s, g_s],
-        out_specs=[x_s, w_s, w_s],
-        out_shape=[
-            jax.ShapeDtypeStruct((Bp, I, *spatial), x.dtype),
-            # dw accumulates across revisited blocks at the accumulator
-            # dtype; cast back to the primal dtype below
-            jax.ShapeDtypeStruct((I, O, Mh), acc),
-            jax.ShapeDtypeStruct((I, O, Mh), acc),
-        ],
-        interpret=interpret,
-        compiler_params=MOSAIC_PARAMS,
-    )(xp, wgr, wgi, *factors, gp)
-    return dx[:B], dwr.astype(wgr.dtype), dwi.astype(wgi.dtype)
-
-
-_fused_op.defvjp(_fused_op_fwd, _fused_op_bwd)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("modes", "block_b", "block_b_bwd", "interpret",
-                     "out_dtype", "cast_to", "sim_fmt"),
-)
-def spectral_fused_pallas(
-    x: jnp.ndarray,
-    wgr: jnp.ndarray,
-    wgi: jnp.ndarray,
-    *,
-    modes: tuple,
-    block_b: int = 1,
-    block_b_bwd: int | None = None,
-    interpret: bool | None = None,
-    out_dtype=None,
-    cast_to=None,
-    sim_fmt: str | None = None,
-) -> jnp.ndarray:
-    """Fused rFFT -> quantise -> contract -> irFFT (differentiable).
-
-    Args:
-      x: (B, I, *spatial) real f32 physical-space input (post-stabiliser).
-      wgr/wgi: (I, O, Mh) corner-gathered split-real spectral weights,
-        flattened row-major over the retained rows per axis (2m for every
-        truncated full-FFT axis — low block then high block — and m for
-        the last, rfft, axis); ``kernels.ops.gather_corner_weights``
-        builds this layout from the per-corner (nc, I, O, *modes) params.
-      modes: retained modes per axis (static).
-      block_b: batch-tile size — the grid walks ceil(B/block_b) steps
-        with the whole spectral pipeline VMEM-resident per step.
-      cast_to: half storage grid applied to the spectrum AND the weight
-        tiles in VMEM (the staged ``fft_in.quantize`` + operand cast).
-      sim_fmt: simulated-fp8 grid ("fp8_e4m3" / "fp8_e5m2") applied to
-        the spectrum only, before ``cast_to`` (Appendix B.11 boundary).
-
-    Returns y: (B, O, *spatial) real, at ``out_dtype`` (default x dtype).
-    Reverse-mode differentiation runs the transposed pipeline in one
-    backward Pallas kernel on the ``block_b_bwd`` batch tiling.
-    """
-    if x.ndim != 2 + len(modes):
-        raise ValueError(
-            f"spectral_fused_pallas: x {x.shape} vs modes {modes} — "
-            f"expected (B, I, *spatial) with one spatial axis per mode")
-    spatial = x.shape[2:]
-    if not fused_exact(spatial, modes):
-        raise ValueError(
-            f"spectral_fused_pallas: modes {modes} do not fit spatial "
-            f"{spatial} (need 2m <= S per truncated axis and "
-            f"m <= S//2+1 on the rfft axis)")
-    rows = _fused_rows(spatial, modes)
-    Mh = 1
-    for r in rows:
-        Mh *= r
-    if wgr.shape[-1] != Mh or wgr.shape != wgi.shape or wgr.ndim != 3:
-        raise ValueError(
-            f"spectral_fused_pallas: weight {wgr.shape} — expected "
-            f"(I, O, {Mh}) corner-gathered rows for modes {modes}")
-    out_dtype = jnp.dtype(out_dtype or x.dtype)
-    cast_to = jnp.dtype(cast_to) if cast_to is not None else None
-    config = (tuple(int(m) for m in modes), int(block_b),
-              int(block_b_bwd or block_b), resolve_interpret(interpret),
-              out_dtype, cast_to, sim_fmt)
-    return _fused_op(config, x, wgr, wgi)
-
-
-# ---------------------------------------------------------------------------
 # VMEM budgeting
 # ---------------------------------------------------------------------------
 
 
-# Mosaic's tiling rules for the staged families: the minor dim of every
+# Mosaic's tiling rules for the kernels here: the minor dim of every
 # block is a multiple of 128 lanes or the whole (padded) axis, the
 # second-minor a multiple of 8 sublanes or the whole axis.  The
 # estimators below count what a compiled grid step holds: every pipelined
@@ -1245,84 +827,6 @@ def lshared_vmem_bytes(B: int, I: int, O: int, Mm: int, block_l: int,
     blocks = 2 * block_l * ((I + O) * Mm * 2 * itemsize
                             + I * O * 2 * itemsize + I * O * 2 * 4)
     return blocks + 6 * max(I, O) * Mm * block_l * 4
-
-
-def _pad(n: int, to: int) -> int:
-    return -(-int(n) // to) * to
-
-
-def _fused_tile_elems(block_b: int, I: int, O: int, spatial, modes):
-    """(x tile, w tile, y tile, factor, worst transform intermediate,
-    per-product contraction footprint) element counts for one fused
-    grid step."""
-    rows = _fused_rows(spatial, modes)
-    S = Mh = 1
-    for s in spatial:
-        S *= int(s)
-    for r in rows:
-        Mh *= int(r)
-    fac = 4 * sum(int(r) * int(s) for r, s in zip(rows, spatial))
-    # per-axis transform intermediates: spatial axes collapse to mode
-    # rows one at a time, so the worst step holds the largest mixed shape
-    # (split re+im) for the wider of the channel counts
-    inter, cur = 0, 1
-    tail = S
-    for k in range(len(modes) + 1):
-        inter = max(inter, cur * tail)
-        if k < len(modes):
-            cur *= int(rows[k])
-            tail //= int(spatial[k])
-    inter *= 2 * block_b * max(I, O)
-    # the mode-batched contraction products, (Mh, BB, O) forward and
-    # (Mh, I, O) for dw, each padded to whole (8, 128) vector tiles
-    prod = Mh * max(_pad(block_b, SUBLANES) * _pad(max(I, O), LANES),
-                    _pad(I, SUBLANES) * _pad(O, LANES))
-    return (block_b * I * S, 2 * I * O * Mh, block_b * O * S, fac, inter,
-            prod)
-
-
-def fused_vmem_bytes(block_b: int, I: int, O: int, spatial, modes,
-                     itemsize: int = 4) -> int:
-    """Forward VMEM working set of one fused grid step: the x / weight /
-    output tiles plus the DFT factors, the worst per-axis transform
-    intermediate (split-real, accumulator dtype) and the four f32
-    contraction products."""
-    x_t, w_t, y_t, fac, inter, prod = _fused_tile_elems(
-        block_b, I, O, spatial, modes)
-    return (x_t + w_t + y_t) * itemsize + fac * 4 + inter * 4 \
-        + 4 * prod * 4
-
-
-def fused_vmem_bytes_bwd(block_b: int, I: int, O: int, spatial, modes,
-                         itemsize: int = 4) -> int:
-    """Backward working set: the forward tiles plus the cotangent tile,
-    the dx tile and the two f32 dw accumulator blocks (the transposed
-    pipeline recomputes the spectrum in-tile, so both transform
-    intermediates are live), and the four f32 contraction products."""
-    x_t, w_t, y_t, fac, inter, prod = _fused_tile_elems(
-        block_b, I, O, spatial, modes)
-    tiles = (x_t + w_t + 2 * y_t + x_t) * itemsize
-    return tiles + w_t * 4 + fac * 4 + 2 * inter * 4 + 4 * prod * 4
-
-
-def pick_block_b(B: int, I: int, O: int, spatial, modes, *,
-                 itemsize: int = 4, budget: int = VMEM_BUDGET // 2,
-                 train: bool = True) -> int:
-    """Largest power-of-two batch tile whose fused working set fits in
-    ``budget`` bytes of VMEM (1 is the heuristic's last resort — callers
-    deciding fused-vs-staged should check ``fused_vmem_bytes(1, ...)``
-    themselves)."""
-    for bb in (8, 4, 2, 1):
-        if bb > max(B, 1):
-            continue
-        need = fused_vmem_bytes(I=I, O=O, spatial=spatial, modes=modes,
-                                block_b=bb, itemsize=itemsize)
-        if train:
-            need = max(need, fused_vmem_bytes_bwd(
-                bb, I, O, spatial, modes, itemsize))
-        if need <= budget:
-            return bb
-    return 1
 
 
 def pick_block_l(B: int, I: int, O: int, L: int, Mm: int, *,

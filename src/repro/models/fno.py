@@ -52,9 +52,8 @@ class FNOConfig:
     #: Tri-state: None = auto (Pallas kernels on TPU backends and under
     #: REPRO_USE_PALLAS=1, einsum elsewhere); True/False force it.
     use_pallas: Optional[bool] = None
-    #: Tri-state: None = auto (the one-grid rFFT→contract→irFFT megakernel
-    #: whenever the Pallas path is on and the dense layer shape/policy is
-    #: viable; REPRO_FUSE_SPECTRAL=0 kills it); True/False force it.
+    #: Read by no code; kept only because bench/tests/test_bench_counts.py
+    #: still passes it.
     fuse_spectral: Optional[bool] = None
     positional_embedding: bool = True  # append normalised grid coords
 
@@ -215,7 +214,6 @@ def _forward(params: dict, x: jnp.ndarray, cfg: FNOConfig,
         ldt = policy.at(f"fno/layer{layer}/dense").compute_dtype
         y = spectral_conv_apply(
             spect, h, cfg.modes, policy, use_pallas=cfg.use_pallas,
-            fuse_spectral=cfg.fuse_spectral,
             site=f"fno/layer{layer}/spectral",
         ).astype(ldt)
         s = jnp.moveaxis(

@@ -39,9 +39,6 @@ DEFAULT_KEYS = (
     ("dense", (2, 16, 16, 216), "bfloat16"),
     ("cp", (4, 32, 32, 16, 144), "bfloat16"),
     ("lshared", (2, 8, 8, 12, 9), "bfloat16"),
-    # the fused megakernel at bench_kernels' fused-2d case:
-    # (B, I, O, *spatial, *modes)
-    ("spectral_fused", (4, 16, 16, 24, 24, 6, 6), "bfloat16"),
 )
 
 #: CI smoke keys: tiny shapes, every family still covered
@@ -50,7 +47,6 @@ SMOKE_KEYS = (
     ("dense-fused", (2, 8, 8, 40), "bfloat16"),
     ("cp", (2, 8, 8, 4, 40), "bfloat16"),
     ("lshared", (2, 8, 8, 12, 9), "bfloat16"),
-    ("spectral_fused", (2, 4, 4, 12, 9, 3, 3), "bfloat16"),
 )
 
 

@@ -51,7 +51,7 @@ from repro.kernels.spectral_contract import KERNEL_VERSION
 FORMAT_VERSION = 1
 
 #: kernel families a calibration entry may address
-FAMILIES = ("dense", "dense-fused", "cp", "lshared", "spectral_fused")
+FAMILIES = ("dense", "dense-fused", "cp", "lshared")
 
 #: env var consulted by ``active_cache`` when nothing was activated
 #: explicitly — the zero-plumbing way to point a whole process (trainer,

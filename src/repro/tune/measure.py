@@ -28,15 +28,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.spectral_contract import (
-    _fused_rows,
     resolve_interpret,
     spectral_contract_cp_pallas,
     spectral_contract_lshared_pallas,
     spectral_contract_pallas,
-    spectral_fused_pallas,
 )
 from repro.launch.roofline import PEAKS
-from .space import Candidate, family_itemsize, fused_axes
+from .space import Candidate, family_itemsize
 
 
 def bytes_moved(family: str, shape, dtype: str) -> int:
@@ -46,28 +44,6 @@ def bytes_moved(family: str, shape, dtype: str) -> int:
     measurement — good enough to rank candidates and to normalise walls
     into achieved GB/s."""
     itemsize = family_itemsize(family, dtype)
-    if family in ("spectral_fused", "spectral_staged"):
-        # real-valued physical tensors + split-real gathered weight; no
-        # re+im doubling of x/y.  ``spectral_staged`` is the same
-        # boundary traffic *plus* the HBM round-trips of the 3-stage
-        # pipeline's intermediate spectra (the rFFT output written and
-        # re-read, the scattered contraction output written and re-read
-        # by the irFFT) — the model the fused bench leg compares
-        # against, at the staged f32 spectrum width.
-        B, I, O, spatial, modes = fused_axes(shape)
-        S = int(np.prod(spatial))
-        Mh = int(np.prod(_fused_rows(spatial, modes)))
-        x_el, w_el, y_el = B * I * S, 2 * I * O * Mh, B * O * S
-        fwd = x_el + w_el + y_el
-        bwd = y_el + x_el + w_el + x_el + w_el  # g in, x/w re-read, dx/dw out
-        elems = fwd + bwd
-        if family == "spectral_staged":
-            Sh = int(np.prod(spatial[:-1])) * (spatial[-1] // 2 + 1)
-            spec_in = 2 * B * I * Sh   # rFFT out: written + re-read
-            spec_out = 2 * B * O * Sh  # scattered contract out: idem
-            elems += 2 * (spec_in + spec_out)   # fwd
-            elems += 4 * (spec_in + spec_out)   # bwd re-traverses both
-        return int(elems) * itemsize
     if family in ("dense", "dense-fused"):
         B, I, O, M = shape
         fwd = (B * I + I * O + B * O) * M
@@ -94,16 +70,11 @@ def make_operands(family: str, shape, dtype: str, seed: int = 0):
     oracle check rebuilds, so a validated entry was validated on the
     data it was timed on."""
     rng = np.random.RandomState(seed)
-    op_dtype = (jnp.float32 if family in ("dense-fused", "spectral_fused")
-                else jnp.dtype(dtype))
+    op_dtype = jnp.float32 if family == "dense-fused" else jnp.dtype(dtype)
 
     def arr(*s):
         return jnp.asarray(0.5 * rng.randn(*s), jnp.float32).astype(op_dtype)
 
-    if family == "spectral_fused":
-        B, I, O, spatial, modes = fused_axes(shape)
-        Mh = int(np.prod(_fused_rows(spatial, modes)))
-        return (arr(B, I, *spatial), arr(I, O, Mh), arr(I, O, Mh))
     if family in ("dense", "dense-fused"):
         B, I, O, M = shape
         return (arr(B, I, M), arr(B, I, M), arr(I, O, M), arr(I, O, M))
@@ -122,21 +93,6 @@ def build_step(cand: Candidate, *, interpret: Optional[bool] = None):
     """The jitted value_and_grad train step a candidate is timed on."""
     interpret = resolve_interpret(interpret)
     family = cand.family
-    if family == "spectral_fused":
-        _B, _I, _O, _spatial, modes = fused_axes(cand.shape)
-        kern = functools.partial(
-            spectral_fused_pallas, modes=modes,
-            block_b=cand.block_fwd, block_b_bwd=cand.block_bwd,
-            interpret=interpret,
-            cast_to=(None if cand.dtype == "float32"
-                     else jnp.dtype(cand.dtype)),
-        )
-
-        def loss(*ops):
-            return jnp.sum(kern(*ops).astype(jnp.float32) ** 2)
-
-        n = len(make_operands(family, cand.shape, cand.dtype))
-        return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(n))))
     if family in ("dense", "dense-fused"):
         kern = functools.partial(
             spectral_contract_pallas,

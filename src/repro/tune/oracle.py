@@ -27,27 +27,16 @@ import numpy as np
 
 from repro.core.precision import FORMAT_EPS
 from repro.core.theory import prec_upper_bound
-from repro.kernels.spectral_contract import (
-    _fused_rows, fused_factors, resolve_interpret,
-)
+from repro.kernels.spectral_contract import resolve_interpret
 from .measure import make_operands
-from .space import Candidate, fused_axes
+from .space import Candidate
 
 F32_EPS = float(np.finfo(np.float32).eps)
 ATOL = 1e-5
 
 #: requantising stages per family — one 4εM term each, mirroring the
 #: stage counts the differential tests budget for the same kernels.
-#: spectral_fused composes four: the forward-DFT boundary quantisation,
-#: the two contraction operand grids, and the half output store — the
-#: composed Thm 3.2 budget the fused differential leg asserts too.
-STAGES = {"dense": 2, "dense-fused": 2, "cp": 6, "lshared": 2,
-          "spectral_fused": 4}
-
-#: the fused pipeline's f32 transform stages (truncated DFT + inverse)
-#: accumulate over prod(spatial) elements; 32·ε_f32 per magnitude unit
-#: covers them at every shape the suite and tuner exercise
-FUSED_F32_C = 32
+STAGES = {"dense": 2, "dense-fused": 2, "cp": 6, "lshared": 2}
 
 
 def storage_eps(dtype: str) -> float:
@@ -97,59 +86,8 @@ def reference(cand: Candidate, ops) -> tuple:
         x, w = _c(xr, xi), _c(wr, wi)
         ref = np.einsum("bilm,iol->bolm", x, w)
         mag = np.einsum("bilm,iol->bolm", np.abs(x), np.abs(w))
-    elif family == "spectral_fused":
-        return _fused_reference(cand, ops)
     else:
         raise ValueError(f"unknown kernel family {family!r}")
-    return ref, mag
-
-
-def _apply_factor(a, f, axis, f_axis):
-    return np.moveaxis(np.tensordot(a, f, axes=[[axis], [f_axis]]), -1, axis)
-
-
-def _fused_reference(cand: Candidate, ops) -> tuple:
-    """Composed f64 reference for the fused pipeline, from the same
-    storage-rounded spectrum/weights the kernel contracts, with the
-    magnitude ``M`` composed through the absolute factor matrices — the
-    per-element Thm 3.2 envelope of the whole rFFT→contract→irFFT chain,
-    not of one stage."""
-    x, wgr, wgi = (np.asarray(a, np.float64) for a in ops)
-    B, I, O, spatial, modes = fused_axes(cand.shape)
-    ndim = len(modes)
-    facs = fused_factors(spatial, modes)
-    fwd = [(facs[2 * k], facs[2 * k + 1]) for k in range(ndim)]
-    inv = [(facs[2 * ndim + 2 * k], facs[2 * ndim + 2 * k + 1])
-           for k in range(ndim)]
-
-    a = x.astype(np.complex128)
-    mag_a = np.abs(x)
-    for k, (fr, fi) in enumerate(fwd):
-        F = fr + 1j * fi
-        a = _apply_factor(a, F, 2 + k, 1)
-        mag_a = _apply_factor(mag_a, np.abs(F), 2 + k, 1)
-    ah = a.reshape(B, I, -1)
-    mag_ah = mag_a.reshape(B, I, -1)
-    if cand.dtype != "float32":
-        ah = (_rounded(ah.real, cand.dtype)
-              + 1j * _rounded(ah.imag, cand.dtype))
-        wgr = _rounded(wgr, cand.dtype)
-        wgi = _rounded(wgi, cand.dtype)
-    w = wgr + 1j * wgi
-    yh = np.einsum("bim,iom->bom", ah, w)
-    mag_yh = np.einsum("bim,iom->bom", mag_ah, np.abs(w))
-    rows = _fused_rows(spatial, modes)
-    yh = yh.reshape(B, O, *rows)
-    mag_yh = mag_yh.reshape(B, O, *rows)
-    for k in range(ndim - 1):
-        G = inv[k][0] + 1j * inv[k][1]
-        yh = _apply_factor(yh, G, 2 + k, 0)
-        mag_yh = _apply_factor(mag_yh, np.abs(G), 2 + k, 0)
-    cr, ci = inv[-1]
-    ax = 2 + ndim - 1
-    ref = (_apply_factor(yh.real, cr, ax, 0)
-           + _apply_factor(yh.imag, ci, ax, 0))
-    mag = _apply_factor(mag_yh, np.abs(cr) + np.abs(ci), ax, 0)
     return ref, mag
 
 
@@ -168,16 +106,7 @@ def check(cand: Candidate, *, interpret: Optional[bool] = None,
     interpret = resolve_interpret(interpret)
     ops = make_operands(cand.family, cand.shape, cand.dtype, seed=seed)
     out_dtype = jnp.dtype(cand.dtype)
-    if cand.family == "spectral_fused":
-        from repro.kernels.spectral_contract import spectral_fused_pallas
-
-        _B, _I, _O, _spatial, modes = fused_axes(cand.shape)
-        y = spectral_fused_pallas(
-            *ops, modes=modes, block_b=cand.block_fwd,
-            block_b_bwd=cand.block_bwd, interpret=interpret,
-            cast_to=None if cand.dtype == "float32" else out_dtype)
-        got = np.asarray(y.astype(jnp.float32), np.float64)
-    elif cand.family in ("dense", "dense-fused"):
+    if cand.family in ("dense", "dense-fused"):
         yr, yi = d_kern(
             *ops, block_m=cand.block_fwd, block_m_bwd=cand.block_bwd,
             interpret=interpret, out_dtype=out_dtype,
@@ -190,15 +119,13 @@ def check(cand: Candidate, *, interpret: Optional[bool] = None,
         yr, yi = l_kern(
             *ops, block_l=cand.block_fwd, block_l_bwd=cand.block_bwd,
             interpret=interpret, out_dtype=out_dtype)
-    if cand.family != "spectral_fused":
-        got = _c(np.asarray(yr.astype(jnp.float32)),
-                 np.asarray(yi.astype(jnp.float32)))
+    got = _c(np.asarray(yr.astype(jnp.float32)),
+             np.asarray(yi.astype(jnp.float32)))
 
     ref, mag = reference(cand, ops)
     eps = storage_eps(cand.dtype)
-    f32_c = FUSED_F32_C if cand.family == "spectral_fused" else 32
     budget = (STAGES[cand.family] * prec_upper_bound(eps, mag)
-              + f32_c * F32_EPS * mag + ATOL)
+              + 32 * F32_EPS * mag + ATOL)
     if perturb:
         # seeded violation: shift the kernel output by perturb×budget so
         # any |perturb| > 1 must trip the gate everywhere

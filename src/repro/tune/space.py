@@ -22,8 +22,6 @@ from typing import List, Optional, Sequence, Tuple
 from repro.kernels.spectral_contract import (
     VMEM_BUDGET,
     cp_vmem_bytes,
-    fused_vmem_bytes,
-    fused_vmem_bytes_bwd,
     lshared_vmem_bytes,
     vmem_bytes,
     vmem_bytes_bwd,
@@ -34,22 +32,6 @@ from repro.kernels.spectral_contract import (
 #: picked (just not necessarily the one it would)
 BLOCKS_M = (512, 256, 128, 64, 32, 16, 8)
 BLOCKS_L = (256, 128, 64, 32, 16, 8, 4, 2, 1)
-BLOCKS_B = (8, 4, 2, 1)
-
-
-def fused_axes(shape: Sequence[int]) -> Tuple[
-        int, int, int, Tuple[int, ...], Tuple[int, ...]]:
-    """Unpack a ``spectral_fused`` shape key ``(B, I, O, *spatial,
-    *modes)`` — spatial and modes have equal length, so the split is
-    unambiguous for any rank."""
-    B, I, O = (int(s) for s in shape[:3])
-    rest = shape[3:]
-    d = len(rest) // 2
-    if d < 1 or len(rest) != 2 * d:
-        raise ValueError(f"malformed spectral_fused shape {tuple(shape)}")
-    spatial = tuple(int(s) for s in rest[:d])
-    modes = tuple(int(s) for s in rest[d:])
-    return B, I, O, spatial, modes
 
 #: same headroom the heuristics leave: half the physical VMEM
 DEFAULT_BUDGET = VMEM_BUDGET // 2
@@ -59,10 +41,9 @@ DEFAULT_BUDGET = VMEM_BUDGET // 2
 class Candidate:
     """One (family, shape, dtype, fwd/bwd tile) point of the search."""
 
-    family: str            # dense | dense-fused | cp | lshared | spectral_fused
+    family: str            # dense | dense-fused | cp | lshared
     shape: Tuple[int, ...]  # dense: (B,I,O,M)  cp: (B,I,O,R,M)
                             # lshared: (B,I,O,L,Mm)
-                            # spectral_fused: (B,I,O,*spatial,*modes)
     dtype: str             # storage dtype name, e.g. "bfloat16"
     block_fwd: int
     block_bwd: int
@@ -70,11 +51,11 @@ class Candidate:
 
 def family_itemsize(family: str, dtype: str) -> int:
     """Bytes/element the family's tiles stream: the storage dtype's —
-    except the cast-fusing families (dense-fused, spectral_fused),
-    which stream f32 operands and quantise on tiles in VMEM."""
+    except the cast-fusing family (dense-fused), which streams f32
+    operands and quantises on tiles in VMEM."""
     import jax.numpy as jnp
 
-    if family in ("dense-fused", "spectral_fused"):
+    if family == "dense-fused":
         return 4
     return jnp.dtype(dtype).itemsize
 
@@ -96,23 +77,14 @@ def tile_vmem_bytes(family: str, shape: Sequence[int], block: int,
     if family == "lshared":
         B, I, O, _L, Mm = shape
         return lshared_vmem_bytes(B, I, O, Mm, block, itemsize)
-    if family == "spectral_fused":
-        _B, I, O, spatial, modes = fused_axes(shape)
-        if direction == "fwd":
-            return fused_vmem_bytes(block, I, O, spatial, modes,
-                                    itemsize=itemsize)
-        return fused_vmem_bytes_bwd(block, I, O, spatial, modes,
-                                    itemsize=itemsize)
     raise ValueError(f"unknown kernel family {family!r}")
 
 
 def _tiled_extent(family: str, shape: Sequence[int]) -> int:
     """The axis length the family tiles over (M for mode-tiled kernels,
-    L for the l-shared one, the batch for the fused spectral grid)."""
+    L for the l-shared one)."""
     if family == "lshared":
         return int(shape[3])
-    if family == "spectral_fused":
-        return int(shape[0])
     return int(shape[-1])
 
 
@@ -126,8 +98,6 @@ def legal_blocks(family: str, shape: Sequence[int], dtype: str,
     extent = _tiled_extent(family, shape)
     if family == "lshared":
         ladder, floor = BLOCKS_L, 1
-    elif family == "spectral_fused":
-        ladder, floor = BLOCKS_B, 1
     else:
         ladder, floor = BLOCKS_M, 8
     out = []

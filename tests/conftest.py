@@ -43,22 +43,15 @@ def pytest_configure(config):
 
 def pytest_report_header(config):
     try:
-        from repro.kernels.ops import resolve_fuse_spectral, resolve_use_pallas
+        from repro.kernels.ops import resolve_use_pallas
 
         on = resolve_use_pallas(None)
-        fused = on and resolve_fuse_spectral(None)
     except Exception:  # pragma: no cover - src not importable yet
         on = bool(os.environ.get("REPRO_USE_PALLAS"))
-        fused = on
     path = "pallas" if on else "einsum"
-    kernels = ["einsum"]
-    if on:
-        kernels = ["dense", "dense-fused", "cp", "lshared"]
-        if fused:
-            kernels.append("spectral_fused")
+    kernels = ["dense", "dense-fused", "cp", "lshared"] if on else ["einsum"]
     return (f"repro spectral path: {path} "
-            f"(REPRO_USE_PALLAS={os.environ.get('REPRO_USE_PALLAS')!r}, "
-            f"REPRO_FUSE_SPECTRAL={os.environ.get('REPRO_FUSE_SPECTRAL')!r}); "
+            f"(REPRO_USE_PALLAS={os.environ.get('REPRO_USE_PALLAS')!r}); "
             f"active kernel set: {', '.join(kernels)}")
 
 try:  # pragma: no cover - prefer the real thing

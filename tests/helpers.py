@@ -6,6 +6,9 @@ elementwise budget assertion, the relative-error norm, seeded complex
 operands, the per-policy grad tolerances, and the calibration-entry
 builders the tune tests seed states with.
 """
+import contextlib
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,9 +33,9 @@ HALF_POLICY_NAMES = [
 #: compiles its own interpret-mode kernel)
 MODES_BY_NDIM = {1: (7,), 2: (3, 5), 3: (2, 3, 2)}
 
-#: odd / non-MXU-aligned spatial grids per dimensionality for the fused
-#: megakernel legs — the truncated-DFT factors must be exact on grids
-#: that are not powers of two and not even
+#: odd / non-MXU-aligned spatial grids per dimensionality for the
+#: truncated-DFT legs — the factors must be exact on grids that are not
+#: powers of two and not even
 SPATIAL_BY_NDIM = {1: (15,), 2: (9, 11), 3: (6, 7, 5)}
 
 #: grad-parity tolerance per registry policy: tight where the contract
@@ -86,15 +89,27 @@ def assert_within_budget(y_pallas, y_einsum, eps, mag, stages, label,
     )
 
 
-def fused_mag(x, wgr, wgi, spatial, modes):
-    """Composed per-output magnitude M of the fused pipeline: |x| pushed
-    through the absolute forward DFT factors, the absolute gathered
-    weight, and the absolute inverse factors — the envelope every
-    rounding stage of either the fused or the staged path lives under."""
-    from repro.kernels.spectral_contract import _fused_rows, fused_factors
+@contextlib.contextmanager
+def force_tpu_transforms():
+    """Make the backend predicate read "tpu" inside the block, so the
+    staged path takes the truncated-DFT transforms on the CPU (kernels
+    stay interpreted).  A jitted function traced inside keeps that path."""
+    from repro.core import spectral
+
+    with mock.patch.object(spectral, "_on_tpu", lambda: True):
+        yield
+
+
+def spectral_mag(x, w_re, w_im, spatial, modes):
+    """Composed per-output magnitude M of the spectral convolution: |x|
+    pushed through the absolute forward DFT factors, the absolute
+    per-corner weights, and the absolute inverse factors — the envelope
+    every rounding stage of either the DFT or the FFT path lives under.
+    ``w_re``/``w_im``: the layer's (corners, I, O, *modes) weights."""
+    from repro.core.spectral import _corner_slices, dft_factors
 
     ndim = len(modes)
-    facs = fused_factors(spatial, modes)
+    facs = dft_factors(spatial, modes)
 
     def apply(a, f, axis, f_axis):
         return np.moveaxis(
@@ -104,14 +119,15 @@ def fused_mag(x, wgr, wgi, spatial, modes):
     for k in range(ndim):
         fr, fi = facs[2 * k], facs[2 * k + 1]
         mag = apply(mag, np.abs(fr + 1j * fi), 2 + k, 1)
-    B, I = mag.shape[:2]
-    mag = mag.reshape(B, I, -1)
-    w_abs = np.abs(np.asarray(wgr, np.float64)
-                   + 1j * np.asarray(wgi, np.float64))
-    mag = np.einsum("bim,iom->bom", mag, w_abs)
-    rows = _fused_rows(spatial, modes)
-    O = mag.shape[1]
-    mag = mag.reshape(B, O, *rows)
+    # the forward factors keep each full axis's low block then its high
+    # block, so the corners of the stacked rows are the spectrum's corners
+    w_abs = np.abs(np.asarray(w_re, np.float64)
+                   + 1j * np.asarray(w_im, np.float64))
+    out = np.zeros((mag.shape[0], w_abs.shape[2], *mag.shape[2:]))
+    for c, sl in enumerate(_corner_slices(modes, mag.shape[2:])):
+        sl = (slice(None), slice(None), *sl)
+        out[sl] = np.einsum("bi...,io...->bo...", mag[sl], w_abs[c])
+    mag = out
     for k in range(ndim - 1):
         gr, gi = facs[2 * ndim + 2 * k], facs[2 * ndim + 2 * k + 1]
         mag = apply(mag, np.abs(gr + 1j * gi), 2 + k, 0)

@@ -241,18 +241,17 @@ class TestSeededKernelViolations:
         assert "in[0]" in oob[0].detail
 
     def test_fused_fake_oob_index_map_detected(self):
-        """A ``spectral_fused``-shaped call (batch-tiled grid over a
-        4-rank operand) whose batch index map overruns the padded
-        extent is caught at exactly the index-oob check — the real
-        fused family's traced calls stay clean
-        (``TestCleanTree::test_kernels_pass_clean``)."""
+        """A ``dense-fused``-shaped call (mode-tiled grid over a (B, I, M)
+        operand) whose mode index map overruns the padded extent is
+        caught at exactly the index-oob check — the real family's traced
+        calls stay clean (``TestCleanTree::test_kernels_pass_clean``)."""
         call = _call(
-            _plain_copy_kernel, grid=(2,),
-            in_specs=[_FakeSpec((2, 4, 8, 8), lambda i: (i + 1, 0, 0, 0))],
-            in_shapes=[(4, 4, 8, 8)],
-            out_specs=[_FakeSpec((2, 4, 8, 8), lambda i: (i, 0, 0, 0))],
-            out_shapes=[(4, 4, 8, 8)])
-        findings = check_call(call, "seeded:spectral_fused")
+            _plain_copy_kernel, grid=(3,),
+            in_specs=[_FakeSpec((2, 8, 16), lambda m: (0, 0, m + 1))],
+            in_shapes=[(2, 8, 48)],
+            out_specs=[_FakeSpec((2, 8, 16), lambda m: (0, 0, m))],
+            out_shapes=[(2, 8, 48)])
+        findings = check_call(call, "seeded:dense-fused")
         oob = [f for f in findings if f.check == "index-oob"]
         assert oob and all(f.severity == ERROR for f in oob)
         assert "in[0]" in oob[0].detail
@@ -308,8 +307,8 @@ class TestCleanTree:
         from repro.analyze.kernels import kernel_families
 
         names = [name for name, _, _ in kernel_families()]
-        assert "spectral_fused/fwd" in names
-        assert "spectral_fused/bwd" in names
+        assert "dense-fused/fwd" in names
+        assert "dense-fused/bwd" in names
 
     @pytest.mark.parametrize("policy_name", ["full", "mixed_fno_fp16"])
     def test_model_forward_has_no_errors(self, policy_name):

@@ -15,6 +15,8 @@ from repro.core import (
     theory,
 )
 
+from helpers import force_tpu_transforms
+
 jax.config.update("jax_platform_name", "cpu")
 
 
@@ -127,14 +129,6 @@ class TestSpectralConv:
         assert rel < 0.05, rel
 
 
-def _force_tpu_transforms(monkeypatch):
-    """Make the backend predicate read "tpu", so the staged path takes the
-    truncated-DFT transforms on the CPU (kernels stay interpreted)."""
-    from repro.core import spectral
-
-    monkeypatch.setattr(spectral, "_on_tpu", lambda: True)
-
-
 def _eqns(jaxpr):
     """Every equation of a jaxpr, sub-jaxprs (pjit, custom_vjp, scan)
     included."""
@@ -169,8 +163,7 @@ class TestDFTPath:
     @pytest.mark.parametrize("fact,use_pallas", [
         ("dense", False), ("dense", True), ("cp", True)],
         ids=["dense-einsum", "dense-pallas", "cp-pallas"])
-    def test_layer_matches_fft_path(self, monkeypatch, fact, use_pallas,
-                                    policy_name):
+    def test_layer_matches_fft_path(self, fact, use_pallas, policy_name):
         rng = np.random.RandomState(7)
         params = init_spectral_weights(jax.random.PRNGKey(7), 4, 6, (4, 5),
                                        factorization=fact)
@@ -181,16 +174,15 @@ class TestDFTPath:
         def run(p):
             def loss(p):
                 y = spectral_conv_apply(p, x, (4, 5), policy,
-                                        use_pallas=use_pallas,
-                                        fuse_spectral=False)
+                                        use_pallas=use_pallas)
                 return jnp.sum(y.astype(jnp.float32) * r), y
 
             (_, y), g = jax.value_and_grad(loss, has_aux=True)(p)
             return y, g
 
         y_fft, g_fft = run(params)
-        _force_tpu_transforms(monkeypatch)
-        y_dft, g_dft = run(params)
+        with force_tpu_transforms():
+            y_dft, g_dft = run(params)
         tol = self.RTOL[policy_name]
 
         def rel(a, b):
@@ -203,7 +195,7 @@ class TestDFTPath:
         for k in g_fft:
             assert rel(g_dft[k], g_fft[k]) <= tol, k
 
-    def test_jaxpr_has_no_fft_and_keeps_scopes(self, monkeypatch):
+    def test_jaxpr_has_no_fft_and_keeps_scopes(self):
         """No ``fft`` primitive on the DFT path, and the transforms keep the
         ``{site}/fft_in`` / ``{site}/fft_out`` named scopes that the
         benchmark's ``fft_ms.train`` reads from the device trace."""
@@ -226,27 +218,27 @@ class TestDFTPath:
 
         prims, _ = census()
         assert "fft" in prims
-        _force_tpu_transforms(monkeypatch)
-        prims, scopes = census()
+        with force_tpu_transforms():
+            prims, scopes = census()
         assert "fft" not in prims
         assert any(f"{site}/fft_in" in s for s in scopes), scopes
         assert any(f"{site}/fft_out" in s for s in scopes), scopes
 
-    def test_overlapping_corners_keep_fft_path(self, monkeypatch):
+    def test_overlapping_corners_keep_fft_path(self):
         """Where the low and high blocks overlap (2m > S) the factorisation
         is not exact: the FFT path runs, even on a TPU."""
         from repro.core.spectral import dft_transforms
 
-        _force_tpu_transforms(monkeypatch)
-        assert dft_transforms((16, 16), (8, 9))
-        assert not dft_transforms((12, 12), (7, 4))
         params = init_spectral_weights(jax.random.PRNGKey(0), 2, 2, (7, 4))
         x = jnp.zeros((1, 2, 12, 12), jnp.float32)
-        jaxpr = jax.make_jaxpr(lambda x: spectral_conv_apply(
-            params, x, (7, 4), FULL, use_pallas=False))(x)
+        with force_tpu_transforms():
+            assert dft_transforms((16, 16), (8, 9))
+            assert not dft_transforms((12, 12), (7, 4))
+            jaxpr = jax.make_jaxpr(lambda x: spectral_conv_apply(
+                params, x, (7, 4), FULL, use_pallas=False))(x)
         assert "fft" in {e.primitive.name for e in _eqns(jaxpr.jaxpr)}
 
-    def test_transform_counter_per_layer_and_path(self, monkeypatch):
+    def test_transform_counter_per_layer_and_path(self):
         """``repro_spectral_transforms_total`` counts one per layer traced,
         under the path it took."""
         params = init_spectral_weights(jax.random.PRNGKey(0), 2, 2, (4, 4))
@@ -259,14 +251,14 @@ class TestDFTPath:
         before = {p: _transforms_total(p) for p in ("dft", "fft")}
         trace(x, (4, 4))
         assert _transforms_total("fft") == before["fft"] + 1
-        _force_tpu_transforms(monkeypatch)
-        trace(x, (4, 4))
-        trace(x, (4, 4))
-        assert _transforms_total("dft") == before["dft"] + 2
         over = init_spectral_weights(jax.random.PRNGKey(0), 2, 2, (7, 4))
-        jax.make_jaxpr(lambda x: spectral_conv_apply(
-            over, x, (7, 4), FULL, use_pallas=False))(
-                jnp.zeros((1, 2, 12, 12), jnp.float32))
+        with force_tpu_transforms():
+            trace(x, (4, 4))
+            trace(x, (4, 4))
+            assert _transforms_total("dft") == before["dft"] + 2
+            jax.make_jaxpr(lambda x: spectral_conv_apply(
+                over, x, (7, 4), FULL, use_pallas=False))(
+                    jnp.zeros((1, 2, 12, 12), jnp.float32))
         assert _transforms_total("fft") == before["fft"] + 2
         assert _transforms_total("dft") == before["dft"] + 2
 

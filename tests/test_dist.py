@@ -193,7 +193,7 @@ rng = np.random.RandomState(0)
 cases = [
     (FNOConfig(in_channels=1, out_channels=1, hidden_channels=8,
                lifting_channels=8, projection_channels=8, n_layers=2,
-               modes=(4, 4), use_pallas=True, fuse_spectral=False),
+               modes=(4, 4), use_pallas=True),
      init_fno, fno_apply, (1, 12, 12)),
     (FNOConfig(in_channels=1, out_channels=1, hidden_channels=8,
                lifting_channels=8, projection_channels=8, n_layers=2,

@@ -11,17 +11,21 @@ rerun to re-record after an *intentional* numerics change.
 import json
 import os
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.analyze import dtype_trace
 from repro.precision.policy import POLICIES, get_policy
+
+from helpers import force_tpu_transforms
 
 GOLDEN_PATH = os.path.join(os.path.dirname(__file__), "golden",
                            "dtype_traces.json")
 
 _KEYS = [name + suffix
          for name in sorted(POLICIES)
-         for suffix in ("", "+pallas", "+fused")]
+         for suffix in ("", "+pallas", "+dft")]
 
 
 def _load_goldens():
@@ -31,10 +35,12 @@ def _load_goldens():
 
 def _compute(key):
     name, _, suffix = key.partition("+")
-    # "+pallas" pins the *staged* Pallas pipeline (dtype_trace defaults
-    # fuse_spectral=False); "+fused" snapshots the megakernel dispatch
-    return dtype_trace(get_policy(name), use_pallas=suffix in ("pallas", "fused"),
-                       fuse_spectral=suffix == "fused")
+    # "+pallas" is the Pallas pipeline over rfftn/irfftn (the CPU's);
+    # "+dft" the same kernels between truncated-DFT matmuls (a TPU's)
+    if suffix == "dft":
+        with force_tpu_transforms():
+            return dtype_trace(get_policy(name), use_pallas=True)
+    return dtype_trace(get_policy(name), use_pallas=suffix == "pallas")
 
 
 @pytest.fixture(scope="module")
@@ -63,9 +69,12 @@ class TestTraceInvariants:
     golden sequence (these survive jax version bumps that reorder or
     rename eqns, where the snapshots would need regeneration)."""
 
-    @pytest.mark.parametrize("name", sorted(POLICIES))
+    @pytest.mark.parametrize(
+        "name", sorted(POLICIES) + [n + "+dft" for n in sorted(POLICIES)])
     def test_no_half_accumulation_on_pallas_path(self, name):
-        trace = dtype_trace(get_policy(name), use_pallas=True)
+        """Every matmul accumulates at f32 or wider: the kernels' and, on
+        a TPU's path ("+dft"), the truncated-DFT transforms'."""
+        trace = _compute(name if "+" in name else name + "+pallas")
         for entry in trace:
             if entry.startswith("dot_general:"):
                 acc = entry.split("@acc=")[1].split("@")[0]
@@ -85,18 +94,38 @@ class TestTraceInvariants:
 
     @pytest.mark.parametrize("name", sorted(POLICIES))
     def test_fused_path_spectrum_never_leaves_vmem(self, name):
-        """The version-robust megakernel invariant: the fused dispatch
-        lowers to exactly one kernel launch with no FFT primitives and
-        zero HBM-visible half casts between rFFT and irFFT — everything
-        between the transforms lives inside the one pallas_call (the
-        trace lists a launch before descending into its body, so every
-        entry before it is HBM-visible staging)."""
-        trace = dtype_trace(get_policy(name), use_pallas=True,
-                            fuse_spectral=True)
-        assert not any(e.startswith("fft:") for e in trace), trace
-        launches = [i for i, e in enumerate(trace)
-                    if e.startswith("pallas_call:")]
-        assert len(launches) == 1, trace
-        for entry in trace[:launches[0]]:
-            assert "float16" not in entry and "bfloat16" not in entry, (
-                f"HBM-visible half cast before the fused launch: {entry}")
+        """A TPU's path computes only the retained modes: no ``fft``
+        primitive, and no half array shaped like the full rfft spectrum
+        outside the kernels — the boundary quantisation rounds the
+        truncated spectrum only.  (The FFT path quantises the whole
+        (16, 9) spectrum of the 16x16 layer before truncating it.)"""
+        from repro.core.spectral import (init_spectral_weights,
+                                         spectral_conv_apply)
+
+        params = init_spectral_weights(jax.random.PRNGKey(0), 4, 4, (4, 4))
+        x = jax.ShapeDtypeStruct((2, 4, 16, 16), jnp.float32)
+        with force_tpu_transforms():
+            assert not any(e.startswith("fft:") for e in _compute(
+                name + "+dft"))
+            closed = jax.make_jaxpr(lambda p, xx: spectral_conv_apply(
+                p, xx, (4, 4), get_policy(name), use_pallas=True))(params, x)
+        half = {jnp.dtype(jnp.float16), jnp.dtype(jnp.bfloat16)}
+        for aval in _outside_kernels(closed.jaxpr):
+            assert not (aval.dtype in half and aval.shape[-2:] == (16, 9)), (
+                f"full-spectrum half array outside the kernels: {aval}")
+
+
+def _outside_kernels(jaxpr):
+    """Avals of every value a jaxpr and its sub-jaxprs compute, not
+    descending into ``pallas_call`` bodies (what lives in VMEM)."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield v.aval
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                if hasattr(sub, "jaxpr") and hasattr(sub.jaxpr, "eqns"):
+                    yield from _outside_kernels(sub.jaxpr)
+                elif hasattr(sub, "eqns"):
+                    yield from _outside_kernels(sub)

@@ -36,7 +36,7 @@ from repro.core import (
     spectral_conv_apply,
 )
 from repro.core.precision import ComplexPair
-from repro.core.spectral import _cp_exprs, _dense_expr
+from repro.core.spectral import _cp_exprs, _dense_expr, dft_transforms
 from repro.kernels import ops, ref
 from repro.kernels.spectral_contract import (
     pick_block_m,
@@ -53,9 +53,10 @@ from helpers import (
     POLICY_NAMES,
     SPATIAL_BY_NDIM,
     assert_within_budget as _assert_within_budget,
-    fused_mag,
+    force_tpu_transforms,
     rand_complex as _randc,
     rel_err as _rel_err,
+    spectral_mag,
 )
 
 jax.config.update("jax_platform_name", "cpu")
@@ -409,23 +410,29 @@ class TestGradients:
 
 class TestFusedCastPrologue:
     """The in-kernel quantize fusion must be numerically *invisible*:
-    same storage grid, same rounding, one fewer HBM round-trip."""
+    same storage grid, same rounding, one fewer HBM round-trip.  The
+    unfused path is the one ``spectral_contract`` takes where the f32
+    operand tiles do not fit VMEM (``_fused_cast_fits`` False)."""
 
     def _xw(self, seed=17, B=2, I=3, O=4, modes=(3, 5)):
         rng = np.random.RandomState(seed)
         return _randc(rng, (B, I, *modes)), _randc(rng, (I, O, *modes))
 
+    @staticmethod
+    def _unfuse(monkeypatch):
+        monkeypatch.setattr(ops, "_fused_cast_fits", lambda *a: False)
+
     @pytest.mark.parametrize("policy_name", HALF_POLICY_NAMES)
-    def test_forward_bit_identical_to_unfused(self, policy_name):
+    def test_forward_bit_identical_to_unfused(self, policy_name,
+                                              monkeypatch):
         """In-VMEM ``astype(half)`` is the same rounding as the HBM
         ``ComplexPair.from_complex`` pass it replaces — the fused forward
         is bitwise equal, not merely within budget."""
         site = get_policy(policy_name).at("fno/layer0/spectral/contract")
         x, w = self._xw()
-        y_f = ops.spectral_contract(x, w, policy=site, block_m=4,
-                                    fuse_casts=True)
-        y_u = ops.spectral_contract(x, w, policy=site, block_m=4,
-                                    fuse_casts=False)
+        y_f = ops.spectral_contract(x, w, policy=site, block_m=4)
+        self._unfuse(monkeypatch)
+        y_u = ops.spectral_contract(x, w, policy=site, block_m=4)
         assert jnp.array_equal(jnp.asarray(y_f), jnp.asarray(y_u)), (
             policy_name)
 
@@ -433,133 +440,117 @@ class TestFusedCastPrologue:
     def test_fused_within_budget_vs_einsum(self, policy_name):
         site = get_policy(policy_name).at("fno/layer0/spectral/contract")
         x, w = self._xw(seed=18)
+        assert ops._fused_cast_fits(x.shape, w.shape[1])
         y_e = site.contract(_dense_expr(2), x, w)
-        y_p = ops.spectral_contract(x, w, policy=site, block_m=4,
-                                    fuse_casts=True)
+        y_p = ops.spectral_contract(x, w, policy=site, block_m=4)
         mag = np.einsum("bixy,ioxy->boxy", np.abs(x), np.abs(w))
         _assert_within_budget(y_p, y_e, site.eps, mag, stages=2,
                               label=f"dense-fused {policy_name}")
 
     @pytest.mark.parametrize("policy_name", HALF_POLICY_NAMES)
-    def test_grads_match_unfused(self, policy_name):
+    def test_grads_match_unfused(self, policy_name, monkeypatch):
         """The fused backward writes dx/dw at f32 (the residuals'
         dtype); the unfused one rounds them to half — they may differ
         only by that final storage rounding."""
         site = get_policy(policy_name).at("fno/layer0/spectral/contract")
         x, w = self._xw(seed=19)
 
-        def loss(x, w, fuse):
-            y = ops.spectral_contract(x, w, policy=site, block_m=4,
-                                      fuse_casts=fuse)
+        def loss(x, w):
+            y = ops.spectral_contract(x, w, policy=site, block_m=4)
             return jnp.sum(jnp.abs(jnp.asarray(y)) ** 2)
 
-        l_f, g_f = jax.value_and_grad(loss, argnums=(0, 1))(x, w, True)
-        l_u, g_u = jax.value_and_grad(loss, argnums=(0, 1))(x, w, False)
+        l_f, g_f = jax.value_and_grad(loss, argnums=(0, 1))(x, w)
+        self._unfuse(monkeypatch)
+        l_u, g_u = jax.value_and_grad(loss, argnums=(0, 1))(x, w)
         np.testing.assert_allclose(float(l_f), float(l_u), rtol=1e-6)
         tol = max(8 * site.eps, 1e-4)
         for a, b in zip(g_f, g_u, strict=True):
             assert _rel_err(a, b) <= tol, policy_name
 
-    def test_full_precision_site_never_fuses(self):
-        """No quantize rule means nothing to fuse: both flags produce
-        the identical f32 path."""
+    def test_full_precision_site_never_fuses(self, monkeypatch):
+        """No quantize rule means nothing to fuse: with or without room
+        for the f32 tiles, the identical f32 path runs."""
         x, w = self._xw(seed=20)
-        y_t = ops.spectral_contract(x, w, policy=FULL, block_m=4,
-                                    fuse_casts=True)
-        y_f = ops.spectral_contract(x, w, policy=FULL, block_m=4,
-                                    fuse_casts=False)
+        y_t = ops.spectral_contract(x, w, policy=FULL, block_m=4)
+        self._unfuse(monkeypatch)
+        y_f = ops.spectral_contract(x, w, policy=FULL, block_m=4)
         assert jnp.array_equal(y_t, y_f)
 
-    def test_pair_inputs_skip_fusion(self):
-        """Operands already rounded to half pairs have no cast to fuse;
-        the flag must be a no-op on them."""
+    def test_pair_inputs_skip_fusion(self, monkeypatch):
+        """Operands already rounded to half pairs have no cast to fuse:
+        room for the f32 tiles must make no difference to them."""
         site = get_policy("mixed_fno_bf16").at("fno/layer0/spectral/contract")
         x, w = self._xw(seed=21)
         xp = ComplexPair.from_complex(x, site.spectral_dtype)
         wp = ComplexPair.from_complex(w, site.spectral_dtype)
-        y_t = ops.spectral_contract(xp, wp, policy=site, block_m=4,
-                                    fuse_casts=True)
-        y_f = ops.spectral_contract(xp, wp, policy=site, block_m=4,
-                                    fuse_casts=False)
+        y_t = ops.spectral_contract(xp, wp, policy=site, block_m=4)
+        self._unfuse(monkeypatch)
+        y_f = ops.spectral_contract(xp, wp, policy=site, block_m=4)
         assert jnp.array_equal(y_t.re, y_f.re)
         assert jnp.array_equal(y_t.im, y_f.im)
 
-    def test_resolve_fuse_casts_env_and_flag(self, monkeypatch):
-        from repro.kernels.ops import resolve_fuse_casts
-
-        assert resolve_fuse_casts(True) is True
-        assert resolve_fuse_casts(False) is False
-        monkeypatch.setenv("REPRO_FUSE_CASTS", "0")
-        assert resolve_fuse_casts(None) is False
-        assert resolve_fuse_casts(True) is True  # explicit beats env
-        monkeypatch.setenv("REPRO_FUSE_CASTS", "1")
-        assert resolve_fuse_casts(None) is True
-        monkeypatch.delenv("REPRO_FUSE_CASTS")
-        assert resolve_fuse_casts(None) is True  # default ON
-
 
 # ---------------------------------------------------------------------------
-# Fused rFFT -> contract -> irFFT megakernel
+# The chip's spectral path: truncated-DFT transforms around the kernels
 # ---------------------------------------------------------------------------
 
 
-def _fused_layer(seed, I, O, modes):
+def _dense_layer(seed, I, O, modes):
     return init_spectral_weights(
         jax.random.PRNGKey(seed), I, O, modes, "dense")
 
 
-def _diff_fused(policy_name, B, I, O, spatial, modes, seed):
-    """The one-grid megakernel vs the staged einsum reference, under the
-    composed Thm 3.2 budget: each path has (at most) four requantising
-    stages — forward transform, quantise, contract, inverse transform —
-    so the elementwise budget carries stages=8, one ``4 eps M`` term per
-    stage of either side, with ``M`` the composed magnitude envelope of
-    the whole pipeline (``helpers.fused_mag``)."""
+def _diff_dft(policy_name, B, I, O, spatial, modes, seed):
+    """The path a TPU takes (truncated-DFT transforms around the staged
+    Pallas kernel) vs the einsum + FFT reference, under the composed
+    Thm 3.2 budget: each path has (at most) four requantising stages —
+    forward transform, quantise, contract, inverse transform — so the
+    elementwise budget carries stages=8, one ``4 eps M`` term per stage
+    of either side, with ``M`` the composed magnitude envelope of the
+    whole pipeline (``helpers.spectral_mag``)."""
     policy = get_policy(policy_name)
     fft_in = policy.at("fno/layer0/spectral/fft_in")
     ctr = policy.at("fno/layer0/spectral/contract")
-    assert ops.fused_spectral_viable(fft_in, ctr, B, I, O, spatial, modes), (
-        "test shape must engage the fused path", spatial, modes)
     rng = np.random.RandomState(seed)
-    params = _fused_layer(seed, I, O, modes)
+    params = _dense_layer(seed, I, O, modes)
     x = jnp.asarray(rng.randn(B, I, *spatial), jnp.float32)
 
-    y_f = spectral_conv_apply(params, x, modes, policy, use_pallas=True,
-                              fuse_spectral=True, site="fno/layer0/spectral")
     y_s = spectral_conv_apply(params, x, modes, policy, use_pallas=False,
                               site="fno/layer0/spectral")
+    with force_tpu_transforms():
+        assert dft_transforms(spatial, modes), (
+            "test shape must take the DFT path", spatial, modes)
+        y_f = spectral_conv_apply(params, x, modes, policy, use_pallas=True,
+                                  site="fno/layer0/spectral")
     assert y_f.shape == y_s.shape == (B, O, *spatial)
     assert y_f.dtype == y_s.dtype, (policy_name, y_f.dtype, y_s.dtype)
 
     xs = fft_in.stabilize(x)
-    wgr, wgi = ops.gather_corner_weights(
-        params["w_re"], params["w_im"], modes)
-    mag = fused_mag(np.asarray(xs, np.float64), np.asarray(wgr, np.float64),
-                    np.asarray(wgi, np.float64), spatial, modes)
+    mag = spectral_mag(xs, params["w_re"], params["w_im"], spatial, modes)
     _assert_within_budget(
         np.asarray(y_f, np.float64), np.asarray(y_s, np.float64),
         ctr.eps, mag, stages=8,
-        label=f"fused {policy_name} B{B} I{I} O{O} "
+        label=f"dft {policy_name} B{B} I{I} O{O} "
               f"spatial{spatial} modes{modes}")
 
 
 class TestFusedMegakernel:
-    """Differential proof for the ``spectral_fused`` family: the whole
-    rFFT -> contract -> irFFT pipeline in one Pallas grid must stay
-    within the composed Thm 3.2 budget against the staged einsum path,
-    for every registry policy, on odd / non-MXU-aligned grids."""
+    """Differential proof for the TPU's spectral path: truncated-DFT
+    matmuls around the staged dense kernel must stay within the composed
+    Thm 3.2 budget against the einsum + FFT reference, for every registry
+    policy, on odd / non-MXU-aligned grids."""
 
     @pytest.mark.parametrize("policy_name", POLICY_NAMES)
     @pytest.mark.parametrize("ndim", [1, 2, 3])
     def test_fused_vs_staged_all_policies(self, policy_name, ndim):
-        _diff_fused(policy_name, B=2, I=3, O=4,
+        _diff_dft(policy_name, B=2, I=3, O=4,
                     spatial=SPATIAL_BY_NDIM[ndim],
                     modes=MODES_BY_NDIM[ndim], seed=40 + ndim)
 
     def test_fused_matches_einsum_reference_full(self):
-        """Against the pure jnp staged reference (no Pallas anywhere),
-        full precision: the truncated-DFT factorisation itself."""
-        _diff_fused("full", B=1, I=2, O=2, spatial=(8, 16),
+        """Against the pure jnp reference (no Pallas, no DFT), full
+        precision: the truncated-DFT factorisation itself."""
+        _diff_dft("full", B=1, I=2, O=2, spatial=(8, 16),
                     modes=(4, 5), seed=51)
 
     @pytest.mark.slow
@@ -573,31 +564,42 @@ class TestFusedMegakernel:
     )
     @settings(max_examples=10, deadline=None)
     def test_property_fuzzed_shapes(self, B, I, O, S0, S1, policy_name):
-        """Hypothesis-fuzzed 2D shapes: odd spatial dims, modes that do
-        not divide the batch tile, Nyquist-touching last-axis modes."""
+        """Hypothesis-fuzzed 2D shapes: odd spatial dims, mode counts off
+        the kernel's tile, Nyquist-touching last-axis modes."""
         m0 = max(1, S0 // 2 - 1)           # corner blocks must not overlap
         m1 = S1 // 2 + 1                   # retain the full rfft extent
         seed = B * 100000 + I * 10000 + O * 1000 + S0 * 16 + S1
-        _diff_fused(policy_name, B, I, O, (S0, S1), (m0, m1), seed)
+        _diff_dft(policy_name, B, I, O, (S0, S1), (m0, m1), seed)
 
     def test_fused_fp64_gradcheck(self):
-        """fp64 central-difference check of the fused custom VJP itself
-        (transposed-pipeline backward kernel), on a tiny 2D case in
-        interpret mode, sampling entries of each operand."""
-        from repro.kernels.spectral_contract import spectral_fused_pallas
+        """fp64 central-difference check of the chip path's pieces
+        composed: ``truncated_rfft`` -> the dense kernel's custom VJP per
+        corner -> ``truncated_irfft``, on a tiny 2D case in interpret
+        mode, sampling entries of each operand.  The factors are f32
+        constants; the check is that the gradients differentiate the
+        forward as computed."""
+        from repro.core.spectral import (
+            _corner_slices, _stack_corners, truncated_irfft, truncated_rfft)
 
         jax.config.update("jax_enable_x64", True)
         try:
             spatial, modes = (7, 8), (3, 3)
-            rows_flat = (2 * modes[0]) * modes[1]
             rng = np.random.RandomState(7)
-            shapes = [(1, 2, *spatial), (2, 3, rows_flat), (2, 3, rows_flat)]
+            shapes = [(1, 2, *spatial)] + [(2, 2, 3, *modes)] * 2
             args = [jnp.asarray(rng.randn(*s), jnp.float64) for s in shapes]
             c = jnp.asarray(rng.randn(1, 3, *spatial), jnp.float64)
 
-            def loss(x, wgr, wgi):
-                y = spectral_fused_pallas(
-                    x, wgr, wgi, modes=modes, block_b=1, interpret=True)
+            def loss(x, w_re, w_im):
+                xf = truncated_rfft(x, modes)
+                ycs = []
+                for k, sl in enumerate(_corner_slices(modes, xf.shape[2:])):
+                    xc = xf[(slice(None), slice(None), *sl)].reshape(1, 2, -1)
+                    yr, yi = spectral_contract_pallas(
+                        jnp.real(xc), jnp.imag(xc),
+                        w_re[k].reshape(2, 3, -1), w_im[k].reshape(2, 3, -1),
+                        block_m=8, interpret=True)
+                    ycs.append(jax.lax.complex(yr, yi).reshape(1, 3, *modes))
+                y = truncated_irfft(_stack_corners(ycs, 2), spatial, modes)
                 return jnp.sum(y * c)
 
             grads = jax.grad(loss, argnums=(0, 1, 2))(*args)
@@ -622,10 +624,10 @@ class TestFusedMegakernel:
 
     @pytest.mark.slow
     def test_train_step_parity_fused_vs_staged_fp16_loss_scale(self):
-        """Full FNO train steps through the Trainer, fused megakernel vs
-        the staged Pallas path, under the fp16 policy whose
-        ``train/loss_scale`` site is on — the loss-scale interaction
-        rides through the fused custom VJP."""
+        """Full FNO train steps through the Trainer on the staged Pallas
+        path, DFT transforms (a TPU's) vs FFT transforms, under the fp16
+        policy whose ``train/loss_scale`` site is on — the loss-scale
+        interaction rides through the DFT matmuls' gradients."""
         cfg = FNOConfig(in_channels=1, out_channels=1, hidden_channels=8,
                         lifting_channels=8, projection_channels=8,
                         n_layers=2, modes=(4, 4), factorization="dense")
@@ -639,23 +641,23 @@ class TestFusedMegakernel:
 
         from repro.core import PrecisionSchedule
 
-        results = {}
-        for fuse in (False, True):
-            def loss_fn(p, batch, policy, use_pallas=None, fuse=fuse):
-                c = dataclasses.replace(cfg, use_pallas=use_pallas,
-                                        fuse_spectral=fuse)
-                return relative_l2(fno_apply(p, batch["a"], c, policy),
-                                   batch["u"])
+        def loss_fn(p, batch, policy, use_pallas=None):
+            c = dataclasses.replace(cfg, use_pallas=use_pallas)
+            return relative_l2(fno_apply(p, batch["a"], c, policy),
+                               batch["u"])
 
+        def train():
             tr = Trainer(loss_fn, params, TrainerConfig(
                 total_steps=3,
                 schedule=PrecisionSchedule.constant("mixed_fno_fp16"),
                 use_pallas=True,
             ))
             hist = tr.run(lambda step: batches[step])
-            results[fuse] = (tr.params, tr.scale_state, hist)
-        p_s, s_s, h_s = results[False]
-        p_f, s_f, h_f = results[True]
+            return tr.params, tr.scale_state, hist
+
+        p_s, s_s, h_s = train()
+        with force_tpu_transforms():
+            p_f, s_f, h_f = train()
         assert float(s_s.scale) == float(s_f.scale)
         # both paths round the spectrum onto the same fp16 grid but order
         # their f32 accumulations differently; 3 accumulated steps
@@ -663,52 +665,6 @@ class TestFusedMegakernel:
             assert _rel_err(a, b) <= 5e-3
         for hs, hf in zip(h_s, h_f, strict=True):
             assert abs(hs["loss"] - hf["loss"]) <= 0.02 * (abs(hs["loss"]) + 1e-6)
-
-    def test_unviable_shapes_fall_back_to_staged(self):
-        """Corner overlap (2m > S) and non-dense factorisations must
-        keep the staged path — same result with the flag forced on."""
-        policy = get_policy("full")
-        rng = np.random.RandomState(9)
-        x = jnp.asarray(rng.randn(2, 3, 5, 8), jnp.float32)
-        params = _fused_layer(9, 3, 4, (3, 3))  # 2*3 > 5: unsupported
-        fft_in = policy.at("fno/layer0/spectral/fft_in")
-        ctr = policy.at("fno/layer0/spectral/contract")
-        assert not ops.fused_spectral_viable(
-            fft_in, ctr, 2, 3, 4, (5, 8), (3, 3))
-        y_on = spectral_conv_apply(params, x, (3, 3), policy,
-                                   use_pallas=True, fuse_spectral=True)
-        y_off = spectral_conv_apply(params, x, (3, 3), policy,
-                                    use_pallas=True, fuse_spectral=False)
-        np.testing.assert_allclose(np.asarray(y_on), np.asarray(y_off),
-                                   rtol=1e-6, atol=1e-6)
-
-    def test_resolve_fuse_spectral_env_and_flag(self, monkeypatch):
-        from repro.kernels.ops import resolve_fuse_spectral
-
-        assert resolve_fuse_spectral(True) is True
-        assert resolve_fuse_spectral(False) is False
-        monkeypatch.setenv("REPRO_FUSE_SPECTRAL", "0")
-        assert resolve_fuse_spectral(None) is False
-        assert resolve_fuse_spectral(True) is True  # explicit beats env
-        monkeypatch.setenv("REPRO_FUSE_SPECTRAL", "1")
-        assert resolve_fuse_spectral(None) is True
-        monkeypatch.delenv("REPRO_FUSE_SPECTRAL")
-        assert resolve_fuse_spectral(None) is True  # default ON
-
-    def test_telemetry_collector_forces_staged(self):
-        """An active autoprec collector must veto the fused path: its
-        per-stage taps observe the HBM spectrum the megakernel never
-        materialises."""
-        from repro.autoprec.telemetry import TraceCollector, collecting
-
-        policy = get_policy("full")
-        fft_in = policy.at("fno/layer0/spectral/fft_in")
-        ctr = policy.at("fno/layer0/spectral/contract")
-        assert ops.fused_spectral_viable(
-            fft_in, ctr, 2, 3, 4, (9, 11), (3, 5))
-        with collecting(TraceCollector()):
-            assert not ops.fused_spectral_viable(
-                fft_in, ctr, 2, 3, 4, (9, 11), (3, 5))
 
 
 class TestCompiledLayout:
@@ -771,24 +727,6 @@ class TestCompiledLayout:
         assert abs(float(l2) - float(l1)) <= tol * abs(float(l1))
         for a, b in zip(g2, g1, strict=True):
             assert _rel_err(np.asarray(a), np.asarray(b)) <= tol
-
-    def test_fused_layout_condition(self):
-        from repro.kernels.spectral_contract import (
-            fused_exact, fused_layout_compiles, fused_supported)
-
-        # the compiled dispatch drops exactly what Mosaic cannot reshape
-        assert fused_exact((64, 64), (16, 16))
-        assert fused_supported((64, 64), (16, 16), compiled=False)
-        assert not fused_supported((64, 64), (16, 16), compiled=True)
-        assert fused_supported((8, 256), (2, 128), compiled=True)
-        assert fused_supported((64,), (16,), compiled=True)
-        assert fused_layout_compiles((16,)) and fused_layout_compiles((3, 128))
-        assert not fused_layout_compiles((3, 64))
-        # never admits a shape whose factorisation is not exact
-        assert not fused_supported((8, 8), (5, 3), compiled=False)
-        # None resolves from the backend the test runs on
-        assert fused_supported((64, 64), (16, 16)) == (
-            jax.default_backend() != "tpu")
 
     def test_interpret_resolves_from_backend(self):
         from repro.kernels.spectral_contract import resolve_interpret

@@ -195,26 +195,23 @@ class TestMetrics:
         assert "repro_kernels_tiles" in snap.get("external", {})
 
     def test_fused_family_counter_and_bytes_gauge(self):
-        """One fused launch lands on the per-family traced-call counter
-        and the bytes-moved gauge under the ``spectral_fused`` label."""
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
+        """One cast-fusing dense launch (a half site, complex operands)
+        lands on the per-family traced-call counter and the bytes-moved
+        gauge under the ``dense-fused`` label."""
+        from repro.core import get_policy
 
-        from repro.core import FULL, init_spectral_weights
-        from repro.kernels import ops
-
-        params = init_spectral_weights(
-            jax.random.PRNGKey(0), 2, 2, (2, 3), "dense")
-        x = jnp.asarray(np.random.RandomState(0).randn(1, 2, 6, 8),
-                        jnp.float32)
+        rng = np.random.RandomState(0)
+        x = jnp.asarray(rng.randn(1, 2, 2, 3) + 1j * rng.randn(1, 2, 2, 3),
+                        jnp.complex64)
+        w = jnp.asarray(rng.randn(2, 2, 2, 3) + 1j * rng.randn(2, 2, 2, 3),
+                        jnp.complex64)
+        site = get_policy("mixed_fno_bf16").at("fno/layer0/spectral/contract")
         registry().reset()
-        ops.spectral_conv_fused(x, params["w_re"], params["w_im"], (2, 3),
-                                policy=FULL)
+        ops.spectral_contract(x, w, policy=site)
         snap = registry().snapshot()
-        key = 'repro_kernels_calls_total{family="spectral_fused"}'
+        key = 'repro_kernels_calls_total{family="dense-fused"}'
         assert snap["counters"].get(key, 0) >= 1, snap["counters"]
-        gkey = 'repro_kernels_bytes_moved{family="spectral_fused"}'
+        gkey = 'repro_kernels_bytes_moved{family="dense-fused"}'
         assert snap["gauges"].get(gkey, 0) > 0, snap["gauges"]
 
 
@@ -545,7 +542,7 @@ class TestCLI:
 
 #: kernel-call and spectral-transform series carry (family=... /
 #: path=...) labels that depend on which compiled paths a leg traces
-#: (REPRO_USE_PALLAS; the megakernel runs no staged transform) —
+#: (REPRO_USE_PALLAS) —
 #: excluded from the schema golden so both CI legs pin the same key set.
 _VOLATILE_PREFIXES = ("repro_kernels_calls_total",
                       "repro_kernels_bytes_moved",

@@ -1,6 +1,7 @@
-"""Compile rehearsal for the TPU v5e: the main-path spectral kernels and
-one whole FNO and one whole SFNO training step, compiled with
-``interpret=False`` for a described (not attached) ``v5e:2x2`` topology.
+"""Compile rehearsal for the TPU v5e: the main-path spectral kernels, the
+FNO serving forward, and one whole FNO and one whole SFNO training step,
+compiled with ``interpret=False`` for a described (not attached)
+``v5e:2x2`` topology.
 
 Nothing here runs on a chip: each test lowers against shapes placed on a
 described device and asks the TPU compiler (Mosaic for the Pallas
@@ -143,62 +144,38 @@ def test_lshared_sfno_swe(one_chip, train):
     _compile(fn, args, train=train)
 
 
-#: (B, I=O, spatial, modes) of a megakernel shape Mosaic refuses
-UNALIGNED = (4, 32, (64, 64), (16, 16))
-
-
-def _fused_args(B, h, spatial, modes, one_chip):
-    Mh = math.prod(sc._fused_rows(spatial, modes))
-    return [_sds((B, h, *spatial), jnp.float32, one_chip)] + \
-        [_sds((h, h, Mh), jnp.float32, one_chip)] * 2
-
-
-def _fused_fn(modes, block_b):
-    def fn(x, wr, wi):
-        return sc.spectral_fused_pallas(
-            x, wr, wi, modes=modes, block_b=block_b, interpret=False,
-            cast_to=jnp.bfloat16)
-
-    return fn
-
-
-@PHASES
-def test_spectral_fused(one_chip, train):
-    """The rFFT-contract-irFFT megakernel at a 2-D shape the compiled
-    dispatch admits (8x256 grid, modes 2x128, I=O=8, bf16 storage grid),
-    on the batch tile the picker chooses."""
-    from repro.core import get_policy
+@pytest.mark.parametrize("policy_name", ["mixed_fno_bf16", "full"])
+def test_fno_infer_serving_forward(one_chip, monkeypatch, policy_name):
+    """The serving cell's program: ``fno_infer`` at FNO_DARCY width on a
+    full micro-batch (B=8, 128x128) with the Pallas path compiled and the
+    TPU's truncated-DFT transforms, under the paper's mixed policy and
+    under ``full``.  Nothing differentiates it, so the block loop stays
+    one rolled scan: one ``while``, no ``fft``, Mosaic kernels, and the
+    program fits one chip's HBM."""
+    from repro.core import get_policy, spectral
     from repro.kernels import ops
+    from repro.models import fno_infer, init_fno
 
-    B, h, spatial, modes = 4, 8, (8, 256), (2, 128)
-    ctr = get_policy("mixed_fno_bf16").at("fno/layer0/spectral/contract")
-    assert sc.fused_supported(spatial, modes, compiled=True)
-    assert sc.fused_vmem_bytes_bwd(1, h, h, spatial, modes) <= \
-        sc.VMEM_BUDGET
-    assert ops.fused_spectral_viable(ctr, ctr, B, h, h, spatial, modes)
-    # the shape the compiler refuses (next test) is excluded by name
-    _, _, u_spatial, u_modes = UNALIGNED
-    assert sc.fused_exact(u_spatial, u_modes)
-    assert not sc.fused_supported(u_spatial, u_modes, compiled=True)
-    bb = sc.pick_block_b(B, h, h, spatial, modes, itemsize=4)
-    _compile(_fused_fn(modes, bb), _fused_args(B, h, spatial, modes,
-                                               one_chip), train=train)
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    monkeypatch.setattr(spectral, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(FNO_DARCY, use_pallas=True)
+    policy = get_policy(policy_name)
+    params = jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda: init_fno(jax.random.PRNGKey(0), cfg)))
+    x = _sds((8, 1, 128, 128), jnp.float32, one_chip)
 
-
-@PHASES
-@pytest.mark.xfail(strict=True, reason=(
-    "Mosaic refuses the megakernel's reshape of the flattened retained "
-    "modes to per-axis blocks: 'infer-vector-layout: unsupported shape "
-    "cast vector<1x32x512xf32> -> vector<1x32x32x16xf32>'; "
-    "fused_layout_compiles excludes these shapes from the compiled "
-    "dispatch"))
-def test_spectral_fused_unaligned_modes_refused(one_chip, train):
-    """A 64x64 grid at modes 16x16 (the last axis keeps 16 modes, not
-    whole 128-lane rows), which the compiled dispatch excludes: the
-    compiler indeed refuses it."""
-    B, h, spatial, modes = UNALIGNED
-    _compile(_fused_fn(modes, 1), _fused_args(B, h, spatial, modes,
-                                              one_chip), train=train)
+    lowered = jax.jit(lambda p, x: fno_infer(p, x, cfg, policy)).lower(
+        params, x)
+    text = lowered.as_text()
+    assert "stablehlo.fft" not in text
+    assert text.count("stablehlo.while") == 1
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 16e9, f"forward needs {used / 1e9:.1f} GB of HBM"
 
 
 #: Ceilings on a training step's temporaries (``memory_analysis``), in
